@@ -203,7 +203,8 @@ def deep_scrub(targets: list, mesh=None,
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from ..ops.device_pool import get_pool
-        from ..parallel.mesh import make_ec_mesh, make_parity_step
+        from ..parallel.mesh import (aliases_host_memory, make_ec_mesh,
+                                     make_parity_step)
 
         if mesh is None:
             mesh = make_ec_mesh()
@@ -227,7 +228,7 @@ def deep_scrub(targets: list, mesh=None,
         dev_label = (str(dev0) if single
                      else f"sharded:{mesh.devices.size}")
         sharding_kb = NamedSharding(mesh, P(None, "data", "block"))
-        zero_copy = single and dev0 == jax.devices("cpu")[0]
+        zero_copy = single and aliases_host_memory(dev0)
         pool_before = pool.snapshot()
 
         oshape = (PARITY_SHARDS_COUNT, b, width)

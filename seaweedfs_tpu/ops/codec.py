@@ -15,11 +15,13 @@ from __future__ import annotations
 
 import ctypes
 import os
+import threading
 
 import numpy as np
 
 from . import native
-from ..util.platform import on_tpu
+from ..util import glog
+from ..util.platform import jax_usable, on_tpu
 from .rs_numpy import (NumpyEncoder, ReconstructError,  # noqa: F401
                        RSCodecBase, decode_plan_cache_info, decode_rows,
                        gf_apply_matrix)
@@ -113,13 +115,34 @@ def recover_device_enabled() -> bool:
     """Whether reconstruct_span may dispatch to a device kernel.
     WEED_EC_RECOVER_DEVICE: unset/"auto" -> only on a real TPU; "1"
     forces it on (any jax backend — the CPU mesh harness and tests);
-    "0" disables."""
+    "0" disables.  Both answers come from this process's own backend,
+    so a forked prefork worker never dispatches."""
     v = os.environ.get("WEED_EC_RECOVER_DEVICE", "auto").lower()
     if v in ("1", "true", "yes", "force"):
-        return True
+        return jax_usable()
     if v in ("0", "false", "no"):
         return False
     return on_tpu()
+
+
+_device_counts_lock = threading.Lock()
+_device_counts = {"device_decodes": 0, "device_fallbacks": 0}
+
+
+def _count_device(ok: bool):
+    from ..stats import metrics as stats
+
+    with _device_counts_lock:
+        _device_counts["device_decodes" if ok else "device_fallbacks"] += 1
+    stats.EcRecoverDeviceCounter.labels("ok" if ok else "fallback").inc()
+
+
+def recover_device_counts() -> dict:
+    """Process-wide {"device_decodes", "device_fallbacks"}: decodes
+    reconstruct_span served on the device, and decodes whose device
+    dispatch failed and were re-run on the host."""
+    with _device_counts_lock:
+        return dict(_device_counts)
 
 
 def _apply_rows_host(rows: np.ndarray, inputs: np.ndarray) -> np.ndarray:
@@ -137,6 +160,43 @@ def _apply_rows_host(rows: np.ndarray, inputs: np.ndarray) -> np.ndarray:
         rows.ctypes.data_as(ctypes.c_char_p), t, d,
         inputs.ctypes.data_as(ctypes.c_char_p), length,
         out.ctypes.data_as(ctypes.c_char_p))
+    return out
+
+
+def _apply_rows_device(rows, to_dev: np.ndarray, out_rows: int, fam_name,
+                       survivors, slab_key) -> np.ndarray:
+    """(t, d) decode rows x (d, L) survivor spans on the default JAX
+    device: the Pallas kernel on a TPU, the SWAR XLA apply elsewhere."""
+    import jax
+    import jax.numpy as jnp
+
+    from .device_pool import get_pool
+    from .rs_jax import apply_matrix
+
+    dev0 = jax.devices()[0]
+    method = "pallas" if dev0.platform == "tpu" else "swar"
+    if slab_key is None:
+        return np.asarray(apply_matrix(
+            np.asarray(rows), to_dev, method=method))[:out_rows]
+    pool = get_pool()
+    # survivor slabs upload to the default device; labeling the
+    # transfers/residency keeps the recover traffic distinguishable
+    # from the sharded encode meshes'
+    dev_label = str(dev0)
+    key = ("recover", fam_name, tuple(survivors), slab_key)
+
+    def _upload():
+        dev = jnp.asarray(to_dev)
+        pool.note_h2d(to_dev.nbytes, device=dev_label)
+        return dev
+
+    dev_in = pool.acquire_resident(key, _upload, to_dev.nbytes)
+    try:
+        out = np.asarray(apply_matrix(
+            np.asarray(rows), dev_in, method=method))[:out_rows]
+    finally:
+        pool.release_resident(key)
+    pool.note_d2h(out.nbytes, device=dev_label)
     return out
 
 
@@ -181,41 +241,17 @@ def reconstruct_span(survivors, inputs: np.ndarray, target: int,
     if inputs.nbytes >= recover_device_min_bytes() \
             and recover_device_enabled():
         try:
-            import jax.numpy as jnp
-
-            from .device_pool import get_pool
-            from .rs_jax import apply_matrix
-
-            method = "pallas" if on_tpu() else "swar"
-            if slab_key is not None:
-                import jax
-
-                pool = get_pool()
-                # survivor slabs upload to the default device; labeling
-                # the transfers/residency keeps the recover traffic
-                # distinguishable from the sharded encode meshes'
-                dev_label = str(jax.devices()[0])
-                key = ("recover", fam_name, tuple(survivors), slab_key)
-
-                def _upload():
-                    dev = jnp.asarray(to_dev)
-                    pool.note_h2d(to_dev.nbytes, device=dev_label)
-                    return dev
-
-                dev_in = pool.acquire_resident(key, _upload,
-                                               to_dev.nbytes)
-                try:
-                    out = np.asarray(apply_matrix(
-                        np.asarray(rows), dev_in,
-                        method=method))[:out_rows]
-                finally:
-                    pool.release_resident(key)
-                pool.note_d2h(out.nbytes, device=dev_label)
-                return _finish(out)
-            return _finish(np.asarray(apply_matrix(
-                np.asarray(rows), to_dev, method=method))[:out_rows])
-        except Exception:
-            pass  # device hiccup mid-incident: the host path always works
+            out = _apply_rows_device(rows, to_dev, out_rows, fam_name,
+                                     survivors, slab_key)
+        except Exception as e:
+            # mid-incident the read must still be served, and the host
+            # path always works — but a device failure is never silent
+            _count_device(ok=False)
+            glog.errorf("ec recover: device decode failed (%s: %s); "
+                        "served by the host codec", type(e).__name__, e)
+        else:
+            _count_device(ok=True)
+            return _finish(out)
     return _finish(_apply_rows_host(rows, to_dev)[:out_rows])
 
 

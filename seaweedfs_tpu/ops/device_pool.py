@@ -1,14 +1,11 @@
 """Device-memory slab pool for the EC pipeline (BASELINE config 4's
 orchestration layer).
 
-The raw kernels sustain tens of GiB/s once data is HBM-resident, but a
-dispatch layer that allocates fresh buffers per batch never gets there:
-BENCH_r05 measured the device dispatch path at 0.005 GiB/s — 12,000x
-under the fused kernel — with the time going to per-batch `device_put`
-allocations, undonated outputs and synchronous drains.  This module is
-the fix's memory half: every buffer the dispatch path touches comes from
-a pool of pre-allocated, fixed-shape slabs so the steady state performs
-ZERO per-batch allocations.
+A dispatch layer that allocates fresh buffers per batch pays per-batch
+`device_put` allocations, undonated outputs and synchronous drains on
+every dispatch.  This module is the fix's memory half: every buffer the
+dispatch path touches comes from a pool of pre-allocated, fixed-shape
+slabs so the steady state performs ZERO per-batch allocations.
 
 Two kinds of slab, one accounting domain:
 

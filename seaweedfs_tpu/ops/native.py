@@ -1,7 +1,8 @@
 """ctypes bindings for the native C++ library (native/ec_native.cpp).
 
-Builds the shared library on first import if missing (make in native/);
-callers must tolerate `lib() is None` when no toolchain is available.
+Builds the shared libraries on first use (make in native/); callers must
+tolerate `lib() is None` when no toolchain is available — a failed build
+is logged once, with the compiler's stderr.
 """
 
 from __future__ import annotations
@@ -19,18 +20,31 @@ _LIB_PATH = os.path.join(_NATIVE_DIR, "libseaweedec.so")
 
 
 @functools.lru_cache(maxsize=1)
-def lib() -> ctypes.CDLL | None:
-    # run make unconditionally: it is a no-op when the .so is fresh and
-    # rebuilds after ec_native.cpp edits (a missing toolchain only matters
-    # when there is no prebuilt library at all)
+def build() -> bool:
+    """Run `make` in native/ once per process (both libraries; shared
+    with storage/native_engine.py).  It is a no-op when the .so files
+    are fresh and rebuilds after source edits.  A failure is logged here,
+    once, with its stderr; callers then use a prebuilt library if one
+    exists and their host fallbacks otherwise."""
+    from ..util import glog
+
     try:
-        subprocess.run(
-            ["make", "-s"], cwd=_NATIVE_DIR, check=True,
-            capture_output=True, timeout=120,
-        )
-    except Exception:
-        if not os.path.exists(_LIB_PATH):
-            return None
+        subprocess.run(["make", "-s"], cwd=_NATIVE_DIR, check=True,
+                       capture_output=True, timeout=300)
+        return True
+    except subprocess.CalledProcessError as e:
+        glog.errorf("native build failed (make exit %d) in %s:\n%s",
+                    e.returncode, _NATIVE_DIR,
+                    e.stderr.decode(errors="replace")[-4000:])
+    except (OSError, subprocess.TimeoutExpired) as e:
+        glog.errorf("native build did not run in %s: %s: %s",
+                    _NATIVE_DIR, type(e).__name__, e)
+    return False
+
+
+@functools.lru_cache(maxsize=1)
+def lib() -> ctypes.CDLL | None:
+    build()
     try:
         cdll = ctypes.CDLL(_LIB_PATH)
     except OSError:

@@ -74,17 +74,25 @@ def _apply_pallas(bit_matrix, data, out_rows: int, block: int,
     )(bit_matrix, data)
 
 
+def _interpret_for(x) -> bool:
+    """Mosaic compiles for a TPU only; everywhere else the kernel runs
+    in Pallas interpret mode.  Decided by where `x` actually lives (a
+    tracer has no placement yet: the default backend will run it)."""
+    if isinstance(x, jax.core.Tracer):
+        return jax.default_backend() != "tpu"
+    return next(iter(x.devices())).platform != "tpu"
+
+
 def apply_matrix_pallas(matrix: np.ndarray, data, block: int = DEFAULT_BLOCK,
                         interpret: bool | None = None):
     """out[i] = XOR_j gf_mul(matrix[i,j], data[j]).  data: (d, L) uint8."""
-    from ..util.platform import on_tpu
     from .rs_jax import _bit_matrix_cached, _matrix_key
 
     p, d = matrix.shape
     bm = jnp.asarray(_bit_matrix_cached(*_matrix_key(matrix)))
     data = jnp.asarray(data, dtype=jnp.uint8)
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = _interpret_for(data)
     return _apply_pallas(bm, data, p, block, interpret)
 
 
@@ -301,7 +309,6 @@ def fused_encode_words(matrix: np.ndarray, words,
     the downloaded array as uint8 on the host; no device bitcast happens
     in either direction.  L must divide into a power-of-two count of
     `block`-byte segments (check with fused_encode_block first)."""
-    from ..util.platform import on_tpu
     from .crc_device import combine_tree
     from .rs_jax import _matrix_key
 
@@ -316,7 +323,7 @@ def fused_encode_words(matrix: np.ndarray, words,
     bmw = jnp.asarray(_bm_word_cached(*_matrix_key(matrix)))
     v = jnp.asarray(_anchor_matrix(block))
     if interpret is None:
-        interpret = not on_tpu()
+        interpret = _interpret_for(words)
     parity_w, tiles = _fused_encode_words(bmw, v, words, d, p, block,
                                           interpret)
     # per-(byteidx, plane) advance corrections + the shared combine fold:

@@ -23,12 +23,10 @@ import os
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import gf256
 from ..ops.rs_jax import _bit_matrix_cached, _matrix_key
-from ..util import glog
 
 
 def make_mesh(devices=None, axes: tuple[str, str] = ("data", "block")
@@ -74,6 +72,14 @@ def shard_devices(devices=None) -> list:
             cores = os.cpu_count() or 1
         n = max(1, min(len(devices), cores))
     return list(devices)[:n]
+
+
+def aliases_host_memory(device) -> bool:
+    """True when numpy -> jax via dlpack is ZERO-copy onto `device`: the
+    first CPU device, where from_dlpack lands.  Asked of the device
+    itself — asking JAX for the CPU platform's devices raises when only
+    the TPU platform is initialised."""
+    return device.platform == "cpu" and device.id == 0
 
 
 def make_ec_mesh(devices=None) -> Mesh:
@@ -146,7 +152,7 @@ def batched_encode_step(bit_matrix, data):
 
 _ENCODER_CACHE: dict = {}
 _APPLY_CACHE: dict = {}
-_PALLAS_OK: dict = {}
+_PALLAS_VERIFIED: set = set()
 _PARITY_STEP_CACHE: dict = {}
 
 
@@ -240,14 +246,14 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
     elif fused_crc:
         sh = P(None, "data", None)
         step = jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(sh, sh),
-                      out_specs=(sh, P(None, "data")), check_rep=False),
+            jax.shard_map(body, mesh=mesh, in_specs=(sh, sh),
+                          out_specs=(sh, P(None, "data")), check_vma=False),
             donate_argnums=(1,))
     else:
         sh = P(None, "data", "block")
         step = jax.jit(
-            shard_map(body, mesh=mesh, in_specs=(sh, sh), out_specs=sh,
-                      check_rep=False),
+            jax.shard_map(body, mesh=mesh, in_specs=(sh, sh), out_specs=sh,
+                          check_vma=False),
             donate_argnums=(1,))
     _PARITY_STEP_CACHE[cache_key] = step
     return step
@@ -270,8 +276,6 @@ def step_cost_analysis(step, key, *abstract_args):
         return cached
     try:
         cost = step.lower(*abstract_args).cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax: one per device
-            cost = cost[0] if cost else {}
         flops = float(cost.get("flops", 0.0))
         nbytes = float(cost.get("bytes accessed", 0.0))
     except Exception:  # cost analysis is telemetry, never fatal
@@ -284,56 +288,45 @@ def step_cost_analysis(step, key, *abstract_args):
     return entry
 
 
-def _pallas_fused_ok(matrix) -> bool:
+def _pallas_fused_selftest(matrix) -> bool:
     """One-time self-test (per matrix geometry) of the fused Mosaic
-    kernel on this backend: compile+run at a production-representative
-    shape (the production fused block with a multi-segment combine)
-    checked against the host codec.  A Mosaic lowering regression then
-    degrades the production encode path to the portable XLA step instead
-    of crashing it."""
-    from ..ops.rs_pallas import DEFAULT_FUSED_BLOCK
+    kernel on the TPU: compile+run at a production-representative shape
+    (the production fused block with a multi-segment combine) checked
+    against the host codec.  Returns True, or raises: a kernel that does
+    not compile, or compiles to wrong parity or CRCs, stops the encode
+    instead of silently moving production to another path."""
+    from ..ops import crc32c as crc_host
+    from ..ops.rs_numpy import gf_apply_matrix
+    from ..ops.rs_pallas import DEFAULT_FUSED_BLOCK, fused_encode_words
 
     m = np.ascontiguousarray(matrix, dtype=np.uint8)
     key = (m.tobytes(), m.shape)
-    if key in _PALLAS_OK:
-        return _PALLAS_OK[key]
-    try:
-        from ..ops.rs_pallas import fused_encode_words
-        from ..ops.rs_numpy import gf_apply_matrix
-        from ..ops import crc32c as crc_host
-
-        rng = np.random.default_rng(0)
-        # batch >= 2 so BOTH grid dimensions take nonzero indices on the
-        # hardware — a bi>0-only miscompile must not pass the guard;
-        # drive the exact production invocation (int32 word views)
-        data = rng.integers(0, 256,
-                            (2, m.shape[1], 2 * DEFAULT_FUSED_BLOCK),
-                            dtype=np.uint8)
-        parity_w, crcs = fused_encode_words(m, data.view(np.int32),
-                                            interpret=False)
-        parity = np.ascontiguousarray(np.asarray(parity_w)).view(np.uint8)
-        parity = parity.reshape(data.shape[0], m.shape[0], -1)
-        crcs = np.asarray(crcs)
-        ok = True
-        for bi in range(data.shape[0]):
-            expect = gf_apply_matrix(m, data[bi])
-            ok = ok and np.array_equal(parity[bi], expect)
-            full = np.concatenate([data[bi], expect], axis=0)
-            ok = ok and all(
+    if key in _PALLAS_VERIFIED:
+        return True
+    rng = np.random.default_rng(0)
+    # batch >= 2 so BOTH grid dimensions take nonzero indices on the
+    # hardware — a bi>0-only miscompile must not pass the guard;
+    # drive the exact production invocation (int32 word views)
+    data = rng.integers(0, 256,
+                        (2, m.shape[1], 2 * DEFAULT_FUSED_BLOCK),
+                        dtype=np.uint8)
+    parity_w, crcs = fused_encode_words(m, data.view(np.int32),
+                                        interpret=False)
+    parity = np.ascontiguousarray(np.asarray(parity_w)).view(np.uint8)
+    parity = parity.reshape(data.shape[0], m.shape[0], -1)
+    crcs = np.asarray(crcs)
+    for bi in range(data.shape[0]):
+        expect = gf_apply_matrix(m, data[bi])
+        full = np.concatenate([data[bi], expect], axis=0)
+        if not (np.array_equal(parity[bi], expect) and all(
                 int(crcs[bi, s]) == crc_host.raw_update(
                     0, full[s].tobytes())
-                for s in range(full.shape[0]))
-        if not ok:
-            glog.warningf(
-                "fused pallas encode self-test MISMATCHED on this "
-                "backend; falling back to the XLA step")
-    except Exception as e:
-        glog.warningf(
-            "fused pallas encode unavailable (%s: %s); falling back to "
-            "the XLA step", type(e).__name__, e)
-        ok = False
-    _PALLAS_OK[key] = ok
-    return ok
+                for s in range(full.shape[0]))):
+            raise RuntimeError(
+                "fused pallas encode self-test MISMATCHED the host codec "
+                f"on this TPU (batch element {bi})")
+    _PALLAS_VERIFIED.add(key)
+    return True
 
 
 def make_sharded_apply(mesh: Mesh, matrix: np.ndarray):
@@ -387,7 +380,7 @@ def words_capable(mesh: Mesh, chunk_len: int,
     return (mesh.devices.size == 1 and chunk_len % 4 == 0
             and bool(fused_encode_block(chunk_len))
             and mesh.devices.flat[0].platform == "tpu"
-            and _pallas_fused_ok(matrix))
+            and _pallas_fused_selftest(matrix))
 
 
 def make_sharded_encoder(mesh: Mesh, data_shards: int = 10,

@@ -240,7 +240,10 @@ def ec_encode(env: CommandEnv, vid: int, collection: str = "",
     ec_code = _collection_ec_code(env, collection)
     if ec_code:
         payload["code_family"] = ec_code
-    call(source, "/admin/ec/generate", payload, timeout=3600)
+    # the reply names where the encode ran (backend, devices, device,
+    # stage stats); it rides the printed plan
+    plan["generate"] = call(source, "/admin/ec/generate", payload,
+                            timeout=3600)
     # 3/4. spread + mount
     for url, shard_ids in allocation.items():
         if url != source:
@@ -407,8 +410,9 @@ def ec_rebuild(env: CommandEnv, vid: int, collection: str = "",
         call(rebuilder.url, "/admin/ec/copy",
              {"volume": vid, "collection": collection, "shard_ids": [sid],
               "source": source, "copy_ecx_file": True}, timeout=3600)
-    call(rebuilder.url, "/admin/ec/rebuild",
-         {"volume": vid, "collection": collection}, timeout=3600)
+    plan["rebuild"] = call(
+        rebuilder.url, "/admin/ec/rebuild",
+        {"volume": vid, "collection": collection}, timeout=3600)
     call(rebuilder.url, "/admin/ec/mount",
          {"volume": vid, "collection": collection, "shard_ids": missing})
     # drop the temporarily copied survivors from the rebuilder's disk

@@ -331,6 +331,11 @@ EcRecoverSpanCounter = REGISTRY.counter(
 EcRecoverBytesCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_recover_bytes_total",
     "survivor bytes pushed through degraded-read decodes")
+EcRecoverDeviceCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_recover_device_total",
+    "degraded-read decodes dispatched to the device, by outcome "
+    "(ok / fallback = the dispatch failed and the host codec served)",
+    ("result",))
 # inline write-path EC (storage/erasure_coding/inline.py): needles
 # stream straight into striped shard logs, parity commits per stripe
 EcInlineStripesCommitted = REGISTRY.counter(

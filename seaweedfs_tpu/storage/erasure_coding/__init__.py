@@ -1,6 +1,6 @@
 """Erasure coding: RS(10,4) over striped volume blocks, computed on TPU.
 
-File taxonomy per volume v (reference weed/storage/erasure_coding/
+File kinds per volume v (reference weed/storage/erasure_coding/
 ec_encoder.go:17-23, ec_volume.go:66-72):
   v.dat/.idx -> v.ec00..v.ec13 (shards), v.ecx (sorted index copy),
   v.ecj (deletion journal), v.vif (volume info sidecar).

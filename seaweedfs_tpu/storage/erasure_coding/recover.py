@@ -143,11 +143,14 @@ class RecoverStats:
         if wall and wall > 0:
             for k in ("fetch", "decode", "serve"):
                 out[f"{k}_frac"] = round(out[f"{k}_seconds"] / wall, 3)
+        # decodes the device served, and device dispatches that failed
+        # and fell back to the host codec (never silent: ops/codec.py)
+        from ...ops import codec, device_pool
+
+        out.update(codec.recover_device_counts())
         # the device slab pool serving the recover device path: resident
         # hits here are survivor-stack uploads the pool saved (one
         # incident's repeated decodes against the same survivor set)
-        from ...ops import device_pool
-
         pool = device_pool.get_pool()
         snap = pool.snapshot()
         out["device_pool"] = {
@@ -252,7 +255,7 @@ class RecoveredBlockCache:
             return data
         if not leader:
             self.stats.cache_event("coalesced")
-            # a wedged leader (e.g. a remote fetch past its own timeout)
+            # a stuck leader (e.g. a remote fetch past its own timeout)
             # must not strand followers forever: time out and self-serve
             if flight.event.wait(timeout=120.0):
                 if flight.error is not None:

@@ -20,12 +20,12 @@ from __future__ import annotations
 import ctypes
 import functools
 import os
-import subprocess
 import threading
 from typing import Callable, Iterator, Optional
 
 import numpy as np
 
+from ..ops import native
 from . import types as t
 
 _NATIVE_DIR = os.path.join(
@@ -43,12 +43,7 @@ _u32 = ctypes.c_uint32
 def lib() -> Optional[ctypes.CDLL]:
     if os.environ.get("SW_NATIVE", "1") == "0":
         return None
-    try:
-        subprocess.run(["make", "-s"], cwd=_NATIVE_DIR, check=True,
-                       capture_output=True, timeout=180)
-    except Exception:
-        if not os.path.exists(_LIB_PATH):
-            return None
+    native.build()  # logs a failed make once, with its stderr
     try:
         cdll = ctypes.CDLL(_LIB_PATH)
     except OSError:

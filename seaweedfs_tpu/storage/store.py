@@ -208,8 +208,12 @@ class Store:
                                      PARITY_SHARDS_COUNT, backend=backend)
         return backend
 
-    def ec_generate(self, vid: int, encoder=None, code_family: str = None):
+    def ec_generate(self, vid: int, encoder=None, code_family: str = None,
+                    stage_stats: Optional[dict] = None):
         """VolumeEcShardsGenerate: encode a local volume into shard files.
+        `stage_stats` (optional dict) is filled by the pipeline that ran:
+        its `backend` names the path; the device pipeline adds devices,
+        platform and per-stage busy seconds.
 
         Backend: -ec.backend=tpu forces the streaming batched device
         pipeline; the default (None) auto-selects batched vs host codec
@@ -234,10 +238,12 @@ class Store:
                           and self.ec_encoder_backend == "tpu") else None
         if family != ec_codes.DEFAULT_FAMILY:
             crcs = ec_encoder.write_ec_files(base, family=family)
+            if stage_stats is not None:
+                stage_stats["backend"] = "host-family-loop"
         else:
             crcs = ec_encoder.write_ec_files(
                 base, encoder=encoder or self._resolve_ec_encoder(),
-                batched=forced)
+                batched=forced, stage_stats=stage_stats)
         ec_encoder.write_sorted_file_from_idx(base)
         extra = {"code_family": family}
         if crcs:
@@ -288,8 +294,12 @@ class Store:
                 extra={"shard_crc32c": crc_map[base],
                        "code_family": ec_codes.DEFAULT_FAMILY})
 
-    def ec_rebuild(self, vid: int, collection: str = "") -> list[int]:
+    def ec_rebuild(self, vid: int, collection: str = "",
+                   stage_stats: Optional[dict] = None) -> list[int]:
         """VolumeEcShardsRebuild: regenerate missing local shard files.
+        -ec.backend=tpu forces the batched device pipeline here exactly
+        as it does for ec_generate; `stage_stats` (optional dict) is
+        filled with the path that ran.
 
         When the batched device path produced fused CRCs AND the .vif
         records the original shard CRCs, the rebuilt values are VERIFIED
@@ -314,6 +324,8 @@ class Store:
             rb_stats: dict = {}
             crcs = ec_encoder.rebuild_ec_files(base, family=family,
                                                stats=rb_stats)
+            if stage_stats is not None:
+                stage_stats["backend"] = "host-planned"
             if rb_stats.get("rebuilt_bytes"):
                 ec_codes.note_rebuild(family, rb_stats["read_bytes"],
                                       rb_stats["rebuilt_bytes"])
@@ -325,7 +337,9 @@ class Store:
                 for i in range(TOTAL_SHARDS_COUNT)
                 if os.path.exists(base + to_ext(i)))
             crcs = ec_encoder.rebuild_ec_files(
-                base, encoder=self._resolve_ec_encoder())
+                base, encoder=self._resolve_ec_encoder(),
+                batched=True if self.ec_encoder_backend == "tpu" else None,
+                stage_stats=stage_stats)
             rebuilt_bytes = sum(
                 os.path.getsize(base + to_ext(sid)) for sid in crcs
                 if os.path.exists(base + to_ext(sid)))

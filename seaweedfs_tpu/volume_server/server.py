@@ -32,6 +32,7 @@ from ..rpc.http_rpc import (FileSlice, Request, Response, RpcError,
                             RpcServer, call, call_stream, sendfile_enabled,
                             stream_file)
 from ..util import faults
+from ..util import platform as platform_util
 from ..security import Guard, gen_write_jwt, token_from_request
 from ..stats import access
 from ..stats import events as events_mod
@@ -1694,18 +1695,35 @@ class VolumeServer:
         return {"results": results}
 
     # -- EC handlers (volume_grpc_erasure_coding.go) -------------------------
+    @staticmethod
+    def _ec_where(stage_stats: dict) -> dict:
+        """Reply fields naming where an EC job ran: the pipeline's
+        backend and device count, the device as JAX reports it in THIS
+        process (None for a host path), and the job's stage stats."""
+        on_device = str(stage_stats.get("backend", "")).startswith("device")
+        return {"backend": stage_stats.get("backend"),
+                "devices": stage_stats.get("devices", 0),
+                "device": (platform_util.device_info() if on_device
+                           else None),
+                "stage_stats": stage_stats}
+
     def _h_ec_generate(self, req: Request):
         p = req.json()
-        self.store.ec_generate(int(p["volume"]),
-                               code_family=p.get("code_family") or None)
-        return {}
+        stage_stats: dict = {}
+        self.store.ec_generate(
+            int(p["volume"]), code_family=p.get("code_family") or None,
+            stage_stats=stage_stats)
+        return self._ec_where(stage_stats)
 
     def _h_ec_rebuild(self, req: Request):
         p = req.json()
         vid = int(p["volume"])
-        rebuilt = self.store.ec_rebuild(vid, p.get("collection", ""))
+        stage_stats: dict = {}
+        rebuilt = self.store.ec_rebuild(vid, p.get("collection", ""),
+                                        stage_stats=stage_stats)
         self.read_cache.invalidate_volume(vid, reason="rebuild")
-        return {"rebuilt_shard_ids": rebuilt}
+        return {"rebuilt_shard_ids": rebuilt,
+                **self._ec_where(stage_stats)}
 
     def _h_ec_mount(self, req: Request):
         p = req.json()
@@ -1846,6 +1864,9 @@ class VolumeServer:
                     "cache_bytes": ev._recover_cache.size_bytes,
                 }
         out["volumes"] = volumes
+        # the device a degraded read would dispatch to, as JAX reports
+        # it in this process (None: no backend, or a prefork worker)
+        out["device"] = platform_util.device_info()
         return out
 
     def _h_ec_shard_file(self, req: Request):

@@ -162,7 +162,7 @@ class FidLeaseCache:
                 # single-flight: another thread is already at the master
                 remaining = deadline - time.monotonic()
                 if remaining <= 0 or not st.cond.wait(remaining):
-                    # refill wedged — don't pile up behind it
+                    # refill stuck — don't pile up behind it
                     _stats.FilerFidLeaseCounter.labels("miss").inc()
                     return self._assign_fn(1, replication, collection, ttl)
         _stats.FilerFidLeaseCounter.labels("miss").inc()

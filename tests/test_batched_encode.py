@@ -139,9 +139,9 @@ class TestBackendAutoSelection:
     def test_slow_link_prefers_host_codec(self, tmp_path, monkeypatch):
         from seaweedfs_tpu.util import platform as plat
 
-        monkeypatch.setattr(plat, "_probe", lambda t: (True, "tpu"))
+        monkeypatch.setattr(plat, "_probe", lambda: (True, "tpu"))
         monkeypatch.setattr(plat, "link_throughput",
-                            lambda **kw: (5.0, 2.0))  # MB/s relay-class
+                            lambda **kw: (5.0, 2.0))  # a link in single MB/s
         assert plat.predicted_batched_gibps() < 0.01
         assert plat.prefer_batched_encode() is False
         # multi-core host: the fallback is the PIPELINED host mode,
@@ -172,7 +172,7 @@ class TestBackendAutoSelection:
 
         # a fast-link TPU picks batched...
         with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(plat, "_probe", lambda t: (True, "tpu"))
+            mp.setattr(plat, "_probe", lambda: (True, "tpu"))
             mp.setattr(plat, "link_throughput", lambda **kw: (1e6, 1e6))
             assert plat.prefer_batched_encode() is True
         # ...and so does the CPU/virtual-mesh backend (device == host, no
@@ -205,7 +205,7 @@ class TestBackendAutoSelection:
         base = _make_volume(tmp_path, "rt", 77777, 8)
         ref = _make_volume(tmp_path, "rtref", 77777, 8)
         with pytest.MonkeyPatch.context() as mp:
-            mp.setattr(plat, "_probe", lambda t: (True, "tpu"))
+            mp.setattr(plat, "_probe", lambda: (True, "tpu"))
             mp.setattr(plat, "link_throughput", lambda **kw: (5.0, 2.0))
             ec_encoder.write_ec_files(base, large_block_size=LARGE,
                                       small_block_size=SMALL)

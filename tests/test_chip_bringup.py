@@ -1,0 +1,301 @@
+"""Bring-up guards: the device path must run where it says it runs.
+
+In-process device detection (no child process ever asks for the chip),
+no silent host fallback on the device path, a placeable compile cache,
+`-ecBackend tpu` forcing rebuild as it forces encode, and chip_smoke.py's
+CPU rehearsal — the flow the chip run repeats at full size."""
+
+import json
+import os
+import subprocess
+import sys
+import types
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _run(args, env_extra=None, env_drop=(), timeout=600):
+    env = {k: v for k, v in os.environ.items() if k not in env_drop}
+    env.update(env_extra or {})
+    return subprocess.run([sys.executable, *args], cwd=REPO, env=env,
+                          capture_output=True, text=True, timeout=timeout)
+
+
+class TestChipSmoke:
+    def test_rehearse_passes_and_says_it_is_a_rehearsal(self):
+        proc = _run(["chip_smoke.py", "--rehearse"],
+                    env_extra={"JAX_PLATFORMS": "cpu"})
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        summary, last = proc.stdout.strip().splitlines()[-2:]
+        out = json.loads(summary)
+        # the result line: exactly these keys, the device as JAX names it
+        result = json.loads(last)
+        assert set(result) == {"ok", "device"} and result["ok"] is True
+        assert set(result["device"]) == {"platform", "kind", "count"}
+        assert result["device"] == out["device"]
+        assert isinstance(result["device"]["kind"], str)
+        assert type(result["device"]["count"]) is int
+        assert out["ok"] is True
+        assert out["mode"].startswith("rehearsal")
+        assert out["device"]["platform"] == "cpu"
+        assert out["reduced"], "a rehearsal must list its cut in size"
+        assert all(p["ok"] for p in out["phases"].values())
+        assert out["phases"]["ec_rebuild"]["backend"] == "device-apply-xla"
+        assert out["phases"]["degraded_read"]["device_fallbacks"] == 0
+        # stage stats of one cold run, named as such; no metric claimed
+        assert out["cold_run_stage_stats"]["ec_encode"]["backend"] \
+            == "device-pooled-swar"
+        assert list(out)[-1] == "claim" and out["claim"] is None
+
+    def test_default_invocation_fails_fast_without_a_tpu(self):
+        proc = _run(["chip_smoke.py"], env_extra={"JAX_PLATFORMS": "cpu"})
+        assert proc.returncode != 0
+        # no result line, and it stopped before loading any data
+        assert not proc.stdout.strip().splitlines()[-1].startswith("{")
+        assert "load ..." not in proc.stdout
+        assert "needs 'tpu'" in proc.stderr
+
+
+class TestInProcessDetection:
+    def test_probes_spawn_no_process(self, monkeypatch):
+        from seaweedfs_tpu.util import platform as plat
+
+        def boom(*a, **kw):
+            raise AssertionError("device detection spawned a process")
+
+        monkeypatch.setattr(subprocess, "run", boom)
+        monkeypatch.setattr(subprocess, "Popen", boom)
+        monkeypatch.setattr(plat, "_cache", {})
+        assert plat.jax_usable() is True
+        assert plat.on_tpu() is False  # the tests' backend is the CPU
+        info = plat.device_info()
+        assert info["platform"] == "cpu" and info["count"] >= 1
+        assert not hasattr(plat, "subprocess")
+
+    def test_forked_prefork_worker_never_sees_a_device(self, monkeypatch):
+        from seaweedfs_tpu.ops import codec
+        from seaweedfs_tpu.rpc import prefork
+        from seaweedfs_tpu.util import platform as plat
+
+        assert plat.jax_usable() is True  # the parent's cached answer
+        monkeypatch.setattr(prefork, "_ROLE", "worker")
+        assert plat.jax_usable() is False
+        assert plat.on_tpu() is False
+        assert plat.device_info() is None
+        assert plat.prefer_batched_encode() is False
+        monkeypatch.setenv("WEED_EC_RECOVER_DEVICE", "1")
+        assert codec.recover_device_enabled() is False
+
+
+_CACHE_SCRIPT = """
+import os, sys
+from seaweedfs_tpu.util.platform import ensure_compile_cache
+before = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+path = ensure_compile_cache()
+import jax, jax.numpy as jnp
+assert jax.config.jax_compilation_cache_dir == path, (
+    jax.config.jax_compilation_cache_dir, path)
+if before is not None:  # set from outside: nothing was set in code
+    assert os.environ["JAX_COMPILATION_CACHE_DIR"] == before == path
+jax.jit(lambda x: x * 3 + 1)(jnp.arange(8)).block_until_ready()
+print(path)
+"""
+
+
+class TestCompileCache:
+    def test_env_directory_wins_and_gets_the_files(self, tmp_path):
+        want = str(tmp_path / "x")
+        proc = _run(["-c", _CACHE_SCRIPT],
+                    env_extra={"JAX_COMPILATION_CACHE_DIR": want,
+                               "JAX_PLATFORMS": "cpu"})
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        assert proc.stdout.strip().splitlines()[-1] == want
+        assert os.listdir(want), "no cache file appeared under the env dir"
+
+    def test_default_is_the_checkout(self):
+        proc = _run(["-c", _CACHE_SCRIPT],
+                    env_extra={"JAX_PLATFORMS": "cpu"},
+                    env_drop=("JAX_COMPILATION_CACHE_DIR",))
+        assert proc.returncode == 0, proc.stderr[-2000:]
+        path = proc.stdout.strip().splitlines()[-1]
+        assert path == os.path.join(REPO, ".jax_cache")
+        assert os.listdir(path)
+
+
+class TestNoSilentFallback:
+    @staticmethod
+    def _tpu_mesh():
+        dev = types.SimpleNamespace(platform="tpu")
+        arr = np.empty((1, 1), dtype=object)
+        arr[0, 0] = dev
+        return types.SimpleNamespace(devices=arr)
+
+    def test_fused_selftest_raises_on_a_tpu_mesh(self, monkeypatch):
+        from seaweedfs_tpu.ops import rs_pallas
+        from seaweedfs_tpu.parallel import mesh as mesh_mod
+
+        monkeypatch.setattr(mesh_mod, "_PALLAS_VERIFIED", set())
+
+        def no_mosaic(*a, **kw):
+            raise NotImplementedError("mosaic lowering failed")
+
+        monkeypatch.setattr(rs_pallas, "fused_encode_words", no_mosaic)
+        with pytest.raises(NotImplementedError):
+            mesh_mod.words_capable(self._tpu_mesh(), 1 << 20)
+
+        def wrong_bytes(matrix, words, **kw):
+            b, _, lw = words.shape
+            return (np.zeros((b, matrix.shape[0], lw), np.int32),
+                    np.zeros((b, sum(matrix.shape)), np.uint32))
+
+        monkeypatch.setattr(rs_pallas, "fused_encode_words", wrong_bytes)
+        with pytest.raises(RuntimeError, match="MISMATCHED"):
+            mesh_mod.words_capable(self._tpu_mesh(), 1 << 20)
+        assert not mesh_mod._PALLAS_VERIFIED
+
+    def test_recover_device_failure_is_counted_and_served(self,
+                                                          monkeypatch):
+        from seaweedfs_tpu.ops import codec
+        from seaweedfs_tpu.ops.rs_numpy import NumpyEncoder
+        from seaweedfs_tpu.storage.erasure_coding.recover import STATS
+
+        monkeypatch.setenv("WEED_EC_RECOVER_DEVICE", "1")
+        monkeypatch.setenv("WEED_EC_RECOVER_DEVICE_MIN_KB", "1")
+        rng = np.random.default_rng(7)
+        data = [rng.integers(0, 256, 4096, dtype=np.uint8)
+                for _ in range(10)]
+        full = NumpyEncoder(10, 4).encode(list(data) + [None] * 4)
+        survivors = tuple(range(1, 11))
+        inputs = np.stack([np.asarray(full[i]) for i in survivors])
+
+        before = codec.recover_device_counts()
+        out = codec.reconstruct_span(survivors, inputs, 0)
+        assert np.array_equal(out, data[0])
+        mid = codec.recover_device_counts()
+        assert mid["device_decodes"] == before["device_decodes"] + 1
+        assert mid["device_fallbacks"] == before["device_fallbacks"]
+
+        def broken(*a, **kw):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(codec, "_apply_rows_device", broken)
+        out = codec.reconstruct_span(survivors, inputs, 0)
+        assert np.array_equal(out, data[0])  # the read is still served
+        after = codec.recover_device_counts()
+        assert after["device_fallbacks"] == mid["device_fallbacks"] + 1
+        assert after["device_decodes"] == mid["device_decodes"]
+        snap = STATS.snapshot()  # what /admin/ec/recover_stats returns
+        assert snap["device_fallbacks"] == after["device_fallbacks"]
+
+    def test_failed_native_make_is_logged_once_with_stderr(
+            self, monkeypatch, capsys):
+        from seaweedfs_tpu.ops import native
+        from seaweedfs_tpu.util import glog
+
+        def failing_make(*a, **kw):
+            raise subprocess.CalledProcessError(
+                2, "make", stderr=b"ec_native.cpp:1: error: no such thing")
+
+        monkeypatch.setattr(subprocess, "run", failing_make)
+        monkeypatch.setattr(glog, "_out", sys.stderr)  # capsys' stream
+        native.build.cache_clear()
+        try:
+            assert native.build() is False
+            assert native.build() is False  # cached: not logged again
+        finally:
+            native.build.cache_clear()
+        err = capsys.readouterr().err
+        assert err.count("native build failed") == 1
+        assert "no such thing" in err
+
+
+class TestEcBackendTpuForcesRebuild:
+    def test_rebuild_runs_the_device_pipeline_and_says_so(
+            self, tmp_path, monkeypatch):
+        from seaweedfs_tpu.master.server import MasterServer
+        from seaweedfs_tpu.parallel import batched_encode
+        from seaweedfs_tpu.rpc.http_rpc import call
+        from seaweedfs_tpu.shell import commands as sh
+        from seaweedfs_tpu.util import platform as plat
+        from seaweedfs_tpu.volume_server.server import VolumeServer
+
+        master = MasterServer(port=0, pulse_seconds=0.2)
+        master.start()
+        (tmp_path / "vs").mkdir()
+        vs = VolumeServer([str(tmp_path / "vs")], master.address, port=0,
+                          pulse_seconds=0.2, ec_encoder_backend="tpu")
+        vs.start()
+        vs.heartbeat_once()
+        try:
+            call(master.address, "/vol/grow?collection=c&count=1",
+                 method="POST")
+            rng = np.random.default_rng(3)
+            stored = {}
+            for _ in range(6):
+                a = call(master.address, "/dir/assign?collection=c")
+                body = rng.bytes(200_000)
+                call(a["url"], "/" + a["fid"], raw=body, method="POST")
+                stored[a["fid"]] = body
+            vid = int(next(iter(stored)).split(",")[0])
+            env = sh.CommandEnv(master.address)
+            rs0 = call(vs.address, "/admin/ec/recover_stats")
+            # the link heuristic says "host": only the flag forces both
+            monkeypatch.setattr(plat, "prefer_batched_encode",
+                                lambda: False)
+            plan = sh.ec_encode(env, vid, collection="c")
+            gen = plan["generate"]
+            assert gen["backend"].startswith("device-")
+            assert gen["devices"] >= 1
+            assert gen["device"]["platform"] == "cpu"
+            assert gen["stage_stats"]["backend"] == gen["backend"]
+
+            calls = []
+            real = batched_encode.rebuild_shards
+
+            def spy(base, **kw):
+                calls.append(base)
+                return real(base, **kw)
+
+            monkeypatch.setattr(batched_encode, "rebuild_shards", spy)
+            call(vs.address, "/admin/ec/delete_shards",
+                 {"volume": vid, "collection": "c",
+                  "shard_ids": [0, 5, 10, 13]})
+            plan = sh.ec_rebuild(env, vid, collection="c")
+            assert len(calls) == 1
+            rb = plan["rebuild"]
+            assert rb["rebuilt_shard_ids"] == [0, 5, 10, 13]
+            assert rb["backend"] == "device-apply-xla"
+            assert rb["device"]["platform"] == "cpu"
+            assert rb["stage_stats"]["h2d_bytes"] > 0
+            for fid, body in stored.items():
+                assert call(vs.address, "/" + fid, parse=False) == body
+            rs = call(vs.address, "/admin/ec/recover_stats")
+            assert rs["device_fallbacks"] == rs0["device_fallbacks"]
+            assert rs["device"]["platform"] == "cpu"
+        finally:
+            vs.stop()
+            master.stop()
+
+
+def test_mesh_imports_clean_under_deprecation_errors():
+    proc = _run(["-W", "error::DeprecationWarning", "-c",
+                 "import seaweedfs_tpu.parallel.mesh"],
+                env_extra={"JAX_PLATFORMS": "cpu"})
+    assert proc.returncode == 0, proc.stderr[-2000:]
+
+
+def test_platform_module_has_no_subprocess_or_old_jax_paths():
+    def src(rel):
+        with open(os.path.join(REPO, rel)) as f:
+            return f.read()
+
+    assert "subprocess" not in src("seaweedfs_tpu/util/platform.py")
+    mesh_src = src("seaweedfs_tpu/parallel/mesh.py")
+    assert "experimental.shard_map" not in mesh_src
+    assert "check_rep" not in mesh_src
+    for rel in ("seaweedfs_tpu/parallel/batched_encode.py",
+                "seaweedfs_tpu/maintenance/deep_scrub.py"):
+        assert 'jax.devices("cpu")' not in src(rel)

@@ -445,7 +445,8 @@ def _shell_handlers(env):
         "ec.decode": lambda a: show(sh.ec_decode(
             env, int(a[0]), plan_only=plan(a))),
         "ec.rebuild": lambda a: show(sh.ec_rebuild(
-            env, int(a[0]), plan_only=plan(a))),
+            env, int(a[0]), collection=flag(a, "collection", ""),
+            plan_only=plan(a))),
         "ec.balance": lambda a: show(sh.ec_balance(
             env, plan_only=plan(a))),
         "ec.scrub": lambda a: show(sh.ec_scrub(
@@ -1688,6 +1689,10 @@ def main(argv=None):
         sorted(sub.choices))))
 
     args = parser.parse_args(argv)
+    # before any command can jit: the compile cache's fixed home
+    from seaweedfs_tpu.util.platform import ensure_compile_cache
+
+    ensure_compile_cache()
     if getattr(args, "workers", 0):
         # flag wins over env; RpcServer reads WEED_HTTP_WORKERS at bind
         os.environ["WEED_HTTP_WORKERS"] = str(args.workers)
