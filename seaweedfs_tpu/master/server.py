@@ -260,7 +260,18 @@ class MasterServer:
         # heartbeat state the nodes already report and routes vacuums
         # through the maintenance queue, where a volume-server worker
         # burns its own thread on the holder RPCs.
-        while not self._stop.wait(self.topo.pulse_seconds):
+        pulse = self.topo.pulse_seconds
+        woke = time.monotonic()
+        while not self._stop.wait(pulse):
+            # a wake-up more than a pulse late means this process (or
+            # the whole machine) stood still: nodes are not charged for
+            # silence the master could not have heard through
+            late = time.monotonic() - woke - pulse
+            woke += late + pulse
+            if late > pulse:
+                glog.warningf("reaper woke %.1f s late; not counted as "
+                              "node silence", late)
+                self.topo.forgive_silence(late)
             self.topo.reap_dead_nodes()
             try:
                 self._drive_shard_resize()

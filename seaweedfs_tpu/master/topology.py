@@ -305,6 +305,19 @@ class Topology:
 
             stats.ScaleClusterSizeGauge.set(len(self.nodes))
 
+    def forgive_silence(self, seconds: float):
+        """The master itself did not run for `seconds` (a stalled
+        machine, a paused VM, a stop-the-world device initialisation on a
+        shared host): that part of every node's silence is the master's
+        own and does not count towards the heartbeat timeout.  Without
+        this a stall longer than the timeout unregisters every alive
+        node the moment the master wakes, and assigns answer 503 until
+        the nodes' next heartbeats land."""
+        now = time.time()
+        with self.lock:
+            for n in self.nodes.values():
+                n.last_seen = min(now, n.last_seen + seconds)
+
     def reap_dead_nodes(self, timeout: Optional[float] = None):
         timeout = timeout or self.pulse_seconds * 3
         now = time.time()

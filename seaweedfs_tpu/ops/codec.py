@@ -163,21 +163,31 @@ def _apply_rows_host(rows: np.ndarray, inputs: np.ndarray) -> np.ndarray:
     return out
 
 
+def _discard_stage(stage: str, seconds: float):
+    """reconstruct_span's stage accumulator when the caller keeps none."""
+
+
 def _apply_rows_device(rows, to_dev: np.ndarray, out_rows: int, fam_name,
-                       survivors, slab_key) -> np.ndarray:
+                       survivors, slab_key, add_stage) -> np.ndarray:
     """(t, d) decode rows x (d, L) survivor spans on the default JAX
-    device: the Pallas kernel on a TPU, the SWAR XLA apply elsewhere."""
+    device: the Pallas kernel on a TPU, the SWAR XLA apply elsewhere.
+    Two stages for `add_stage`: decode_h2d (the upload; a resident-slab
+    hit records nothing) and decode_apply (apply_matrix called ->
+    np.asarray returned: dispatch, kernel, copy back)."""
     import jax
     import jax.numpy as jnp
 
+    from .. import tracing
     from .device_pool import get_pool
     from .rs_jax import apply_matrix
 
     dev0 = jax.devices()[0]
     method = "pallas" if dev0.platform == "tpu" else "swar"
     if slab_key is None:
-        return np.asarray(apply_matrix(
-            np.asarray(rows), to_dev, method=method))[:out_rows]
+        with tracing.stage("ec.recover.decode.apply", add_stage,
+                           "decode_apply", -1, to_dev.nbytes):
+            return np.asarray(apply_matrix(
+                np.asarray(rows), to_dev, method=method))[:out_rows]
     pool = get_pool()
     # survivor slabs upload to the default device; labeling the
     # transfers/residency keeps the recover traffic distinguishable
@@ -186,14 +196,18 @@ def _apply_rows_device(rows, to_dev: np.ndarray, out_rows: int, fam_name,
     key = ("recover", fam_name, tuple(survivors), slab_key)
 
     def _upload():
-        dev = jnp.asarray(to_dev)
+        with tracing.stage("ec.recover.decode.h2d", add_stage,
+                           "decode_h2d", -1, to_dev.nbytes):
+            dev = jnp.asarray(to_dev)
         pool.note_h2d(to_dev.nbytes, device=dev_label)
         return dev
 
     dev_in = pool.acquire_resident(key, _upload, to_dev.nbytes)
     try:
-        out = np.asarray(apply_matrix(
-            np.asarray(rows), dev_in, method=method))[:out_rows]
+        with tracing.stage("ec.recover.decode.apply", add_stage,
+                           "decode_apply", -1, to_dev.nbytes):
+            out = np.asarray(apply_matrix(
+                np.asarray(rows), dev_in, method=method))[:out_rows]
     finally:
         pool.release_resident(key)
     pool.note_d2h(out.nbytes, device=dev_label)
@@ -203,7 +217,8 @@ def _apply_rows_device(rows, to_dev: np.ndarray, out_rows: int, fam_name,
 def reconstruct_span(survivors, inputs: np.ndarray, target: int,
                      data_shards: int = 10,
                      total_shards: int = 14,
-                     slab_key=None, family=None) -> np.ndarray:
+                     slab_key=None, family=None,
+                     add_stage=_discard_stage) -> np.ndarray:
     """Target-row reconstruction: rebuild ONE shard's span from the
     (d, L) survivor stack via the cached decode plan — one GF mat-vec,
     never a full Reconstruct.  `inputs[i]` must be the span read from
@@ -223,7 +238,10 @@ def reconstruct_span(survivors, inputs: np.ndarray, target: int,
     keeps the classic (total, data) path; other families supply their own
     cached decode plan (each family's cheap inversion), and vector codes
     (sub_shards > 1) run the same kernels over the lane-interleaved view
-    of the survivor stack."""
+    of the survivor stack.
+
+    add_stage: `(stage, seconds)` accumulator of the device path's two
+    stages (RecoverStats.add_stage on the degraded-read path)."""
     fam_name = getattr(family, "name", None)
     if family is not None and fam_name != "rs_vandermonde":
         rows = family.decode_rows(tuple(survivors), (target,))
@@ -242,7 +260,7 @@ def reconstruct_span(survivors, inputs: np.ndarray, target: int,
             and recover_device_enabled():
         try:
             out = _apply_rows_device(rows, to_dev, out_rows, fam_name,
-                                     survivors, slab_key)
+                                     survivors, slab_key, add_stage)
         except Exception as e:
             # mid-incident the read must still be served, and the host
             # path always works — but a device failure is never silent

@@ -92,20 +92,22 @@ def batched_crc32c_raw(data: jax.Array) -> jax.Array:
     length = data.shape[-1]
     nseg, seg = _plan_segments(length)
     pad = nseg * seg - length
-    if pad:
-        data = jnp.pad(data, [(0, 0)] * (data.ndim - 1) + [(pad, 0)])
-    lead = data.shape[:-1]
-    x = data.reshape(*lead, nseg, seg)
-    shifts = jnp.arange(8, dtype=jnp.uint8)
-    # bit-PLANE-major expansion: (.., nseg, 8, seg) keeps seg minormost, so
-    # the merge into (.., nseg, 8*seg) is relayout-free (byte-major order
-    # would interleave bit and byte axes and force a full copy of the 8x
-    # expanded tensor — measured 6x slower on TPU v5e)
-    bits = ((x[..., None, :] >> shifts[:, None]) & 1).astype(jnp.int8)
-    bits = bits.reshape(*lead, nseg, 8 * seg)
-    w = jnp.asarray(_segment_matrix(seg))  # (8*seg, 32) plane-major rows
-    state = jnp.matmul(bits, w, preferred_element_type=jnp.int32) & 1
-    return combine_tree(state, seg, nseg)
+    with jax.named_scope("ec.crc32c"):
+        if pad:
+            data = jnp.pad(data, [(0, 0)] * (data.ndim - 1) + [(pad, 0)])
+        lead = data.shape[:-1]
+        x = data.reshape(*lead, nseg, seg)
+        shifts = jnp.arange(8, dtype=jnp.uint8)
+        # bit-PLANE-major expansion: (.., nseg, 8, seg) keeps seg
+        # minormost, so the merge into (.., nseg, 8*seg) is relayout-free
+        # (byte-major order would interleave bit and byte axes and force a
+        # full copy of the 8x expanded tensor — measured 6x slower on TPU
+        # v5e)
+        bits = ((x[..., None, :] >> shifts[:, None]) & 1).astype(jnp.int8)
+        bits = bits.reshape(*lead, nseg, 8 * seg)
+        w = jnp.asarray(_segment_matrix(seg))  # (8*seg, 32) plane-major
+        state = jnp.matmul(bits, w, preferred_element_type=jnp.int32) & 1
+        return combine_tree(state, seg, nseg)
 
 
 def combine_tree(state, seg: int, nseg: int):

@@ -48,7 +48,7 @@ from typing import Optional
 
 import numpy as np
 
-from .. import profiling
+from .. import profiling, tracing
 from ..qos import lanes as _lanes
 
 DATA_SHARDS = 10
@@ -367,18 +367,31 @@ def encode_volumes(bases: list[str], large_block: Optional[int] = None,
             _ShardFileSet(p.base, to_ext).close()
             out[p.base] = [0] * TOTAL_SHARDS
         return out
-    if host_codec:
-        return _encode_units_host(plans, units, chunk, host_codec,
-                                  stage_stats)
-    _, _, flush_bytes, drop_cache = _write_knobs()
-    pacer = _WritebackPacer(flush_bytes, drop_cache)
-    writers = {vi: _ShardFileSet(
-                   p.base, to_ext,
-                   (p.rows[-1][1] + p.rows[-1][2]) if p.rows else 0,
-                   pacer)
-               for vi, p in enumerate(plans)}
-    return _encode_units_device(plans, units, chunk, writers, mesh,
-                                batch_units, stage_stats)
+    # the seal's root span, current on this thread while either pipeline
+    # runs: their worker threads install it too, so a sampled seal shows
+    # its per-batch stages, and the per-stage totals hang under it
+    root = tracing.start("ec.encode_volumes",
+                         tags={"volumes": len(plans), "units": len(units)})
+    prev = tracing.swap(root)
+    try:
+        if host_codec:
+            return _encode_units_host(plans, units, chunk, host_codec,
+                                      stage_stats)
+        _, _, flush_bytes, drop_cache = _write_knobs()
+        pacer = _WritebackPacer(flush_bytes, drop_cache)
+        writers = {vi: _ShardFileSet(
+                       p.base, to_ext,
+                       (p.rows[-1][1] + p.rows[-1][2]) if p.rows else 0,
+                       pacer)
+                   for vi, p in enumerate(plans)}
+        return _encode_units_device(plans, units, chunk, writers, mesh,
+                                    batch_units, stage_stats)
+    except BaseException as e:
+        root.status = f"error: {type(e).__name__}"
+        raise
+    finally:
+        tracing.restore(prev)
+        root.finish()
 
 
 class _PipelineIO:
@@ -410,9 +423,18 @@ class _PipelineIO:
         self.writers, self.b = writers, b
         self.layout = layout
         self.pool = pool
+        # the seal's span (encode_volumes'), installed on worker threads
+        self.root = tracing.current()
         self.n_batches = (len(units) + b - 1) // b
         self.dats = [open(p.base + ".dat", "rb") for p in plans]
-        self.timers = {"read": 0.0, "dispatch": 0.0, "encode_crc": 0.0,
+        # busy seconds per stage.  read = the reader's per-unit block
+        # (read_dat + read_data_write + the loop around them);
+        # dispatch = upload + step call (h2d is its upload);
+        # encode_crc = the completion thread's per-batch block (d2h_wait
+        # = blocked in the copy back, crc_host = the host work after it)
+        self.timers = {"read": 0.0, "read_dat": 0.0, "read_data_write": 0.0,
+                       "read_slot_wait": 0.0, "dispatch": 0.0, "h2d": 0.0,
+                       "encode_crc": 0.0, "d2h_wait": 0.0, "crc_host": 0.0,
                        "write": 0.0}
         self.tlock = threading.Lock()
         shape = (b, DATA_SHARDS, chunk) if layout == "bk" \
@@ -432,6 +454,11 @@ class _PipelineIO:
         self.stop = threading.Event()
         self._rt = threading.Thread(target=self._reader, daemon=True)
         self._wt = threading.Thread(target=self._writer, daemon=True)
+
+    def add_time(self, key: str, seconds: float):
+        """The stage accumulator handed to tracing.stage()."""
+        with self.tlock:
+            self.timers[key] = self.timers.get(key, 0.0) + seconds
 
     def put(self, q, item) -> bool:
         while not self.stop.is_set():
@@ -461,65 +488,87 @@ class _PipelineIO:
             row[got:] = 0
         return min(self.chunk, self.plans[u.vol].dat_size - start)
 
+    def _read_unit(self, u: _Unit, k: int, buf: np.ndarray, k_max: int):
+        """One unit: its real rows from the .dat into the staging slot
+        and out to the data-shard files, padding rows zeroed.  The split
+        of the two is taken with bare clock readings, one pair a row: a
+        span per 1 MiB row would cost more than it tells."""
+        w = self.writers[u.vol]
+        t_dat = t_write = 0.0
+        t = time.perf_counter()
+        for i in range(u.real_rows):
+            row = buf[i, k] if self.layout == "kb" else buf[k, i]
+            real = self._fill_row(u, i, row)
+            t1 = time.perf_counter()
+            t_dat += t1 - t
+            w.write(i, [row[:real]], u.shard_off)
+            t = time.perf_counter()
+            t_write += t - t1
+        # zero padding rows up to the compacted height: they feed the
+        # parity math but neither files nor CRCs (files are ftruncate
+        # zeros, CRC is the cached zeros CRC)
+        for i in range(u.real_rows, k_max):
+            if self.layout == "kb":
+                buf[i, k].fill(0)
+            else:
+                buf[k, i].fill(0)
+        with self.tlock:
+            self.timers["read_dat"] += t_dat
+            self.timers["read_data_write"] += t_write
+
     def _reader(self):
+        tracing.swap(self.root)
         try:
             for n in range(self.n_batches):
                 batch = self.units[n * self.b:(n + 1) * self.b]
-                slot = self.get(self.free_slots)
+                with tracing.stage("ec.encode.stage_wait", self.add_time,
+                                   "read_slot_wait", n):
+                    slot = self.get(self.free_slots)
                 if slot is None:
                     return
                 buf = slot.payload
-                t0 = time.perf_counter()
                 if self.layout == "kb":
                     k_max = max(u.real_rows for u in batch)
                 else:
                     k_max = DATA_SHARDS
                 for k, u in enumerate(batch):
-                    w = self.writers[u.vol]
-                    for i in range(u.real_rows):
-                        row = buf[i, k] if self.layout == "kb" \
-                            else buf[k, i]
-                        real = self._fill_row(u, i, row)
-                        w.write(i, [row[:real]], u.shard_off)
-                    # zero padding rows up to the compacted height: they
-                    # feed the parity math but neither files nor CRCs
-                    # (files are ftruncate zeros, CRC is the cached
-                    # zeros CRC)
-                    for i in range(u.real_rows, k_max):
-                        if self.layout == "kb":
-                            buf[i, k].fill(0)
-                        else:
-                            buf[k, i].fill(0)
-                with self.tlock:
-                    self.timers["read"] += time.perf_counter() - t0
+                    with tracing.stage("ec.encode.read", self.add_time,
+                                       "read", n, u.real_rows * self.chunk):
+                        self._read_unit(u, k, buf, k_max)
                 if not self.put(self.ready, (slot, batch, k_max)):
                     return
             self.put(self.ready, None)
         except BaseException as e:  # propagate to the main thread
             self.errors.append(e)
             self.stop.set()
+        finally:
+            tracing.restore(None)
 
     def _writer(self):
+        tracing.swap(self.root)
         try:
+            n = 0
             while True:
                 item = self.get(self.parity_q)
                 if item is None:
                     return
                 parity, batch = item
-                t0 = time.perf_counter()
-                for k, u in enumerate(batch):
-                    if u.real_rows == 0:
-                        continue  # parity of all-zero rows is zero:
-                        #           already on disk via ftruncate
-                    w = self.writers[u.vol]
-                    for i in range(PARITY_SHARDS):
-                        w.write(DATA_SHARDS + i, [parity[k, i]],
-                                u.shard_off)
-                with self.tlock:
-                    self.timers["write"] += time.perf_counter() - t0
+                with tracing.stage("ec.encode.write", self.add_time,
+                                   "write", n):
+                    for k, u in enumerate(batch):
+                        if u.real_rows == 0:
+                            continue  # parity of all-zero rows is zero:
+                            #           already on disk via ftruncate
+                        w = self.writers[u.vol]
+                        for i in range(PARITY_SHARDS):
+                            w.write(DATA_SHARDS + i, [parity[k, i]],
+                                    u.shard_off)
+                n += 1
         except BaseException as e:
             self.errors.append(e)
             self.stop.set()
+        finally:
+            tracing.restore(None)
 
     def start(self):
         self._rt.start()
@@ -661,6 +710,8 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
     io = _PipelineIO(plans, units, chunk, writers, b, layout, pool,
                      n_slots=n_slots)
     timers = io.timers
+    add_time = io.add_time
+    root = io.root
 
     # donated output-slot ring (pooled path): depth+1 device slots the
     # persistent step aliases its parity into — the donation swap means
@@ -688,8 +739,8 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
     k_shapes: set = set()
     kernel_lats: list = []  # host-timed dispatch->ready per batch
 
-    def _complete(slot, batch, out, crc_dev, t_disp, k_rows):
-        """Synchronize one batch: D2H, per-chunk CRCs chained into the
+    def _complete(n, slot, batch, out, crc_dev, t_disp, k_rows):
+        """Synchronize batch n: D2H, per-chunk CRCs chained into the
         rolling shard-file CRCs (FIFO order — CRC chaining is order-
         dependent), slots recycled, parity handed to the writer."""
         buf = slot.payload
@@ -698,148 +749,169 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
             parity = None
             fin = None
             if out is not None:
-                # copies out of the donated slot (required: the slot is
-                # re-donated for a later batch while the writer thread
-                # still holds this parity); blocks until compute done
-                parity32 = np.array(out.payload)
+                with tracing.stage("ec.encode.d2h_wait", add_time,
+                                   "d2h_wait", n):
+                    # copies out of the donated slot (required: the slot
+                    # is re-donated for a later batch while the writer
+                    # thread still holds this parity); blocks until
+                    # compute done
+                    parity32 = np.array(out.payload)
+                    raw = np.asarray(crc_dev) if fused else None
                 lat = time.perf_counter() - t_disp
                 kernel_lats.append(lat)
                 profiling.record_device_batch(lat, units=len(batch),
                                               k=k_rows,
                                               devices=mesh.devices.size)
-                pool.note_d2h(parity32.nbytes, device=dev_label)
-                out_ring.put(out)
-                parity = parity32.view(np.uint8).reshape(
-                    PARITY_SHARDS, b, chunk)
+            with tracing.stage("ec.encode.crc", add_time, "crc_host", n):
+                if out is not None:
+                    pool.note_d2h(parity32.nbytes, device=dev_label)
+                    out_ring.put(out)
+                    parity = parity32.view(np.uint8).reshape(
+                        PARITY_SHARDS, b, chunk)
+                    if fused:
+                        pool.note_d2h(raw.nbytes, device=dev_label)
+                        fin = finalize(raw, chunk)  # (k_rows + 4, b)
                 if fused:
-                    raw = np.asarray(crc_dev)
-                    pool.note_d2h(raw.nbytes, device=dev_label)
-                    fin = finalize(raw, chunk)  # (k_rows + 4, b)
-            if fused:
-                # the device already CRC'd every row (padding rows were
-                # zeroed in staging, so their image equals the cached
-                # zeros CRC) — only the O(1)-per-chunk combines remain
-                for k, u in enumerate(batch):
-                    w = writers[u.vol]
-                    r = u.real_rows
-                    for i in range(DATA_SHARDS):
-                        c = int(fin[i, k]) if i < k_rows else zcrc
-                        w.crcs[i] = crc_host.crc32c_combine(
-                            w.crcs[i], c, chunk)
-                    for j in range(PARITY_SHARDS):
-                        c = int(fin[k_rows + j, k]) if r else zcrc
-                        w.crcs[DATA_SHARDS + j] = crc_host.crc32c_combine(
-                            w.crcs[DATA_SHARDS + j], c, chunk)
-            else:
-                t_crc = time.perf_counter()
-                for k, u in enumerate(batch):
-                    w = writers[u.vol]
-                    r = u.real_rows
-                    for i in range(DATA_SHARDS):
-                        c = crc_host.crc32c(buf[i, k]) if i < r else zcrc
-                        w.crcs[i] = crc_host.crc32c_combine(
-                            w.crcs[i], c, chunk)
-                    for j in range(PARITY_SHARDS):
-                        c = crc_host.crc32c(parity[j, k]) if r else zcrc
-                        w.crcs[DATA_SHARDS + j] = crc_host.crc32c_combine(
-                            w.crcs[DATA_SHARDS + j], c, chunk)
-                with io.tlock:
+                    # the device already CRC'd every row (padding rows
+                    # were zeroed in staging, so their image equals the
+                    # cached zeros CRC) — only the O(1)-per-chunk
+                    # combines remain
+                    for k, u in enumerate(batch):
+                        w = writers[u.vol]
+                        r = u.real_rows
+                        for i in range(DATA_SHARDS):
+                            c = int(fin[i, k]) if i < k_rows else zcrc
+                            w.crcs[i] = crc_host.crc32c_combine(
+                                w.crcs[i], c, chunk)
+                        for j in range(PARITY_SHARDS):
+                            c = int(fin[k_rows + j, k]) if r else zcrc
+                            w.crcs[DATA_SHARDS + j] = \
+                                crc_host.crc32c_combine(
+                                    w.crcs[DATA_SHARDS + j], c, chunk)
+                else:
+                    t_crc = time.perf_counter()
+                    for k, u in enumerate(batch):
+                        w = writers[u.vol]
+                        r = u.real_rows
+                        for i in range(DATA_SHARDS):
+                            c = crc_host.crc32c(buf[i, k]) if i < r \
+                                else zcrc
+                            w.crcs[i] = crc_host.crc32c_combine(
+                                w.crcs[i], c, chunk)
+                        for j in range(PARITY_SHARDS):
+                            c = crc_host.crc32c(parity[j, k]) if r \
+                                else zcrc
+                            w.crcs[DATA_SHARDS + j] = \
+                                crc_host.crc32c_combine(
+                                    w.crcs[DATA_SHARDS + j], c, chunk)
                     # distinct timer: this key's absence from the stage
                     # stats is the proof the fused path took host CRC
                     # off the critical path
-                    timers["host_crc"] = timers.get("host_crc", 0.0) \
-                        + (time.perf_counter() - t_crc)
-            with io.tlock:
-                timers["encode_crc"] += time.perf_counter() - t0
+                    add_time("host_crc", time.perf_counter() - t_crc)
+            add_time("encode_crc", time.perf_counter() - t0)
             io.free_slots.put(slot)
             if parity is not None:
                 # (4, B, L) -> writer's [k][i] indexing as a free view
                 io.put(io.parity_q, (parity.transpose(1, 0, 2), batch))
         else:
             parity_dev, crc_dev = out
-            # blocks until compute done; sharded gathers can come back
-            # non-contiguous, and file writes need a contiguous buffer
-            parity = np.ascontiguousarray(np.asarray(parity_dev))
+            with tracing.stage("ec.encode.d2h_wait", add_time, "d2h_wait",
+                               n):
+                # blocks until compute done; sharded gathers can come
+                # back non-contiguous, and file writes need a contiguous
+                # buffer
+                parity = np.ascontiguousarray(np.asarray(parity_dev))
+                raw = np.asarray(crc_dev)
             lat = time.perf_counter() - t_disp
             kernel_lats.append(lat)
             profiling.record_device_batch(lat, units=len(batch), k=k_rows,
                                           devices=mesh.devices.size)
-            pool.note_d2h(parity.nbytes, device=dev_label)
-            if use_words:  # packed int32 parity words -> bytes
-                parity = parity.view(np.uint8).reshape(
-                    parity.shape[0], PARITY_SHARDS, chunk)
-            crcs = finalize(crc_dev, chunk)
-            io.free_slots.put(slot)  # device consumed the transfer
-            for k, u in enumerate(batch):
-                w = writers[u.vol]
-                for s in range(TOTAL_SHARDS):
-                    w.crcs[s] = crc_host.crc32c_combine(
-                        w.crcs[s], int(crcs[k, s]), chunk)
-            with io.tlock:
-                timers["encode_crc"] += time.perf_counter() - t0
+            with tracing.stage("ec.encode.crc", add_time, "crc_host", n):
+                pool.note_d2h(parity.nbytes + raw.nbytes, device=dev_label)
+                if use_words:  # packed int32 parity words -> bytes
+                    parity = parity.view(np.uint8).reshape(
+                        parity.shape[0], PARITY_SHARDS, chunk)
+                crcs = finalize(raw, chunk)
+                io.free_slots.put(slot)  # device consumed the transfer
+                for k, u in enumerate(batch):
+                    w = writers[u.vol]
+                    for s in range(TOTAL_SHARDS):
+                        w.crcs[s] = crc_host.crc32c_combine(
+                            w.crcs[s], int(crcs[k, s]), chunk)
+            add_time("encode_crc", time.perf_counter() - t0)
             io.put(io.parity_q, (parity, batch))
 
     def _completion():
+        tracing.swap(root)
         try:
+            n = 0
             while True:
                 item = io.get(done_q)
                 if item is None:
                     return
-                _complete(*item)
+                _complete(n, *item)
+                n += 1
         except BaseException as e:
             io.errors.append(e)
             io.stop.set()
+        finally:
+            tracing.restore(None)
 
     ct = threading.Thread(target=_completion, daemon=True)
     io.start()
     ct.start()
     try:
+        n = -1
         while not io.stop.is_set():
             item = io.get(io.ready)
             if item is None:
                 break
+            n += 1
             slot, batch, k_max = item
             buf = slot.payload
             # background device lane: bulk encode yields to in-flight
             # foreground (degraded-read recover) decodes per batch
             lane_wait = _lanes.LANES.background_checkpoint()
             if lane_wait:
-                with io.tlock:
-                    timers["lane_wait"] = timers.get("lane_wait", 0.0) \
-                        + lane_wait
+                add_time("lane_wait", lane_wait)
             t0 = time.perf_counter()
             crc_dev = None
-            if pooled:
-                out = None
-                if k_max > 0:
-                    k_shapes.add(k_max)
-                    words = buf.view(np.int32)[:k_max]
-                    if zero_copy:
-                        din = jax.dlpack.from_dlpack(words)
-                    else:
-                        din = jax.device_put(
-                            words, dev0 if single else sharding_kb)
-                        pool.note_h2d(words.nbytes, device=dev_label)
-                    out = io.get(out_ring)  # backpressure at `depth`
-                    if out is None:
-                        break
-                    # donation swap: the step aliases its result into
-                    # the slot's buffer; the old handle is dead
-                    if fused:
-                        out.payload, crc_dev = step(din, out.payload)
-                    else:
-                        out.payload = step(din, out.payload)
-            else:
-                if use_words:
-                    # pin to the mesh's device: the caller may run
-                    # several 1-device meshes side by side
-                    din = jax.device_put(buf.view(np.int32), dev0)
+            with tracing.stage("ec.encode.dispatch", add_time, "dispatch",
+                               n, buf.nbytes):
+                if pooled:
+                    out = None
+                    if k_max > 0:
+                        k_shapes.add(k_max)
+                        words = buf.view(np.int32)[:k_max]
+                        with tracing.stage("ec.encode.h2d", add_time,
+                                           "h2d", n, words.nbytes):
+                            if zero_copy:
+                                din = jax.dlpack.from_dlpack(words)
+                            else:
+                                din = jax.device_put(
+                                    words, dev0 if single else sharding_kb)
+                                pool.note_h2d(words.nbytes,
+                                              device=dev_label)
+                        out = io.get(out_ring)  # backpressure at `depth`
+                        if out is None:
+                            break
+                        # donation swap: the step aliases its result into
+                        # the slot's buffer; the old handle is dead
+                        if fused:
+                            out.payload, crc_dev = step(din, out.payload)
+                        else:
+                            out.payload = step(din, out.payload)
                 else:
-                    din = jax.device_put(buf, sharding)
-                pool.note_h2d(buf.nbytes, device=dev_label)
-                out = step(din)
-            with io.tlock:
-                timers["dispatch"] += time.perf_counter() - t0
+                    with tracing.stage("ec.encode.h2d", add_time, "h2d",
+                                       n, buf.nbytes):
+                        if use_words:
+                            # pin to the mesh's device: the caller may
+                            # run several 1-device meshes side by side
+                            din = jax.device_put(buf.view(np.int32), dev0)
+                        else:
+                            din = jax.device_put(buf, sharding)
+                        pool.note_h2d(buf.nbytes, device=dev_label)
+                    out = step(din)
             if not io.put(done_q, (slot, batch, out, crc_dev, t0, k_max)):
                 break
         io.put(done_q, None)
@@ -857,20 +929,6 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
     result = io.result()
 
     wall = time.perf_counter() - wall0
-    # XLA cost analysis once per compiled geometry (pooled SWAR path;
-    # StableHLO-level, no backend compile — see mesh.step_cost_analysis)
-    kernel_cost = {}
-    if pooled:
-        from .mesh import step_cost_analysis
-
-        for k in sorted(k_shapes):
-            geom = f"k{k}xb{b}xw{width}" + ("f" if fused else "")
-            entry = step_cost_analysis(
-                step, geom,
-                jax.ShapeDtypeStruct((k, b, width), np.int32),
-                jax.ShapeDtypeStruct((PARITY_SHARDS, b, width), np.int32))
-            if entry is not None:
-                kernel_cost[geom] = entry
     if stage_stats is not None:
         stage_stats.update({k: round(v, 3) for k, v in timers.items()})
         stage_stats["wall"] = round(wall, 3)
@@ -900,13 +958,25 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
                              int(len(lats) * 0.95))] * 1e3, 3),
                 "dispatch_ready_max_ms": round(lats[-1] * 1e3, 3),
             }
-        if kernel_cost:
-            stage_stats["kernel_cost"] = kernel_cost
         stage_stats["pool"] = pool.snapshot()
+    _publish_stage_seconds(timers, wall, root)
+    return result
+
+
+def _publish_stage_seconds(timers: dict, wall: float, root):
+    """The end of either pipeline: the stage seconds that stage() added
+    up become the `ec_encode_stage_seconds` gauge (the last seal's, with
+    its wall) and one child span each of the seal's root, so a trace of
+    the seal reads the same whichever pipeline ran it."""
     from ..stats import metrics as stats
+
     for k, v in timers.items():
         stats.EcEncodeStageSeconds.labels(k).set(round(v, 3))
-    return result
+    stats.EcEncodeStageSeconds.labels("wall").set(round(wall, 3))
+    if root is not None:
+        for k, v in timers.items():
+            tracing.record_span("ec.encode." + k, v, parent=root,
+                                tags={"total": 1})
 
 
 # Host-pipeline work sizing: a span batches consecutive equal-block rows
@@ -1050,6 +1120,11 @@ def _encode_units_host(plans, units, chunk, host_codec,
             for vi, p in enumerate(plans)}
     timers = {"read": 0.0, "encode_crc": 0.0, "write": 0.0, "flush": 0.0}
     tlock = threading.Lock()
+    root = tracing.current()  # the seal's span, for the worker threads
+
+    def add_time(key: str, seconds: float):
+        with tlock:
+            timers[key] += seconds
 
     def read_item(w: _HostWork, flat: np.ndarray) -> np.ndarray:
         """Fill (and return) the item's (rows, 10, length) view of the
@@ -1089,42 +1164,52 @@ def _encode_units_host(plans, units, chunk, host_codec,
         """Encode stage: parity+CRC into a pooled parity slot.  The slot
         travels with the item to the writer stage (write-behind) or is
         released right after the inline write."""
-        t0 = _t.perf_counter()
-        while True:  # stop-aware: an error elsewhere must not wedge us
-            try:
-                pbuf = parity_free.get(timeout=0.5)
-                break
-            except queue.Empty:
-                if stop.is_set():
-                    raise RuntimeError("encode pipeline stopped")
-        need = w.rows * PARITY_SHARDS * w.length
-        parity = pbuf[:need].reshape(w.rows, PARITY_SHARDS, w.length)
-        if fused:
-            crcs = enc.encode_rows(parity_matrix, data, parity)
-        else:
-            crcs = [0] * TOTAL_SHARDS
-            for r in range(w.rows):
-                parity[r] = enc._apply(parity_matrix, data[r])
-                for i in range(DATA_SHARDS):
-                    crcs[i] = crc_host.crc32c(data[r, i], crcs[i])
-                for i in range(PARITY_SHARDS):
-                    crcs[DATA_SHARDS + i] = crc_host.crc32c(
-                        parity[r, i], crcs[DATA_SHARDS + i])
-        with tlock:
-            timers["encode_crc"] += _t.perf_counter() - t0
+        prev = tracing.swap(root)  # a pool thread: hand it the seal's span
+        try:
+            with tracing.stage("ec.encode.crc", add_time, "encode_crc",
+                               w.rows, data.nbytes):
+                while True:  # stop-aware: an error elsewhere must not
+                    #          wedge us
+                    try:
+                        pbuf = parity_free.get(timeout=0.5)
+                        break
+                    except queue.Empty:
+                        if stop.is_set():
+                            raise RuntimeError("encode pipeline stopped")
+                need = w.rows * PARITY_SHARDS * w.length
+                parity = pbuf[:need].reshape(w.rows, PARITY_SHARDS,
+                                             w.length)
+                if fused:
+                    crcs = enc.encode_rows(parity_matrix, data, parity)
+                else:
+                    crcs = [0] * TOTAL_SHARDS
+                    for r in range(w.rows):
+                        parity[r] = enc._apply(parity_matrix, data[r])
+                        for i in range(DATA_SHARDS):
+                            crcs[i] = crc_host.crc32c(data[r, i], crcs[i])
+                        for i in range(PARITY_SHARDS):
+                            crcs[DATA_SHARDS + i] = crc_host.crc32c(
+                                parity[r, i], crcs[DATA_SHARDS + i])
+        finally:
+            tracing.restore(prev)
         return pbuf, parity, crcs
 
     def write_item(w: _HostWork, data: np.ndarray, parity: np.ndarray):
         """Write stage body: the item's data+parity shard spans."""
-        t0 = _t.perf_counter()
-        v = vols[w.vol]
-        for i in range(DATA_SHARDS):
-            v.write(i, [data[r, i] for r in range(w.rows)], w.shard_off)
-        for i in range(PARITY_SHARDS):
-            v.write(DATA_SHARDS + i,
-                    [parity[r, i] for r in range(w.rows)], w.shard_off)
-        with tlock:
-            timers["write"] += _t.perf_counter() - t0
+        prev = tracing.swap(root)
+        try:
+            with tracing.stage("ec.encode.write", add_time, "write",
+                               w.rows, data.nbytes + parity.nbytes):
+                v = vols[w.vol]
+                for i in range(DATA_SHARDS):
+                    v.write(i, [data[r, i] for r in range(w.rows)],
+                            w.shard_off)
+                for i in range(PARITY_SHARDS):
+                    v.write(DATA_SHARDS + i,
+                            [parity[r, i] for r in range(w.rows)],
+                            w.shard_off)
+        finally:
+            tracing.restore(prev)
 
     def encode_write_item(w: _HostWork, data: np.ndarray) -> list[int]:
         """Two-stage form (WEED_EC_WRITE_BEHIND=0): the compute worker
@@ -1163,9 +1248,9 @@ def _encode_units_host(plans, units, chunk, host_codec,
         if nworkers == 1:
             flat = np.empty(slot_bytes, dtype=np.uint8)
             for w in items:
-                t0 = _t.perf_counter()
-                data = read_item(w, flat)
-                timers["read"] += _t.perf_counter() - t0
+                with tracing.stage("ec.encode.read", add_time, "read",
+                                   w.rows):
+                    data = read_item(w, flat)
                 pbuf, parity, crcs = encode_item(w, data)
                 write_item(w, data, parity)
                 parity_free.put(pbuf)
@@ -1179,21 +1264,23 @@ def _encode_units_host(plans, units, chunk, host_codec,
             write_q: "queue.Queue" = queue.Queue(maxsize=2 * nwriters + 2)
 
             def reader():
+                tracing.swap(root)
                 try:
                     for w in items:
                         flat = qget(free_slots)
                         if flat is None:
                             return
-                        t0 = _t.perf_counter()
-                        data = read_item(w, flat)
-                        with tlock:
-                            timers["read"] += _t.perf_counter() - t0
+                        with tracing.stage("ec.encode.read", add_time,
+                                           "read", w.rows):
+                            data = read_item(w, flat)
                         if not qput(ready, (flat, data, w)):
                             return
                     qput(ready, None)
                 except BaseException as e:
                     errors.append(e)
                     stop.set()
+                finally:
+                    tracing.restore(None)
 
             # the writer pool: items arrive in stripe order (the main
             # loop combines and enqueues in submission order), so a
@@ -1202,25 +1289,25 @@ def _encode_units_host(plans, units, chunk, host_codec,
             _GROUP_MAX = 8  # spans per coalesced group
 
             def write_group(group):
-                t0 = _t.perf_counter()
-                v = vols[group[0][0].vol]
-                base_off = group[0][0].shard_off
-                for s in range(TOTAL_SHARDS):
-                    iovs = []
-                    for (w, _flat, data, parity, _pbuf) in group:
-                        src = data if s < DATA_SHARDS else parity
-                        j = s if s < DATA_SHARDS else s - DATA_SHARDS
-                        for r in range(w.rows):
-                            iovs.append(src[r, j])
-                    v.write(s, iovs, base_off)
-                with tlock:
-                    timers["write"] += _t.perf_counter() - t0
+                with tracing.stage("ec.encode.write", add_time, "write",
+                                   len(group)):
+                    v = vols[group[0][0].vol]
+                    base_off = group[0][0].shard_off
+                    for s in range(TOTAL_SHARDS):
+                        iovs = []
+                        for (w, _flat, data, parity, _pbuf) in group:
+                            src = data if s < DATA_SHARDS else parity
+                            j = s if s < DATA_SHARDS else s - DATA_SHARDS
+                            for r in range(w.rows):
+                                iovs.append(src[r, j])
+                        v.write(s, iovs, base_off)
                 for (_w, flat, _data, _parity, pbuf) in group:
                     free_slots.put(flat)
                     parity_free.put(pbuf)
 
             def writer_loop():
                 carry = None
+                tracing.swap(root)
                 try:
                     while True:
                         if carry is not None:
@@ -1253,6 +1340,8 @@ def _encode_units_host(plans, units, chunk, host_codec,
                 except BaseException as e:
                     errors.append(e)
                     stop.set()
+                finally:
+                    tracing.restore(None)
 
             rt = threading.Thread(target=reader, daemon=True)
             rt.start()
@@ -1334,22 +1423,9 @@ def _encode_units_host(plans, units, chunk, host_codec,
                 round(timers[k] / wall, 3) if wall > 0 else 0.0)
     from ..stats import metrics as stats
     stats.EcEncodeBytesCounter.inc(sum(p.dat_size for p in plans))
-    for k, v in timers.items():
-        stats.EcEncodeStageSeconds.labels(k).set(round(v, 3))
     if pacer.flushes:
         stats.EcWritebackFlushCounter.inc(pacer.flushes)
-    # the stage timers aggregate busy seconds across worker threads, so
-    # they become synthesised child spans of one encode root (recorded
-    # before the root finishes — retention is decided at the root)
-    from .. import tracing
-    root = tracing.start(
-        "ec.encode_volumes",
-        tags={"volumes": len(plans), "workers": nworkers,
-              "writers": nwriters, "items": len(items)})
-    root.start_ts -= wall
-    for k, v in timers.items():
-        tracing.record_span(f"ec.encode.{k}", v, parent=root)
-    root.finish(duration=wall)
+    _publish_stage_seconds(timers, wall, root)
     return {p.base: vols[vi].crcs for vi, p in enumerate(plans)}
 
 

@@ -231,14 +231,17 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
         return acc
 
     def _fused(data32, out):
-        parity = _parity(data32, out)
-        full = jnp.concatenate([data32, parity], axis=0)  # (k+p, B, W)
-        # int32 words -> the row's byte stream: little-endian byte order
-        # within a word matches memory order, so the bitcast+reshape is
-        # layout-free
-        byts = jax.lax.bitcast_convert_type(full, jnp.uint8)
-        byts = byts.reshape(full.shape[0], full.shape[1], -1)
-        return parity, batched_crc32c_raw(byts)
+        # the scope names the step in a trace whatever this function is
+        # called; its Python name is matched by a benchmark pattern too
+        with jax.named_scope("ec.encode.fused"):
+            parity = _parity(data32, out)
+            full = jnp.concatenate([data32, parity], axis=0)  # (k+p, B, W)
+            # int32 words -> the row's byte stream: little-endian byte
+            # order within a word matches memory order, so the
+            # bitcast+reshape is layout-free
+            byts = jax.lax.bitcast_convert_type(full, jnp.uint8)
+            byts = byts.reshape(full.shape[0], full.shape[1], -1)
+            return parity, batched_crc32c_raw(byts)
 
     body = _fused if fused_crc else _parity
     if mesh.devices.size == 1:
@@ -257,35 +260,6 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
             donate_argnums=(1,))
     _PARITY_STEP_CACHE[cache_key] = step
     return step
-
-
-_COST_CACHE: dict = {}
-
-
-def step_cost_analysis(step, key, *abstract_args):
-    """XLA cost analysis (flops / bytes accessed) for `step` at the
-    abstract shapes in `abstract_args`, computed once per `key` and
-    published to the profiling layer's kernel-cost table.
-
-    Uses ``Lowered.cost_analysis()`` — StableHLO-level, no backend
-    compile (~10ms) — so capturing it always-on per compiled geometry is
-    safe even inside the encode hot path.  Returns the entry dict, or
-    None when analysis is unavailable on this jax build."""
-    cached = _COST_CACHE.get(key)
-    if cached is not None:
-        return cached
-    try:
-        cost = step.lower(*abstract_args).cost_analysis()
-        flops = float(cost.get("flops", 0.0))
-        nbytes = float(cost.get("bytes accessed", 0.0))
-    except Exception:  # cost analysis is telemetry, never fatal
-        return None
-    from .. import profiling
-
-    entry = {"flops": flops, "bytes_accessed": nbytes}
-    _COST_CACHE[key] = entry
-    profiling.record_kernel_cost(str(key), flops, nbytes)
-    return entry
 
 
 def _pallas_fused_selftest(matrix) -> bool:
@@ -409,8 +383,9 @@ def make_sharded_encoder(mesh: Mesh, data_shards: int = 10,
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         def step(data_words):
-            return fused_encode_words(matrix, data_words,
-                                      interpret=False)
+            with jax.named_scope("ec.encode.step"):
+                return fused_encode_words(matrix, data_words,
+                                          interpret=False)
     else:
         data_sharding = NamedSharding(mesh, P("data", None, "block"))
         out_shardings = (
@@ -434,9 +409,10 @@ def make_sharded_encoder(mesh: Mesh, data_shards: int = 10,
         def step(data):
             # SWAR packs 4 bytes per int32 lane; odd chunk lengths keep
             # the (length-agnostic) bit-matmul formulation
-            if on_cpu_mesh and data.shape[-1] % 4 == 0:
-                return batched_swar_encode_step(consts, data)
-            return batched_encode_step(bit_matrix, data)
+            with jax.named_scope("ec.encode.step"):
+                if on_cpu_mesh and data.shape[-1] % 4 == 0:
+                    return batched_swar_encode_step(consts, data)
+                return batched_encode_step(bit_matrix, data)
 
     _ENCODER_CACHE[cache_key] = step
     return step

@@ -292,7 +292,6 @@ def profile_burst(seconds: float, hz: float, exclude=()) -> str:
 
 _tl_lock = threading.Lock()
 _DEVICE_TIMELINE: "deque[dict]" = deque(maxlen=512)
-_KERNEL_COST: dict[str, dict] = {}
 
 
 def record_device_batch(latency_s: float, units: int = 0, k: int = 0,
@@ -310,36 +309,23 @@ def record_device_batch(latency_s: float, units: int = 0, k: int = 0,
             "units": units, "k": k, "devices": devices})
 
 
-def record_kernel_cost(geometry: str, flops: float, bytes_accessed: float,
-                       extra: Optional[dict] = None):
-    """XLA cost analysis for one compiled geometry (from mesh.py)."""
-    entry = {"flops": float(flops), "bytes_accessed": float(bytes_accessed)}
-    if extra:
-        entry.update(extra)
-    with _tl_lock:
-        _KERNEL_COST[geometry] = entry
-    _stats.EcKernelFlopsGauge.labels(geometry).set(float(flops))
-    _stats.EcKernelBytesGauge.labels(geometry).set(float(bytes_accessed))
-
-
 def device_timeline() -> dict:
-    """The /debug/pprof/device payload: recent batch latencies, per-
-    geometry kernel cost, and the device pool's occupancy snapshot."""
+    """The /debug/pprof/device payload: recent batch latencies (host
+    clock, dispatch -> host copy, transfer included) and the device
+    pool's occupancy snapshot."""
     from .ops import device_pool
 
     pool = device_pool._pool  # do NOT materialize a pool just to report
     with _tl_lock:
         timeline = list(_DEVICE_TIMELINE)
-        cost = {k: dict(v) for k, v in _KERNEL_COST.items()}
-    return {"timeline": timeline, "kernel_cost": cost,
+    return {"timeline": timeline,
             "pool": pool.snapshot() if pool is not None else {}}
 
 
 def reset_device_telemetry():
-    """Tests: drop the timeline + cost table."""
+    """Tests: drop the timeline."""
     with _tl_lock:
         _DEVICE_TIMELINE.clear()
-        _KERNEL_COST.clear()
 
 
 # -- cluster merge ------------------------------------------------------------

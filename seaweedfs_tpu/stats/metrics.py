@@ -313,13 +313,17 @@ EcEncodeBytesCounter = REGISTRY.counter(
     "volume bytes pushed through the batched EC encode pipeline")
 EcEncodeStageSeconds = REGISTRY.gauge(
     "SeaweedFS_volumeServer_ec_encode_stage_seconds",
-    "busy seconds per host EC encode stage, last encode run", ("stage",))
+    "busy seconds per EC encode stage, and the wall, of the last encode "
+    "run (read = read_dat + read_data_write + loop; dispatch includes "
+    "h2d; encode_crc = d2h_wait + crc_host + loop)", ("stage",))
 EcWritebackFlushCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_writeback_flushes_total",
     "sync_file_range writeback-pacing windows flushed by EC writers")
 EcRecoverStageSeconds = REGISTRY.gauge(
     "SeaweedFS_volumeServer_ec_recover_stage_seconds",
-    "cumulative busy seconds per degraded-read stage", ("stage",))
+    "cumulative busy seconds per degraded-read stage (decode_stack, "
+    "decode_h2d and decode_apply lie inside decode; decode_queue is a "
+    "request's wait for its batch, outside it)", ("stage",))
 EcRecoverCacheCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_recover_cache_total",
     "recovered-block cache lookups by outcome "
@@ -328,6 +332,10 @@ EcRecoverSpanCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_recover_spans_total",
     "spans reconstructed on the degraded-read path, by decode mode",
     ("mode",))
+EcRecoverDecodeStackCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_recover_decode_stack_total",
+    "degraded-read decode batches by the number of blocks stacked into "
+    "the one GF mat-vec", ("blocks",))
 EcRecoverBytesCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_recover_bytes_total",
     "survivor bytes pushed through degraded-read decodes")
@@ -474,6 +482,21 @@ FaultsInjectedCounter = REGISTRY.counter(
 TopologyDeadNodesCounter = REGISTRY.counter(
     "SeaweedFS_topology_dead_nodes_total",
     "volume servers reaped by the master after missed heartbeats")
+VolumeServerStartupSeconds = REGISTRY.gauge(
+    "SeaweedFS_volumeServer_startup_seconds",
+    "seconds of the volume server's start phases, recorded once: import "
+    "(process start -> weed.main entered), device_init (this process's "
+    "first jax.devices()), load (volumes loaded), listen (serving)",
+    ("phase",))
+VolumeServerHeartbeatFailures = REGISTRY.counter(
+    "SeaweedFS_volumeServer_heartbeat_failures_total",
+    "heartbeats of the volume server's own loop that the master did not "
+    "acknowledge (RPC error or any other exception)")
+VolumeServerHeartbeatMaxGap = REGISTRY.gauge(
+    "SeaweedFS_volumeServer_heartbeat_max_gap_seconds",
+    "longest time between two heartbeats of the loop that the master "
+    "acknowledged, since the process started (the master unregisters a "
+    "node after 3 pulses of silence)")
 VolumeReadonlyDemotions = REGISTRY.counter(
     "SeaweedFS_volume_readonly_demotions_total",
     "volumes auto-demoted to read-only after disk write failures")
@@ -508,16 +531,9 @@ ProfilerRouteSamplesCounter = REGISTRY.counter(
     ("route",))
 EcKernelDispatchHistogram = REGISTRY.histogram(
     "SeaweedFS_volumeServer_ec_kernel_dispatch_ready_seconds",
-    "host-observed dispatch->ready latency per EC device batch, by the "
+    "host clock, dispatch of one EC device batch -> its parity copied "
+    "back to the host (kernel AND transfer; not a device time), by the "
     "device count the batch was sharded over", ("devices",))
-EcKernelFlopsGauge = REGISTRY.gauge(
-    "SeaweedFS_volumeServer_ec_kernel_flops",
-    "XLA cost-analysis flops per compiled EC parity geometry",
-    ("geometry",))
-EcKernelBytesGauge = REGISTRY.gauge(
-    "SeaweedFS_volumeServer_ec_kernel_bytes_accessed",
-    "XLA cost-analysis bytes accessed per compiled EC parity geometry",
-    ("geometry",))
 DevicePoolHwmBytesGauge = REGISTRY.gauge(
     "SeaweedFS_volumeServer_device_pool_hwm_bytes",
     "high-watermark of bytes held by the EC device slab pool")

@@ -345,10 +345,8 @@ class EcVolume:
         against (shard_size unknown) the exact span becomes the unit —
         still coalesced and cached."""
         self._tls.busy = 0.0
-        with tracing.span(
-                "ec.recover.serve",
-                tags={"shard": target_shard, "offset": offset,
-                      "size": size}) as sp:
+        with tracing.stage("ec.recover.serve", self._add_serve, "serve",
+                           target_shard, size):
             cache_bytes, block, coalesce = recover_knobs()
             shard_size = self.shard_size
             # recovery units must be sub-shard-aligned so vector codes
@@ -380,16 +378,18 @@ class EcVolume:
                 raise EcError(
                     f"recovered span short for shard {target_shard} at "
                     f"{offset}+{size}: got {len(out)}")
-        # the span measured the whole degraded read; the serve stage is
-        # that wall minus this thread's fetch+decode busy seconds
-        RECOVER_STATS.add_stage(
-            "serve", max(0.0, (sp.duration or 0.0)
-                         - getattr(self._tls, "busy", 0.0)))
         return out
 
     # per-thread fetch+decode busy seconds inside the current span, so
     # the serve stage reports assembly/wait overhead, not a double count
     _tls = threading.local()
+
+    def _add_serve(self, stage: str, seconds: float):
+        """ec.recover.serve's accumulator: the span measured the whole
+        degraded read; the serve stage is that wall minus this thread's
+        fetch+decode busy seconds."""
+        RECOVER_STATS.add_stage(
+            stage, max(0.0, seconds - getattr(self._tls, "busy", 0.0)))
 
     def _recover_block(self, target_shard: int, offset: int,
                        size: int) -> bytes:
@@ -399,12 +399,10 @@ class EcVolume:
         batcher."""
         blk0 = time.perf_counter()
         try:
-            with tracing.span(
-                    "ec.recover.fetch",
-                    tags={"shard": target_shard, "bytes": size}) as fsp:
+            with tracing.stage("ec.recover.fetch", RECOVER_STATS.add_stage,
+                               "fetch", target_shard, size):
                 survivors, inputs = self._fetch_survivors(
                     target_shard, offset, size)
-            RECOVER_STATS.add_stage("fetch", fsp.duration or 0.0)
             out = self._recover_batcher.decode(
                 survivors, target_shard, inputs)
             return np.ascontiguousarray(out).tobytes()
@@ -529,7 +527,8 @@ class EcVolume:
         return codec_mod.reconstruct_span(
             survivors, inputs, target,
             self.family.data_shards, TOTAL_SHARDS_COUNT,
-            slab_key=slab_key, family=self.family)
+            slab_key=slab_key, family=self.family,
+            add_stage=RECOVER_STATS.add_stage)
 
     # -- delete (ec_volume_delete.go) -----------------------------------------
     def delete_needle(self, needle_id: int):

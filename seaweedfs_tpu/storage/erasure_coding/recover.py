@@ -52,11 +52,22 @@ def recover_knobs() -> tuple[int, int, bool]:
     return cache_bytes, block_bytes, coalesce
 
 
+# Stage keys of RecoverStats.add_stage, in reply order.  The first three
+# are the stages PR 21 had and keep their 3-decimal reply; the decode_*
+# ones split the decode stage: queue = a request waiting in the batcher
+# for its batch to start (outside `decode`), stack / h2d / apply = the
+# concatenate, the upload and dispatch->copied-back inside it.
+_STAGES = ("fetch", "decode", "serve")
+_DECODE_STAGES = ("decode_queue", "decode_stack", "decode_h2d",
+                  "decode_apply")
+
+
 class RecoverStats:
     """Cumulative degraded-read telemetry, process-wide.  Busy seconds
     per stage (fetch = survivor reads, decode = GF math, serve = span
-    assembly/cache bookkeeping around them) plus cache and coalescing
-    counters; mirrored into the Prometheus vectors on every update."""
+    assembly/cache bookkeeping around them; decode_* see _DECODE_STAGES)
+    plus cache and coalescing counters; mirrored into the Prometheus
+    vectors on every update."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -64,9 +75,7 @@ class RecoverStats:
 
     def reset(self):
         with self._lock:
-            self.fetch_seconds = 0.0
-            self.decode_seconds = 0.0
-            self.serve_seconds = 0.0
+            self._seconds = dict.fromkeys(_STAGES + _DECODE_STAGES, 0.0)
             self.cache_hits = 0
             self.cache_misses = 0
             self.coalesced = 0
@@ -76,22 +85,11 @@ class RecoverStats:
             self.recovered_bytes = 0
 
     def add_stage(self, stage: str, seconds: float):
-        with self._lock:
-            if stage == "fetch":
-                self.fetch_seconds += seconds
-            elif stage == "decode":
-                self.decode_seconds += seconds
-            else:
-                self.serve_seconds += seconds
-        self._push_stage(stage)
-
-    def _push_stage(self, stage: str):
+        """The stage accumulator handed to tracing.stage()."""
         from ...stats import metrics as stats
 
         with self._lock:
-            val = {"fetch": self.fetch_seconds,
-                   "decode": self.decode_seconds,
-                   "serve": self.serve_seconds}[stage]
+            val = self._seconds[stage] = self._seconds[stage] + seconds
         stats.EcRecoverStageSeconds.labels(stage).set(round(val, 6))
 
     def cache_event(self, result: str, n: int = 1):
@@ -117,6 +115,7 @@ class RecoverStats:
             self.recovered_bytes += nbytes
         stats.EcRecoverSpanCounter.labels(
             "batched" if n_spans > 1 else "solo").inc(n_spans)
+        stats.EcRecoverDecodeStackCounter.labels(str(n_spans)).inc()
         stats.EcRecoverBytesCounter.inc(nbytes)
 
     def snapshot(self, wall: Optional[float] = None) -> dict:
@@ -125,10 +124,11 @@ class RecoverStats:
         degraded-read pipeline's own answer to "which stage is the
         bottleneck"."""
         with self._lock:
-            out = {
-                "fetch_seconds": round(self.fetch_seconds, 3),
-                "decode_seconds": round(self.decode_seconds, 3),
-                "serve_seconds": round(self.serve_seconds, 3),
+            out = {f"{k}_seconds": round(self._seconds[k], 3)
+                   for k in _STAGES}
+            out.update({f"{k}_seconds": round(self._seconds[k], 6)
+                        for k in _DECODE_STAGES})
+            out.update({
                 "cache_hits": self.cache_hits,
                 "cache_misses": self.cache_misses,
                 "coalesced": self.coalesced,
@@ -136,7 +136,12 @@ class RecoverStats:
                 "batches": self.batches,
                 "batched_spans": self.batched_spans,
                 "recovered_bytes": self.recovered_bytes,
-            }
+                # one decode batch = one stack of blocks in one GF
+                # mat-vec (the same counts as batches / spans, under the
+                # names the per-stack counter on /metrics sums to)
+                "decode_batches": self.batches,
+                "decode_blocks": self.spans,
+            })
         lookups = out["cache_hits"] + out["cache_misses"]
         out["cache_hit_ratio"] = (
             round(out["cache_hits"] / lookups, 3) if lookups else 0.0)
@@ -280,11 +285,12 @@ class RecoveredBlockCache:
 
 
 class _DecodeReq:
-    __slots__ = ("inputs", "event", "out", "error")
+    __slots__ = ("inputs", "started", "event", "out", "error")
 
     def __init__(self, inputs: np.ndarray):
         self.inputs = inputs
-        self.event = threading.Event()
+        self.started = threading.Event()  # its batch began to decode
+        self.event = threading.Event()    # ... and ended
         self.out: Optional[np.ndarray] = None
         self.error: Optional[BaseException] = None
 
@@ -319,7 +325,10 @@ class SpanDecodeBatcher:
             if leader:
                 self._busy.add(key)
         if not leader:
-            if req.event.wait(timeout=60.0):
+            with tracing.stage("ec.recover.decode.queue",
+                               self.stats.add_stage, "decode_queue"):
+                started = req.started.wait(timeout=60.0)
+            if started and req.event.wait(timeout=60.0):
                 if req.error is not None:
                     raise req.error
                 return req.out
@@ -339,6 +348,7 @@ class SpanDecodeBatcher:
                 stranded = self._queues.pop(key, [])
             for r in stranded:  # late joiners must not wait forever
                 r.error = req.error or r.error
+                r.started.set()
                 r.event.set()
             raise
 
@@ -346,35 +356,37 @@ class SpanDecodeBatcher:
                       batch: list[_DecodeReq]) -> list[np.ndarray]:
         from ...qos.lanes import LANES
 
-        sp = tracing.start("ec.recover.decode", tags={"spans": len(batch)})
-        prev = tracing.swap(sp)
+        add = self.stats.add_stage
+        for r in batch:
+            r.started.set()
         try:
-            if len(batch) == 1:
-                stacked = batch[0].inputs
-            else:
-                stacked = np.concatenate([r.inputs for r in batch], axis=1)
-            # foreground device lane: while this decode runs, queued
-            # background batches (scrub re-encode, bulk encode) yield
-            # at their next checkpoint
-            with LANES.foreground():
-                out = self._decode_fn(survivors, target, stacked)
-            outs = []
-            col = 0
-            for r in batch:
-                width = r.inputs.shape[1]
-                r.out = out[col:col + width]
-                outs.append(r.out)
-                col += width
+            with tracing.stage("ec.recover.decode", add, "decode",
+                               len(batch)):
+                with tracing.stage("ec.recover.decode.stack", add,
+                                   "decode_stack", len(batch)):
+                    if len(batch) == 1:
+                        stacked = batch[0].inputs
+                    else:
+                        stacked = np.concatenate(
+                            [r.inputs for r in batch], axis=1)
+                # foreground device lane: while this decode runs, queued
+                # background batches (scrub re-encode, bulk encode) yield
+                # at their next checkpoint
+                with LANES.foreground():
+                    out = self._decode_fn(survivors, target, stacked)
+                outs = []
+                col = 0
+                for r in batch:
+                    width = r.inputs.shape[1]
+                    r.out = out[col:col + width]
+                    outs.append(r.out)
+                    col += width
             self.stats.decoded(len(batch), int(stacked.nbytes))
             return outs
         except BaseException as e:
             for r in batch:
                 r.error = e
-            sp.status = f"error: {type(e).__name__}"
             raise
         finally:
-            tracing.restore(prev)
-            sp.finish()
-            self.stats.add_stage("decode", sp.duration or 0.0)
             for r in batch:
                 r.event.set()

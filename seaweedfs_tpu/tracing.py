@@ -19,7 +19,13 @@ slow reply to the hop or kernel stage that caused it.  Here:
     cost with sampling off is just the duration measurement; a slow
     span promotes its trace from that span onward;
   * ``GET /debug/traces`` (recent index) and ``GET /debug/traces/<id>``
-    (full span tree) are mounted on every daemon.
+    (full span tree) are mounted on every daemon;
+  * the two device EC paths (the encode pipeline, the degraded-read
+    decode) time their pipeline stages with ``stage()``: one
+    ``perf_counter`` measurement that feeds the stage's counter, a child
+    span when the request is sampled, and — while a ``jax.profiler``
+    session is on — a host event on the profiler's clock, next to the
+    device planes.
 
 The daemons share one process in tests/bench (like stats.REGISTRY), so
 the recorder is process-global and spans carry a ``service`` label —
@@ -37,6 +43,7 @@ from __future__ import annotations
 import itertools
 import os
 import random
+import sys
 import threading
 import time
 from collections import OrderedDict
@@ -297,13 +304,100 @@ def span(name: str, service: str = "", parent: Optional[Span] = None,
 def record_span(name: str, duration: float, service: str = "",
                 parent: Optional[Span] = None, tags: Optional[dict] = None,
                 status: str = "ok") -> Span:
-    """Adopt an externally-measured duration as a finished span (the
-    bridge for stage timers aggregated outside a with-block, e.g. the
-    encode pipeline's per-stage busy seconds)."""
+    """Adopt an externally-measured duration as a finished span: the
+    busy seconds a pipeline stage gathered over many ``stage()`` blocks
+    and worker threads, as one child of the job's root.  It starts where
+    its parent started — the seconds lie somewhere inside the parent,
+    not at its end."""
     sp = start(name, service, parent, tags)
-    sp.start_ts -= duration
+    if parent is not None:
+        sp.start_ts = parent.start_ts
     sp.finish(status=status, duration=duration)
     return sp
+
+
+# jax.profiler.TraceAnnotation, looked up once jax is in the process.
+# Never imported from here: a daemon that does not touch the device
+# (master, filer, a prefork worker) must not pay for jax because it
+# timed a stage.
+_trace_annotation = None
+
+
+def _annotation_class():
+    global _trace_annotation
+    if _trace_annotation is None:
+        prof = sys.modules.get("jax.profiler")
+        if prof is not None:
+            _trace_annotation = getattr(prof, "TraceAnnotation", None)
+    return _trace_annotation
+
+
+class stage:
+    """``with stage(name, add, key[, n, nbytes]):`` — one pipeline stage
+    of a device EC path, timed once.  `name` is one of the fixed
+    ``ec.encode.*`` / ``ec.recover.*`` names.  Meant for per-batch and
+    per-block sites — a few hundred calls a GiB — never per row or per
+    request.
+
+      * ``add(key, seconds)``, the stage accumulator (the encode
+        pipeline's timers, ``RecoverStats.add_stage``), gets the
+        ``perf_counter`` elapsed — the stage's counter and its span are
+        the same measurement;
+      * when the thread's current span is sampled the stage is recorded
+        as its child (and is the current span inside the block, so
+        nested stages hang under it); otherwise no Span is built;
+      * while a jax.profiler session is on the block is a
+        ``TraceAnnotation``: an event on the trace's host plane, on the
+        device planes' clock.  With no session none is built.
+
+    `n` / `nbytes` are the span's two small integers (batch index or
+    stack length, bytes); -1 = absent.  ``seconds`` holds the elapsed time
+    after the block."""
+
+    __slots__ = ("name", "add", "key", "n", "nbytes", "seconds",
+                 "_t0", "_ann", "_sp", "_prev")
+
+    def __init__(self, name: str, add, key: str, n: int = -1,
+                 nbytes: int = -1):
+        self.name = name
+        self.add = add
+        self.key = key
+        self.n = n
+        self.nbytes = nbytes
+        self.seconds = 0.0
+
+    def __enter__(self) -> "stage":
+        ann = _trace_annotation or _annotation_class()
+        if ann is not None and ann.is_enabled():
+            ann = self._ann = ann(self.name)
+            ann.__enter__()
+        else:
+            self._ann = None
+        parent = getattr(_ctx, "span", None)
+        if parent is not None and parent.sampled:
+            sp = self._sp = Span(
+                parent.trace_id, _new_id(), parent.span_id, self.name,
+                parent.service, True, False,
+                {"n": self.n, "bytes": self.nbytes})
+            sp.route = parent.route
+            self._prev = swap(sp)
+        else:
+            self._sp = None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.seconds = seconds = time.perf_counter() - self._t0
+        sp = self._sp
+        if sp is not None:
+            if exc_type is not None:
+                sp.status = f"error: {exc_type.__name__}"
+            restore(self._prev)
+            sp.finish(duration=seconds)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        self.add(self.key, seconds)
+        return False
 
 
 class Recorder:

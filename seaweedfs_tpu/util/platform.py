@@ -49,6 +49,34 @@ def ensure_compile_cache() -> str:
     return path
 
 
+def process_age() -> float | None:
+    """Seconds since the kernel started this process (Linux /proc);
+    None where that cannot be read."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def record_startup(**phases: float):
+    """Start phases of this process, each recorded once, as the
+    `volumeServer_startup_seconds{phase=}` gauge and one log line
+    (import, load and listen come from `weed.py volume`; device_init
+    from the first jax.devices(), whenever a device path first asks)."""
+    from ..stats import metrics as stats
+    from . import glog
+
+    for phase, seconds in phases.items():
+        stats.VolumeServerStartupSeconds.labels(phase).set(
+            round(seconds, 6))
+    glog.infof("start-up: %s", ", ".join(
+        f"{phase} {seconds:.3f} s" for phase, seconds in phases.items()))
+
+
 def available_cpu_count() -> int:
     """Cores THIS process may run on: the scheduling affinity mask when
     the platform exposes it (cgroup cpusets, taskset, k8s cpu-manager
@@ -75,12 +103,14 @@ def device_info() -> dict | None:
     with _lock:
         if not _cache:
             try:
+                t0 = time.perf_counter()
                 import jax
 
                 devs = jax.devices()
                 _cache["device"] = {"platform": devs[0].platform,
                                     "device_kind": devs[0].device_kind,
                                     "count": len(devs)}
+                record_startup(device_init=time.perf_counter() - t0)
             except (ImportError, RuntimeError) as e:
                 from . import glog
 

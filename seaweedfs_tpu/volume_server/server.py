@@ -644,19 +644,28 @@ class VolumeServer:
         return resp
 
     def _heartbeat_loop(self):
+        last_ack = None
+        max_gap = 0.0
         while not self._stop.is_set():
             try:
                 self.heartbeat_once()
             except RpcError:
-                pass
+                stats.VolumeServerHeartbeatFailures.inc()
             except Exception:
                 # the heartbeat thread must never die: a missed beat is
                 # recoverable, a dead loop gets the node reaped by the
                 # master and strands every volume it holds
                 import logging
 
+                stats.VolumeServerHeartbeatFailures.inc()
                 logging.getLogger(__name__).exception(
                     "heartbeat iteration failed")
+            else:
+                now = time.perf_counter()
+                if last_ack is not None and now - last_ack > max_gap:
+                    max_gap = now - last_ack
+                    stats.VolumeServerHeartbeatMaxGap.set(max_gap)
+                last_ack = now
             self._stop.wait(self.pulse_seconds)
 
     # -- routing -------------------------------------------------------------

@@ -181,7 +181,14 @@ class TestExpositionFormat:
             "type"] == "counter"
         assert fams["SeaweedFS_volumeServer_ec_kernel_dispatch_ready"
                     "_seconds"]["type"] == "histogram"
-        assert fams["SeaweedFS_volumeServer_ec_kernel_flops"][
+        # XLA's cost-analysis gauges are gone (read by nothing); the
+        # heartbeat loop's own two instruments are exported instead
+        assert not [f for f in fams if f.startswith(
+            "SeaweedFS_volumeServer_ec_kernel_flops")
+            or f.startswith("SeaweedFS_volumeServer_ec_kernel_bytes")]
+        assert fams["SeaweedFS_volumeServer_heartbeat_failures_total"][
+            "type"] == "counter"
+        assert fams["SeaweedFS_volumeServer_heartbeat_max_gap_seconds"][
             "type"] == "gauge"
         assert fams["SeaweedFS_volumeServer_device_pool_hwm_bytes"][
             "type"] == "gauge"

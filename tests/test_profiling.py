@@ -218,7 +218,9 @@ class TestPprofEndpoints:
         vs = cluster[1]
         profiling.record_device_batch(0.0123, units=4, k=7)
         dev = call(vs.store.url, "/debug/pprof/device")
-        assert set(dev) == {"timeline", "kernel_cost", "pool"}
+        # XLA's cost estimate ("kernel_cost") left with PR 26: nothing
+        # read it, and the roofline counts its own operations and bytes
+        assert set(dev) == {"timeline", "pool"}
         batch = dev["timeline"][-1]
         assert batch["dispatch_ready_ms"] == pytest.approx(12.3)
         assert batch["units"] == 4 and batch["k"] == 7

@@ -13,10 +13,16 @@ import json
 import os
 import signal
 import sys
+import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from seaweedfs_tpu.rpc.http_rpc import RpcError, call  # noqa: E402
+from seaweedfs_tpu.util import platform as platform_util  # noqa: E402
+
+# process age when main() was entered: the volume server's "import"
+# start phase (interpreter start + this file's imports)
+_main_entered_at = None
 
 VERSION = "seaweedfs_tpu 0.1 (RS(10,4) EC on TPU via JAX/Pallas)"
 
@@ -130,6 +136,7 @@ def cmd_volume(args):
     maxes = [int(x) for x in args.max.split(",")] if args.max else None
     if maxes and len(maxes) == 1:
         maxes = maxes * len(dirs)
+    t0 = time.perf_counter()
     vs = VolumeServer(dirs, args.mserver, host=args.ip, port=args.port,
                       rack=args.rack, data_center=args.dataCenter,
                       max_volume_counts=maxes,
@@ -141,8 +148,13 @@ def cmd_volume(args):
                       ec_encoder_backend=args.ecBackend or None,
                       upload_limit_mb=args.concurrentUploadLimitMB,
                       download_limit_mb=args.concurrentDownloadLimitMB)
+    t1 = time.perf_counter()
     vs.start()
     print(f"volume server listening on {vs.address}, dirs={dirs}")
+    phases = {"load": t1 - t0, "listen": time.perf_counter() - t1}
+    if _main_entered_at is not None:
+        phases = {"import": _main_entered_at, **phases}
+    platform_util.record_startup(**phases)
     _wait_forever([vs])
 
 
@@ -1279,6 +1291,8 @@ def _workers_flag(p):
 
 
 def main(argv=None):
+    global _main_entered_at
+    _main_entered_at = platform_util.process_age()
     parser = argparse.ArgumentParser(prog="weed", description=__doc__)
     parser.add_argument("-v", type=int, default=0,
                         help="glog verbosity level")
