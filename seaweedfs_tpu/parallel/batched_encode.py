@@ -8,12 +8,14 @@ ec_encoder.go:194-231).  Here the striped rows of MANY volumes are tiled
 into (B, 10, L) uint8 batches and pushed through one jit-compiled
 parity+CRC step (parallel/mesh.py) with a three-stage pipeline:
 
-  reader thread   — fills pooled host staging slots from the .dat files
-                    and appends the data-shard bytes to .ec00-.ec09 (data
-                    shards are a pure re-interleaving of the .dat, no
-                    compute needed; all-zero padding rows are skipped —
-                    the shard files are ftruncate()d to final size, so
-                    their bytes are already zero);
+  read stage      — a coordinator thread and a few I/O workers fill
+                    pooled host staging slots from the .dat files, a
+                    batch at a time and in batch order, and write the
+                    data-shard bytes to .ec00-.ec09 (data shards are a
+                    pure re-interleaving of the .dat, no compute needed;
+                    all-zero padding rows are skipped — the shard files
+                    are ftruncate()d to final size, so their bytes are
+                    already zero);
   main thread     — dispatches batch N+1 into the persistent jitted step
                     while earlier batches are still in flight (depth
                     WEED_EC_DEVICE_INFLIGHT), uploading through the
@@ -43,6 +45,8 @@ import os
 import queue
 import threading
 import time
+from collections import deque
+from concurrent import futures
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -129,21 +133,6 @@ def _make_units(plans: list[_VolumePlan], chunk: int) -> list[_Unit]:
     return units
 
 
-def _read_unit(dat, dat_size: int, u: _Unit, chunk: int, out: np.ndarray):
-    """Fill out (10, chunk) with the unit's data-shard bytes, zero-padding
-    past EOF (the tail row's zero padding is part of the format)."""
-    for i in range(DATA_SHARDS):
-        start = u.row_start + i * u.block_size + u.col
-        view = memoryview(out[i]).cast("B")
-        if start >= dat_size:
-            out[i].fill(0)
-            continue
-        dat.seek(start)
-        got = dat.readinto(view)
-        if got < chunk:
-            out[i, got:].fill(0)
-
-
 # -- the write stage's shared plumbing --------------------------------------
 # checked vectored writes, dirty-page writeback pacing, and the raw shard
 # fd set.  Shared by all three consumers: the host pipeline's writer pool,
@@ -198,6 +187,21 @@ def _write_knobs() -> tuple[bool, int, int, bool]:
     drop = os.environ.get("WEED_EC_WRITE_DROP_CACHE", "0").lower() \
         not in ("", "0", "false", "no")
     return behind, writers, flush_bytes, drop
+
+
+def _preadv_full(fd: int, view: memoryview, offset: int):
+    """Positional read that fills `view` or raises OSError: a short read
+    is continued from where the kernel stopped, and end of file before
+    the planned bytes (a .dat truncated under the seal) fails the encode
+    rather than sealing zeros."""
+    got = 0
+    while got < view.nbytes:
+        n = os.preadv(fd, [view[got:]], offset + got)
+        if n <= 0:
+            raise OSError(
+                "preadv reached end of file: %d of %d bytes at offset %d "
+                "(.dat shorter than planned)" % (got, view.nbytes, offset))
+        got += n
 
 
 def _pwritev_full(fd: int, bufs, offset: int) -> int:
@@ -396,10 +400,23 @@ def encode_volumes(bases: list[str], large_block: Optional[int] = None,
 
 class _PipelineIO:
     """Shared reader/writer scaffolding of the streaming pipeline:
-    pooled staging slots, backpressure queues, the reader thread (fills
-    slots and appends data shards), the writer thread (appends parity
+    pooled staging slots, backpressure queues, the read stage (fills
+    slots and writes data shards), the writer thread (appends parity
     shards), and the torn-shutdown sequencing.  The device compute
     stages differ only in what happens between `ready` and `parity_q`.
+
+    The read stage is one coordinator thread and `read_workers` I/O
+    workers.  For each batch the coordinator takes a free slot, hands
+    the batch's (unit, row) steps — read the row's .dat bytes into its
+    place in the slot (preadv), write them to its data shard (pwritev),
+    both positional and both outside the GIL — to the workers, waits for
+    all of them and only then puts the slot on `ready`: batches reach
+    the dispatch loop and the completion thread in order, which the CRC
+    chaining needs, while the rows inside a batch overlap.  The rows are
+    independent (each its own 1 MiB of the slot and its own range of one
+    of ten files), and one worker runs the same loop.  `read_workers`
+    follows the cores the process may use (`_read_workers`); tests pass
+    it to the constructor.
 
     Staging slots are leased from the device slab pool so repeated
     encodes with the same geometry reuse the same buffers.  Two layouts:
@@ -412,13 +429,14 @@ class _PipelineIO:
              as one contiguous view, and each shard row stays contiguous
              for readinto/pwritev/host-CRC.
 
-    Either way the reader skips zero-padding rows' shard writes (the
-    files are ftruncate zeros already) and trims partial tail rows to
-    their real bytes; `ready` items carry the batch's compacted row
-    count k_max ("bk" readers always report the full 10)."""
+    Either way a worker is handed a contiguous row; the read stage
+    skips zero-padding rows' shard writes (the files are ftruncate zeros
+    already) and trims partial tail rows to their real bytes; `ready`
+    items carry the batch's compacted row count k_max ("bk" always
+    reports the full 10)."""
 
     def __init__(self, plans, units, chunk, writers, b, layout, pool,
-                 n_slots=_SLOTS):
+                 n_slots=_SLOTS, read_workers: Optional[int] = None):
         self.plans, self.units, self.chunk = plans, units, chunk
         self.writers, self.b = writers, b
         self.layout = layout
@@ -426,13 +444,22 @@ class _PipelineIO:
         # the seal's span (encode_volumes'), installed on worker threads
         self.root = tracing.current()
         self.n_batches = (len(units) + b - 1) // b
-        self.dats = [open(p.base + ".dat", "rb") for p in plans]
-        # busy seconds per stage.  read = the reader's per-unit block
-        # (read_dat + read_data_write + the loop around them);
+        # raw fds: the workers read positionally, sharing no file offset
+        self.dats = [os.open(p.base + ".dat", os.O_RDONLY) for p in plans]
+        self.read_workers = read_workers or _read_workers()
+        self._workers = futures.ThreadPoolExecutor(
+            self.read_workers, thread_name_prefix="ec-encode-read")
+        # busy seconds per stage.  read = the read stage's wall, one
+        # block a batch around the fan-out to the I/O workers;
+        # read_worker_busy = thread-seconds the workers spent on their
+        # steps, of which read_dat and read_data_write are the two calls
+        # (so the three may exceed read: read_worker_busy / read is how
+        # many workers the stage kept busy);
         # dispatch = upload + step call (h2d is its upload);
         # encode_crc = the completion thread's per-batch block (d2h_wait
         # = blocked in the copy back, crc_host = the host work after it)
-        self.timers = {"read": 0.0, "read_dat": 0.0, "read_data_write": 0.0,
+        self.timers = {"read": 0.0, "read_worker_busy": 0.0,
+                       "read_dat": 0.0, "read_data_write": 0.0,
                        "read_slot_wait": 0.0, "dispatch": 0.0, "h2d": 0.0,
                        "encode_crc": 0.0, "d2h_wait": 0.0, "crc_host": 0.0,
                        "write": 0.0}
@@ -452,8 +479,10 @@ class _PipelineIO:
         self.parity_q: "queue.Queue" = queue.Queue(maxsize=n_slots)
         self.errors: list[BaseException] = []
         self.stop = threading.Event()
-        self._rt = threading.Thread(target=self._reader, daemon=True)
-        self._wt = threading.Thread(target=self._writer, daemon=True)
+        self._rt = threading.Thread(target=self._reader, daemon=True,
+                                    name="ec-encode-reader")
+        self._wt = threading.Thread(target=self._writer, daemon=True,
+                                    name="ec-encode-writer")
 
     def add_time(self, key: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -477,44 +506,61 @@ class _PipelineIO:
                 continue
         return None
 
-    def _fill_row(self, u: _Unit, i: int, row: np.ndarray) -> int:
-        """Read shard row i of the unit into `row`, zero-padding a short
-        read; returns the count of real .dat bytes in the row."""
-        dat = self.dats[u.vol]
-        start = u.row_start + i * u.block_size + u.col
-        dat.seek(start)
-        got = dat.readinto(memoryview(row).cast("B"))
-        if got < self.chunk:
-            row[got:] = 0
-        return min(self.chunk, self.plans[u.vol].dat_size - start)
-
-    def _read_unit(self, u: _Unit, k: int, buf: np.ndarray, k_max: int):
-        """One unit: its real rows from the .dat into the staging slot
-        and out to the data-shard files, padding rows zeroed.  The split
-        of the two is taken with bare clock readings, one pair a row: a
-        span per 1 MiB row would cost more than it tells."""
-        w = self.writers[u.vol]
+    def _read_steps(self, buf: np.ndarray, steps: deque):
+        """One I/O worker's share of a batch: take (k, unit, row) steps
+        off the batch's deque until it is empty, and for each read the
+        row's real .dat bytes into its place in the staging slot (zero
+        past them) and write them to data shard `row`.  The split of the
+        two is taken with bare clock readings, one pair a row: a span
+        per 1 MiB row would cost more than it tells."""
         t_dat = t_write = 0.0
-        t = time.perf_counter()
-        for i in range(u.real_rows):
-            row = buf[i, k] if self.layout == "kb" else buf[k, i]
-            real = self._fill_row(u, i, row)
-            t1 = time.perf_counter()
-            t_dat += t1 - t
-            w.write(i, [row[:real]], u.shard_off)
-            t = time.perf_counter()
-            t_write += t - t1
-        # zero padding rows up to the compacted height: they feed the
-        # parity math but neither files nor CRCs (files are ftruncate
-        # zeros, CRC is the cached zeros CRC)
-        for i in range(u.real_rows, k_max):
-            if self.layout == "kb":
-                buf[i, k].fill(0)
-            else:
-                buf[k, i].fill(0)
+        t_in = t = time.perf_counter()
+        try:
+            while not self.stop.is_set():
+                try:
+                    k, u, i = steps.popleft()
+                except IndexError:
+                    break
+                row = buf[i, k] if self.layout == "kb" else buf[k, i]
+                start = u.row_start + i * u.block_size + u.col
+                real = min(self.chunk, self.plans[u.vol].dat_size - start)
+                _preadv_full(self.dats[u.vol], memoryview(row)[:real],
+                             start)
+                if real < self.chunk:
+                    row[real:] = 0
+                t1 = time.perf_counter()
+                t_dat += t1 - t
+                self.writers[u.vol].write(i, [row[:real]], u.shard_off)
+                t = time.perf_counter()
+                t_write += t - t1
+        except BaseException as e:  # fails the seal, stops the others
+            self.errors.append(e)
+            self.stop.set()
         with self.tlock:
             self.timers["read_dat"] += t_dat
             self.timers["read_data_write"] += t_write
+            self.timers["read_worker_busy"] += t - t_in
+
+    def _read_batch(self, n: int, batch, buf: np.ndarray, k_max: int):
+        """Fan batch n's rows out to the I/O workers and wait for all of
+        them, so batches reach `ready` in order.  Padding rows up to the
+        compacted height are zeroed meanwhile: they feed the parity math
+        but neither files nor CRCs (files are ftruncate zeros, CRC is
+        the cached zeros CRC)."""
+        steps = deque((k, u, i) for k, u in enumerate(batch)
+                      for i in range(u.real_rows))
+        with tracing.stage("ec.encode.read", self.add_time, "read", n,
+                           len(steps) * self.chunk):
+            jobs = [self._workers.submit(self._read_steps, buf, steps)
+                    for _ in range(self.read_workers)]
+            for k, u in enumerate(batch):
+                for i in range(u.real_rows, k_max):
+                    if self.layout == "kb":
+                        buf[i, k].fill(0)
+                    else:
+                        buf[k, i].fill(0)
+            for job in jobs:
+                job.result()
 
     def _reader(self):
         tracing.swap(self.root)
@@ -526,17 +572,13 @@ class _PipelineIO:
                     slot = self.get(self.free_slots)
                 if slot is None:
                     return
-                buf = slot.payload
                 if self.layout == "kb":
                     k_max = max(u.real_rows for u in batch)
                 else:
                     k_max = DATA_SHARDS
-                for k, u in enumerate(batch):
-                    with tracing.stage("ec.encode.read", self.add_time,
-                                       "read", n, u.real_rows * self.chunk):
-                        self._read_unit(u, k, buf, k_max)
+                self._read_batch(n, batch, slot.payload, k_max)
                 if not self.put(self.ready, (slot, batch, k_max)):
-                    return
+                    return      # also when a stop cut the batch short
             self.put(self.ready, None)
         except BaseException as e:  # propagate to the main thread
             self.errors.append(e)
@@ -579,8 +621,9 @@ class _PipelineIO:
         self._wt.join(timeout=60)
         self.stop.set()
         self._rt.join(timeout=30)
-        for f in self.dats:
-            f.close()
+        self._workers.shutdown(wait=True)   # they saw the stop
+        for fd in self.dats:
+            os.close(fd)
         for w in self.writers.values():
             w.close()
         for ls in self._slot_leases:
@@ -596,6 +639,21 @@ class _PipelineIO:
             sum(p.dat_size for p in self.plans))
         return {p.base: self.writers[vi].crcs
                 for vi, p in enumerate(self.plans)}
+
+
+def _read_workers() -> int:
+    """I/O workers of the device pipeline's read stage: a third of the
+    cores this process may use, at most four.  Observed, not set: the
+    stage's gain flattens at four on the 13- and 30-core chip hosts
+    (from there the completion thread is as long as the read stage;
+    PERF.md, PR 27), and the other two thirds are the pipeline's four
+    other threads and the server's.  A one- or two-core machine runs
+    the same loop with one worker."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except AttributeError:      # not on Linux
+        cores = os.cpu_count() or 1
+    return max(1, min(4, cores // 3))
 
 
 def _device_inflight() -> int:
@@ -938,6 +996,7 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
         stage_stats["k_shapes"] = sorted(k_shapes)
         stage_stats["inflight"] = depth
         stage_stats["staging_slots"] = n_slots
+        stage_stats["read_workers"] = io.read_workers
         stage_stats["zero_copy_h2d"] = zero_copy
         stage_stats["devices"] = mesh.devices.size
         stage_stats["device_shard"] = dev_label

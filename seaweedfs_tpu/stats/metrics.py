@@ -314,8 +314,10 @@ EcEncodeBytesCounter = REGISTRY.counter(
 EcEncodeStageSeconds = REGISTRY.gauge(
     "SeaweedFS_volumeServer_ec_encode_stage_seconds",
     "busy seconds per EC encode stage, and the wall, of the last encode "
-    "run (read = read_dat + read_data_write + loop; dispatch includes "
-    "h2d; encode_crc = d2h_wait + crc_host + loop)", ("stage",))
+    "run (read = the read stage's wall a batch; read_worker_busy = "
+    "thread-seconds of its I/O workers, of which read_dat and "
+    "read_data_write; dispatch includes h2d; encode_crc = d2h_wait + "
+    "crc_host + loop)", ("stage",))
 EcWritebackFlushCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_writeback_flushes_total",
     "sync_file_range writeback-pacing windows flushed by EC writers")

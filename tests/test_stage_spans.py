@@ -43,7 +43,7 @@ RECOVER_SPANS = {"ec.recover.fetch", "ec.recover.decode.queue",
                  "ec.recover.decode.h2d", "ec.recover.decode.apply",
                  "ec.recover.serve"}
 NEW_ENCODE_KEYS = ("read_dat", "read_data_write", "read_slot_wait", "h2d",
-                   "d2h_wait", "crc_host")
+                   "d2h_wait", "crc_host", "read_worker_busy")
 OLD_ENCODE_KEYS = ("read", "dispatch", "encode_crc", "write", "wall")
 NEW_RECOVER_KEYS = ("decode_queue_seconds", "decode_stack_seconds",
                     "decode_h2d_seconds", "decode_apply_seconds",
@@ -206,8 +206,11 @@ def test_recover_stats_key_after_a_cpu_degraded_read(key, paths):
 # -- (b) the arithmetic between the counters ----------------------------------
 
 def test_read_splits_into_dat_read_and_data_write(paths):
+    # thread-seconds over the read stage's workers against its own wall
     st = paths["stage_stats"]
-    assert st["read_dat"] + st["read_data_write"] <= st["read"] + 0.002
+    cap = st["read"] * st["read_workers"] + 0.002
+    assert st["read_dat"] + st["read_data_write"] <= cap
+    assert st["read_worker_busy"] <= cap
     assert st["read_dat"] > 0 or st["read"] < 0.002
 
 
@@ -782,8 +785,9 @@ def test_small_seal_twice_under_put_get_load(tmp_path, monkeypatch):
             for key in OLD_ENCODE_KEYS + NEW_ENCODE_KEYS:
                 assert isinstance(ss[key], float), key
             assert "kernel_cost" not in ss
-            assert ss["read_dat"] + ss["read_data_write"] \
-                <= ss["read"] + 0.002
+            cap = ss["read"] * ss["read_workers"] + 0.002
+            assert ss["read_dat"] + ss["read_data_write"] <= cap
+            assert ss["read_worker_busy"] <= cap
         rs = call(vs.address, "/admin/ec/recover_stats")
         for key in OLD_RECOVER_KEYS + NEW_RECOVER_KEYS:
             assert key in rs, key
