@@ -15,7 +15,9 @@ A run starts the configuration's daemons (the volume server through
 and warm up (`setup_s`), measures for `--seconds`, then checks what the
 window produced against the plain reference.  Without a TPU, or with
 fewer chips than the cell asks for, it exits non-zero and prints no
-result.  The last line of stdout is the result object and nothing more.
+result.  The last line of stdout is the result object and nothing more;
+its last key, `compared`, and the last lines of stderr hold every number
+that was compared, beside its limit.
 
 `--rehearse` (tests only) runs the traffic file's `rehearse` sizes on the
 CPU backend and says so; `--control <name>` (tests and the control runs
@@ -328,6 +330,9 @@ def execute(args, loaded: dict, run: Run) -> dict:
             shutil.copytree(traced["logdir"], args.keep_trace,
                             dirs_exist_ok=True)
     out["device"] = device
+    # every number compared, beside its limit: the result's last key
+    out["compared"] = {c["name"]: {"value": c["value"], "limit": c["limit"]}
+                       for c in compared}
     run.log(f"attempted {out['attempted']}, failed {out['failed']}, "
             f"correct {correct}")
     return out
@@ -399,6 +404,9 @@ def main(argv=None) -> int:
     if failure:
         print(f"perfbench FAILED: {failure}", file=sys.stderr)
         return code
+    for name, c in out["compared"].items():     # stderr's last lines
+        print(f"compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
     print(json.dumps(out))
     return 0
 

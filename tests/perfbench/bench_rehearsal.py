@@ -11,7 +11,8 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+               "compared"}
 DEVICE_KEYS = {"platform", "kind", "count", "memory_peak_bytes"}
 
 
@@ -44,3 +45,9 @@ def check_result_line(result, trace):
         assert isinstance(m["value"], float)
     if trace:
         assert set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+    # every number compared sits beside its limit, under the last key
+    assert list(result)[-1] == "compared" and result["compared"]
+    for c in result["compared"].values():
+        assert set(c) == {"value", "limit"}
+    assert result["correct"] == all(
+        c["value"] <= c["limit"] for c in result["compared"].values())

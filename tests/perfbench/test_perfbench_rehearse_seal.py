@@ -8,7 +8,10 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+import bench_checks as checks  # noqa: E402
 from bench_rehearsal import check_result_line, run_cell  # noqa: E402
+
+TREE = checks.Tree(checks.ROOT)   # what a cell reports is read, not listed
 
 
 def test_rehearse_seal_end_to_end_line():
@@ -18,7 +21,8 @@ def test_rehearse_seal_end_to_end_line():
     check_result_line(result, trace=False)
     assert result["correct"] is True and result["failed"] == 0
     assert result["attempted"] >= 1
-    assert set(result["metrics"]) == {"bulk_rate", "setup_s"}
+    assert set(result["metrics"]) == TREE.ends_of("seal") \
+        >= {"bulk_rate", "setup_s"}
     assert result["device"]["platform"] == "cpu"
     # every number compared is printed beside its limit
     assert proc.stdout.count("compared: {") >= 6
@@ -32,6 +36,7 @@ def test_rehearse_seal_traced_line():
     # per-layer metrics only; a reader with nothing to read (no TPU
     # plane in a CPU trace) leaves its metric out of the line
     assert "encode_read_s_per_gib" in result["metrics"]
+    assert set(result["metrics"]) <= TREE.layers_of("seal")
     assert "bulk_rate" not in result["metrics"]
     assert "encode_kernel_roofline" not in result["metrics"]
 
