@@ -1505,6 +1505,12 @@ def rebuild_matrix(present: list[int], missing: list[int],
     return chosen, np.array(rows, dtype=np.uint8, copy=True)
 
 
+# the keys of a rebuild's stage seconds in `stage_stats`, one per
+# `ec.rebuild.<key>` stage
+_REBUILD_STAGES = ("read", "dispatch", "h2d", "d2h_wait", "crc",
+                   "write_wait", "write")
+
+
 def rebuild_shards(base: str, mesh=None,
                    batch_units: Optional[int] = None,
                    stage_stats: Optional[dict] = None) -> dict[int, int]:
@@ -1515,8 +1521,14 @@ def rebuild_shards(base: str, mesh=None,
     the rebuilt shards).  Returns {shard_id: crc32c of the rebuilt file}.
 
     stage_stats: optional dict filled with where the rebuild ran
-    (backend, devices, platform, device_kind) and its wall seconds,
-    batch count and transfer bytes.
+    (backend, devices, platform, device_kind), its wall seconds, batch
+    count and transfer bytes, and the seconds of each `ec.rebuild.*`
+    stage: on the pipeline thread, disjoint, `read` (the ten survivors
+    of one batch into the staging slot), `dispatch` (upload + step call)
+    with `h2d` inside it, `d2h_wait` (step + copy back), `crc` and
+    `write_wait` (blocked on the write-behind thread: its full queue,
+    and the join at the end); on the writer thread `write`, which
+    overlaps them.
     """
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -1592,22 +1604,37 @@ def rebuild_shards(base: str, mesh=None,
     # pacer keeps large rebuilds from stalling on dirty-page writeback
     werrs: list[BaseException] = []
     wq: "queue.Queue" = queue.Queue(maxsize=2)
+    timers = dict.fromkeys(_REBUILD_STAGES, 0.0)
+    tlock = threading.Lock()
+    root = tracing.current()  # the rebuild request's span, if sampled
+
+    def add_time(key: str, seconds: float):
+        """The stage accumulator handed to tracing.stage()."""
+        with tlock:
+            timers[key] += seconds
 
     def wb_writer():
+        tracing.swap(root)
         try:
+            n = 0
             while True:
                 item = wq.get()
                 if item is None:
                     return
                 batch_offs, out = item
-                for k, off in enumerate(batch_offs):
-                    width = min(chunk, shard_size - off)
-                    for j, sid in enumerate(missing):
-                        fd = out_fds[sid]
-                        _pwritev_full(fd, [out[k, j, :width]], off)
-                        pacer.wrote(fd, off, width)
+                with tracing.stage("ec.rebuild.write", add_time, "write",
+                                   n, out.nbytes):
+                    for k, off in enumerate(batch_offs):
+                        width = min(chunk, shard_size - off)
+                        for j, sid in enumerate(missing):
+                            fd = out_fds[sid]
+                            _pwritev_full(fd, [out[k, j, :width]], off)
+                            pacer.wrote(fd, off, width)
+                n += 1
         except BaseException as e:
             werrs.append(e)
+        finally:
+            tracing.restore(None)
 
     wt = threading.Thread(target=wb_writer, daemon=True)
     wt.start()
@@ -1615,47 +1642,60 @@ def rebuild_shards(base: str, mesh=None,
         inflight: list = []
 
         def drain_one():
-            batch_offs, out_dev, crc_dev = inflight.pop(0)
-            out = np.ascontiguousarray(np.asarray(out_dev))
+            n, batch_offs, out_dev, crc_dev = inflight.pop(0)
+            with tracing.stage("ec.rebuild.d2h_wait", add_time, "d2h_wait",
+                               n):
+                # blocks until the step is done, then copies back
+                out = np.ascontiguousarray(np.asarray(out_dev))
+                raw = np.asarray(crc_dev)
             pool.note_d2h(out.nbytes, device=dev_label)
-            raw = np.asarray(crc_dev)
-            for k, off in enumerate(batch_offs):
-                width = min(chunk, shard_size - off)
-                fin = finalize(raw[k], chunk)
-                for j, sid in enumerate(missing):
-                    # chunks are full except possibly the last; a short
-                    # final chunk was zero-padded on device, and CRCs of
-                    # zero-extended data un-extend via combine algebra
-                    chunk_crc = int(fin[j]) if width == chunk else \
-                        crc_host.crc32c(out[k, j, :width].tobytes())
-                    crcs[sid] = crc_host.crc32c_combine(
-                        crcs[sid], chunk_crc, width)
-            while True:  # `out` is fresh per drain — safe to hand off
-                if werrs:
-                    raise werrs[0]
-                try:
-                    wq.put((batch_offs, out), timeout=0.5)
-                    return None
-                except queue.Full:
-                    continue
+            with tracing.stage("ec.rebuild.crc", add_time, "crc", n):
+                for k, off in enumerate(batch_offs):
+                    width = min(chunk, shard_size - off)
+                    fin = finalize(raw[k], chunk)
+                    for j, sid in enumerate(missing):
+                        # chunks are full except possibly the last; a
+                        # short final chunk was zero-padded on device,
+                        # and CRCs of zero-extended data un-extend via
+                        # combine algebra
+                        chunk_crc = int(fin[j]) if width == chunk else \
+                            crc_host.crc32c(out[k, j, :width].tobytes())
+                        crcs[sid] = crc_host.crc32c_combine(
+                            crcs[sid], chunk_crc, width)
+            with tracing.stage("ec.rebuild.write_wait", add_time,
+                               "write_wait", n, out.nbytes):
+                while True:  # `out` is fresh per drain: safe to hand off
+                    if werrs:
+                        raise werrs[0]
+                    try:
+                        wq.put((batch_offs, out), timeout=0.5)
+                        return None
+                    except queue.Full:
+                        continue
 
         for step_i, start in enumerate(range(0, len(offsets), b)):
             buf = slots[step_i % 2].payload
             batch_offs = offsets[start:start + b]
-            for k, off in enumerate(batch_offs):
-                width = min(chunk, shard_size - off)
-                for i, f in enumerate(inputs):
-                    f.seek(off)
-                    view = memoryview(buf[k, i])[:width]
-                    got = f.readinto(view)
-                    if got < width:
-                        buf[k, i, got:width] = 0
-                    if width < chunk:
-                        buf[k, i, width:] = 0
-            dev = jax.device_put(buf, sharding)
-            pool.note_h2d(buf.nbytes, device=dev_label)
-            out_dev, crc_dev = step(dev)
-            inflight.append((batch_offs, out_dev, crc_dev))
+            with tracing.stage("ec.rebuild.read", add_time, "read", step_i,
+                               buf.nbytes):
+                for k, off in enumerate(batch_offs):
+                    width = min(chunk, shard_size - off)
+                    for i, f in enumerate(inputs):
+                        f.seek(off)
+                        view = memoryview(buf[k, i])[:width]
+                        got = f.readinto(view)
+                        if got < width:
+                            buf[k, i, got:width] = 0
+                        if width < chunk:
+                            buf[k, i, width:] = 0
+            with tracing.stage("ec.rebuild.dispatch", add_time, "dispatch",
+                               step_i, buf.nbytes):
+                with tracing.stage("ec.rebuild.h2d", add_time, "h2d",
+                                   step_i, buf.nbytes):
+                    dev = jax.device_put(buf, sharding)
+                pool.note_h2d(buf.nbytes, device=dev_label)
+                out_dev, crc_dev = step(dev)
+            inflight.append((step_i, batch_offs, out_dev, crc_dev))
             if len(inflight) >= 2:
                 drain_one()
         while inflight:
@@ -1663,11 +1703,12 @@ def rebuild_shards(base: str, mesh=None,
     finally:
         for sl in slots:
             pool.release(sl)
-        try:
-            wq.put(None, timeout=5)
-        except queue.Full:
-            pass
-        wt.join(timeout=120)
+        with tracing.stage("ec.rebuild.write_wait", add_time, "write_wait"):
+            try:
+                wq.put(None, timeout=5)
+            except queue.Full:
+                pass
+            wt.join(timeout=120)
         for f in inputs:
             f.close()
         for fd in out_fds.values():
@@ -1683,7 +1724,8 @@ def rebuild_shards(base: str, mesh=None,
             "device_shard": dev_label,
             "platform": dev0.platform,
             "device_kind": dev0.device_kind,
-            "wall": round(time.perf_counter() - wall0, 3),
+            "wall": round(time.perf_counter() - wall0, 6),
+            **{k: round(v, 6) for k, v in timers.items()},
             "batches": n_batches,
             "batch_units": b,
             "missing": list(missing),

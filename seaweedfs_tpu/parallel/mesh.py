@@ -334,12 +334,15 @@ def make_sharded_apply(mesh: Mesh, matrix: np.ndarray):
         out_shardings=out_shardings,
         donate_argnums=(0,),
     )
-    def step(data):
-        out = _parity_bits_matmul(bit_matrix, data)
+    def rebuild_apply(data):
+        # a name of its own: on a trace's XLA Modules line the encode
+        # step is `jit_step(` and this must not read as it
+        with jax.named_scope("ec.rebuild.apply"):
+            out = _parity_bits_matmul(bit_matrix, data)
         return out, batched_crc32c_raw(out)
 
-    _APPLY_CACHE[cache_key] = step
-    return step
+    _APPLY_CACHE[cache_key] = rebuild_apply
+    return rebuild_apply
 
 
 def words_capable(mesh: Mesh, chunk_len: int,

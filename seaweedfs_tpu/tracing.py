@@ -335,14 +335,14 @@ def _annotation_class():
 class stage:
     """``with stage(name, add, key[, n, nbytes]):`` — one pipeline stage
     of a device EC path, timed once.  `name` is one of the fixed
-    ``ec.encode.*`` / ``ec.recover.*`` names.  Meant for per-batch and
-    per-block sites — a few hundred calls a GiB — never per row or per
-    request.
+    ``ec.encode.*`` / ``ec.recover.*`` / ``ec.rebuild.*`` names.  Meant
+    for per-batch and per-block sites — a few hundred calls a GiB —
+    never per row or per request.
 
       * ``add(key, seconds)``, the stage accumulator (the encode
-        pipeline's timers, ``RecoverStats.add_stage``), gets the
-        ``perf_counter`` elapsed — the stage's counter and its span are
-        the same measurement;
+        pipeline's and the rebuild's timers, ``RecoverStats.add_stage``),
+        gets the ``perf_counter`` elapsed — the stage's counter and its
+        span are the same measurement;
       * when the thread's current span is sampled the stage is recorded
         as its child (and is the current span inside the block, so
         nested stages hang under it); otherwise no Span is built;

@@ -222,11 +222,21 @@ class TestEcBackendTpuForcesRebuild:
         from seaweedfs_tpu.util import platform as plat
         from seaweedfs_tpu.volume_server.server import VolumeServer
 
+        # the test's own maintenance setting, as the benchmark's
+        # configurations state theirs: with the curator on, a tick that
+        # lands after delete_shards repairs the volume too (ROADMAP C3)
+        # and rebuild_shards runs twice (seen with WEED_MAINT_INTERVAL
+        # 0.3: the second call is the worker's ec.rebuild job); 16
+        # slots, because under the whole suite's load the node has been
+        # seen with eight volumes (one grown here, seven by an assign
+        # that found none writable) and so no slot left for the shards
+        monkeypatch.setenv("WEED_MAINT", "0")
         master = MasterServer(port=0, pulse_seconds=0.2)
         master.start()
         (tmp_path / "vs").mkdir()
         vs = VolumeServer([str(tmp_path / "vs")], master.address, port=0,
-                          pulse_seconds=0.2, ec_encoder_backend="tpu")
+                          pulse_seconds=0.2, ec_encoder_backend="tpu",
+                          max_volume_counts=[16])
         vs.start()
         vs.heartbeat_once()
         try:

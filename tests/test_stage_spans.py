@@ -42,6 +42,7 @@ RECOVER_SPANS = {"ec.recover.fetch", "ec.recover.decode.queue",
                  "ec.recover.decode", "ec.recover.decode.stack",
                  "ec.recover.decode.h2d", "ec.recover.decode.apply",
                  "ec.recover.serve"}
+REBUILD_LOST = (0, 3, 11, 13)
 NEW_ENCODE_KEYS = ("read_dat", "read_data_write", "read_slot_wait", "h2d",
                    "d2h_wait", "crc_host", "read_worker_busy")
 OLD_ENCODE_KEYS = ("read", "dispatch", "encode_crc", "write", "wall")
@@ -111,7 +112,8 @@ def paths(tmp_path_factory):
     """One CPU seal through the device pipeline and one CPU degraded
     read through the device decode, once for the module: the seal's
     `stage_stats`, the recover stats before and after, the stack counter
-    before and after."""
+    before and after; then one CPU rebuild of four of its shard files
+    through the device pipeline, and its `stage_stats`."""
     d = tmp_path_factory.mktemp("stage_spans")
     mp = pytest.MonkeyPatch()
     mp.setenv("WEED_EC_RECOVER_DEVICE", "1")
@@ -128,10 +130,15 @@ def paths(tmp_path_factory):
         ev.close()
         after = recover_mod.STATS.snapshot()
         stacks_after = _stack_counts()
+        for sid in REBUILD_LOST:
+            os.remove(base + enc.to_ext(sid))
+        rebuild_stats: dict = {}
+        be.rebuild_shards(base, stage_stats=rebuild_stats)
     finally:
         mp.undo()
     assert reads > 50
     return {"dir": d, "base": base, "stage_stats": stage_stats,
+            "rebuild_stats": rebuild_stats,
             "recover_before": before, "recover_after": after,
             "stacks_before": stacks_before, "stacks_after": stacks_after}
 
@@ -158,8 +165,11 @@ def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch):
     if kind == "harness_record":
         section, _, key = reader["key"].partition(".")
         assert section == "stage_stats"
-        assert key in paths["stage_stats"], sorted(paths["stage_stats"])
-        assert isinstance(paths["stage_stats"][key], float)
+        # the replies a driver keeps: a seal's or a rebuild's
+        got = paths[{"seal": "stage_stats",
+                     "rebuild": "rebuild_stats"}[reader["record"]]]
+        assert key in got, sorted(got)
+        assert isinstance(got[key], float)
     elif kind == "admin_json":
         assert reader["path"] == "/admin/ec/recover_stats"
         after = paths["recover_after"]
@@ -301,7 +311,8 @@ def test_recover_kernel_pattern_matches_apply_pallas():
 @pytest.mark.parametrize("scope,build", [
     ("ec.encode.step", "step"), ("ec.encode.fused", "fused"),
     ("ec.encode.fused_words", "fused_words"),
-    ("ec.recover.apply", "apply"), ("ec.crc32c", "fused")])
+    ("ec.recover.apply", "apply"), ("ec.crc32c", "fused"),
+    ("ec.rebuild.apply", "rebuild")])
 def test_kernel_scope_names_are_in_the_lowered_program(scope, build):
     """The scope is what a trace shows whatever the function is called:
     it has to reach the compiled program's metadata."""
@@ -319,6 +330,13 @@ def test_kernel_scope_names_are_in_the_lowered_program(scope, build):
         step = mesh_mod.make_parity_step(_ec_mesh(1), fused_crc=True)
         lowered = step.lower(jax.ShapeDtypeStruct((10, 1, 128), jnp.int32),
                              jax.ShapeDtypeStruct((4, 1, 128), jnp.int32))
+    elif build == "rebuild":
+        from seaweedfs_tpu.parallel.batched_encode import rebuild_matrix
+
+        _, m = rebuild_matrix([1, 2, 4, 5, 6, 7, 8, 9, 10, 12],
+                              list(REBUILD_LOST))
+        step = mesh_mod.make_sharded_apply(_ec_mesh(1), m)
+        lowered = step.lower(jax.ShapeDtypeStruct((1, 10, 512), jnp.uint8))
     elif build == "fused_words":
         lowered = jax.jit(lambda w: rs_pallas.fused_encode_words(
             matrix, w, interpret=True)).lower(
