@@ -40,12 +40,13 @@ are returned and persisted in the .vif sidecar for scrub tooling.
 from __future__ import annotations
 
 import ctypes
+import functools
 import math
 import os
 import queue
 import threading
 import time
-from collections import deque
+from collections import defaultdict, deque
 from concurrent import futures
 from dataclasses import dataclass, field
 from typing import Optional
@@ -192,15 +193,16 @@ def _write_knobs() -> tuple[bool, int, int, bool]:
 def _preadv_full(fd: int, view: memoryview, offset: int):
     """Positional read that fills `view` or raises OSError: a short read
     is continued from where the kernel stopped, and end of file before
-    the planned bytes (a .dat truncated under the seal) fails the encode
-    rather than sealing zeros."""
+    the planned bytes (a .dat truncated under the seal, a survivor under
+    the rebuild) fails the job rather than sealing or rebuilding from
+    zeros."""
     got = 0
     while got < view.nbytes:
         n = os.preadv(fd, [view[got:]], offset + got)
         if n <= 0:
             raise OSError(
                 "preadv reached end of file: %d of %d bytes at offset %d "
-                "(.dat shorter than planned)" % (got, view.nbytes, offset))
+                "(file shorter than planned)" % (got, view.nbytes, offset))
         got += n
 
 
@@ -398,25 +400,151 @@ def encode_volumes(bases: list[str], large_block: Optional[int] = None,
         root.finish()
 
 
-class _PipelineIO:
-    """Shared reader/writer scaffolding of the streaming pipeline:
-    pooled staging slots, backpressure queues, the read stage (fills
-    slots and writes data shards), the writer thread (appends parity
-    shards), and the torn-shutdown sequencing.  The device compute
-    stages differ only in what happens between `ready` and `parity_q`.
+class _ReadStage:
+    """The read stage of both streaming pipelines (the seal's and the
+    rebuild's): one coordinator thread and `read_workers` I/O workers
+    that fill pooled staging slots a batch at a time and in batch order,
+    ahead of the thread that uploads them.
 
-    The read stage is one coordinator thread and `read_workers` I/O
-    workers.  For each batch the coordinator takes a free slot, hands
-    the batch's (unit, row) steps — read the row's .dat bytes into its
-    place in the slot (preadv), write them to its data shard (pwritev),
-    both positional and both outside the GIL — to the workers, waits for
-    all of them and only then puts the slot on `ready`: batches reach
-    the dispatch loop and the completion thread in order, which the CRC
-    chaining needs, while the rows inside a batch overlap.  The rows are
-    independent (each its own 1 MiB of the slot and its own range of one
-    of ten files), and one worker runs the same loop.  `read_workers`
-    follows the cores the process may use (`_read_workers`); tests pass
-    it to the constructor.
+    `batches` yields, a batch, `(steps, meanwhile, item)`.  The
+    coordinator takes a free slot, hands the deque `steps` to the
+    workers (each takes steps off it until it is empty and runs the
+    client's `step(buf, step, split)`: one row read into its place in
+    the slot by a positional call outside the GIL), runs
+    `meanwhile(buf)` if there is one, waits for all of them and only
+    then puts `(slot, *item)` on `ready`: batches reach the dispatch
+    loop, the CRC chaining and the writer in order, while the rows
+    inside a batch overlap.  The rows are independent (each its own
+    chunk of the slot and its own range of a file), and one worker runs
+    the same loop.  The client gives a slot back through `free_slots`
+    once its batch no longer needs it.  `read_workers` follows the cores
+    the process may use (`_read_workers`); tests pass it to the
+    constructor.
+
+    Seconds, through `add_time`: `read` = the stage's wall, one block a
+    batch around the fan-out (the `<span>.read` stage); `read_slot_wait`
+    = the coordinator blocked on a free slot (`<span>.stage_wait`: the
+    stages behind set the pace); `read_worker_busy` = thread-seconds the
+    workers spent in their loops, so it may exceed `read`:
+    `read_worker_busy / read` is how many workers the stage kept busy
+    (1 = the pool bought nothing, N = perfect overlap).  A step may
+    split its own time further into `split` (key -> seconds, bare clock
+    readings: a span per 1 MiB row would cost more than it tells); the
+    worker adds those up once a batch.
+
+    A failing step stops the other workers and the coordinator; the
+    error is in `errors`, `stop` is set, and `get` then returns None to
+    whoever waits on `ready`."""
+
+    def __init__(self, span: str, batches, step, slots, add_time,
+                 read_workers: Optional[int] = None):
+        self.batches, self.step, self.add_time = batches, step, add_time
+        self._read_span = span + ".read"
+        self._wait_span = span + ".stage_wait"
+        # the job's span (the seal's, the rebuild request's), installed
+        # on the coordinator
+        self.root = tracing.current()
+        self.read_workers = read_workers or _read_workers()
+        threads = span.replace(".", "-")    # ec-encode-read, ec-rebuild-read
+        self._workers = futures.ThreadPoolExecutor(
+            self.read_workers, thread_name_prefix=threads + "-read")
+        self.free_slots: "queue.Queue" = queue.Queue()
+        for ls in slots:
+            self.free_slots.put(ls)
+        self.ready: "queue.Queue" = queue.Queue(maxsize=len(slots))
+        self.errors: list[BaseException] = []
+        self.stop = threading.Event()
+        self._coordinator = threading.Thread(
+            target=self._coordinate, daemon=True, name=threads + "-reader")
+
+    def put(self, q, item) -> bool:
+        while not self.stop.is_set():
+            try:
+                q.put(item, timeout=0.5)
+                return True
+            except queue.Full:
+                continue
+        return False
+
+    def get(self, q):
+        while not self.stop.is_set():
+            try:
+                return q.get(timeout=0.5)
+            except queue.Empty:
+                continue
+        return None
+
+    def _run_steps(self, buf: np.ndarray, steps: deque):
+        """One I/O worker's share of a batch."""
+        split: dict = defaultdict(float)
+        step = self.step
+        t_in = time.perf_counter()
+        try:
+            while not self.stop.is_set():
+                try:
+                    item = steps.popleft()
+                except IndexError:
+                    break
+                step(buf, item, split)
+        except BaseException as e:  # fails the job, stops the others
+            self.errors.append(e)
+            self.stop.set()
+        self.add_time("read_worker_busy", time.perf_counter() - t_in)
+        for key, seconds in split.items():
+            self.add_time(key, seconds)
+
+    def _coordinate(self):
+        tracing.swap(self.root)
+        try:
+            for n, (steps, meanwhile, item) in enumerate(self.batches):
+                with tracing.stage(self._wait_span, self.add_time,
+                                   "read_slot_wait", n):
+                    slot = self.get(self.free_slots)
+                if slot is None:
+                    return
+                buf = slot.payload
+                with tracing.stage(self._read_span, self.add_time, "read",
+                                   n, len(steps) * buf.shape[-1]):
+                    jobs = [self._workers.submit(self._run_steps, buf, steps)
+                            for _ in range(self.read_workers)]
+                    if meanwhile is not None:
+                        meanwhile(buf)
+                    for job in jobs:
+                        job.result()
+                if not self.put(self.ready, (slot, *item)):
+                    return      # also when a stop cut the batch short
+            self.put(self.ready, None)
+        except BaseException as e:  # propagate to the pipeline's thread
+            self.errors.append(e)
+            self.stop.set()
+        finally:
+            tracing.restore(None)
+
+    def start(self):
+        self._coordinator.start()
+
+    def close(self):
+        """Stop and join the coordinator and the workers.  Slots, files
+        and leases are the client's to release, after this."""
+        self.stop.set()
+        if self._coordinator.is_alive():
+            self._coordinator.join(timeout=30)
+        self._workers.shutdown(wait=True)   # they saw the stop
+
+
+class _PipelineIO:
+    """Shared reader/writer scaffolding of the streaming encode
+    pipeline: pooled staging slots, backpressure queues, the read stage
+    (fills slots and writes data shards), the writer thread (appends
+    parity shards), and the torn-shutdown sequencing.  The device
+    compute stages differ only in what happens between `ready` and
+    `parity_q`.
+
+    The read stage is a `_ReadStage`, which the rebuild composes too.
+    The seal hands it, a batch, the (k, unit, row) steps of the rows
+    that hold .dat bytes, and as a worker's step: read the row's .dat
+    bytes into its place in the slot (preadv), write them to its data
+    shard (pwritev), both positional and both outside the GIL.
 
     Staging slots are leased from the device slab pool so repeated
     encodes with the same geometry reuse the same buffers.  Two layouts:
@@ -446,16 +574,10 @@ class _PipelineIO:
         self.n_batches = (len(units) + b - 1) // b
         # raw fds: the workers read positionally, sharing no file offset
         self.dats = [os.open(p.base + ".dat", os.O_RDONLY) for p in plans]
-        self.read_workers = read_workers or _read_workers()
-        self._workers = futures.ThreadPoolExecutor(
-            self.read_workers, thread_name_prefix="ec-encode-read")
-        # busy seconds per stage.  read = the read stage's wall, one
-        # block a batch around the fan-out to the I/O workers;
-        # read_worker_busy = thread-seconds the workers spent on their
-        # steps, of which read_dat and read_data_write are the two calls
-        # (so the three may exceed read: read_worker_busy / read is how
-        # many workers the stage kept busy);
-        # dispatch = upload + step call (h2d is its upload);
+        # busy seconds per stage.  read, read_worker_busy and
+        # read_slot_wait are the read stage's (_ReadStage); read_dat and
+        # read_data_write split its workers' seconds into their two
+        # calls; dispatch = upload + step call (h2d is its upload);
         # encode_crc = the completion thread's per-batch block (d2h_wait
         # = blocked in the copy back, crc_host = the host work after it)
         self.timers = {"read": 0.0, "read_worker_busy": 0.0,
@@ -466,21 +588,20 @@ class _PipelineIO:
         self.tlock = threading.Lock()
         shape = (b, DATA_SHARDS, chunk) if layout == "bk" \
             else (DATA_SHARDS, b, chunk)
-        self._slot_leases = []
-        self.free_slots: "queue.Queue" = queue.Queue()
         key = ("ec-stage", layout, shape)
         nbytes = b * DATA_SHARDS * chunk
-        for _ in range(n_slots):
-            ls = pool.lease(key, lambda: np.zeros(shape, dtype=np.uint8),
-                            nbytes)
-            self._slot_leases.append(ls)
-            self.free_slots.put(ls)
-        self.ready: "queue.Queue" = queue.Queue(maxsize=n_slots)
+        self._slot_leases = [
+            pool.lease(key, lambda: np.zeros(shape, dtype=np.uint8), nbytes)
+            for _ in range(n_slots)]
+        self.reads = _ReadStage("ec.encode", self._batches(),
+                                self._read_row, self._slot_leases,
+                                self.add_time, read_workers)
+        self.read_workers = self.reads.read_workers
+        self.free_slots, self.ready = self.reads.free_slots, self.reads.ready
+        # one stop and one list of errors for every thread of the seal
+        self.errors, self.stop = self.reads.errors, self.reads.stop
+        self.put, self.get = self.reads.put, self.reads.get
         self.parity_q: "queue.Queue" = queue.Queue(maxsize=n_slots)
-        self.errors: list[BaseException] = []
-        self.stop = threading.Event()
-        self._rt = threading.Thread(target=self._reader, daemon=True,
-                                    name="ec-encode-reader")
         self._wt = threading.Thread(target=self._writer, daemon=True,
                                     name="ec-encode-writer")
 
@@ -489,102 +610,46 @@ class _PipelineIO:
         with self.tlock:
             self.timers[key] = self.timers.get(key, 0.0) + seconds
 
-    def put(self, q, item) -> bool:
-        while not self.stop.is_set():
-            try:
-                q.put(item, timeout=0.5)
-                return True
-            except queue.Full:
-                continue
-        return False
+    def _read_row(self, buf: np.ndarray, step, split: dict):
+        """A worker's step: the real .dat bytes of row `i` of unit `k`
+        into their place in the staging slot (zero past them), and on to
+        data shard `i`."""
+        k, u, i = step
+        t = time.perf_counter()
+        row = buf[i, k] if self.layout == "kb" else buf[k, i]
+        start = u.row_start + i * u.block_size + u.col
+        real = min(self.chunk, self.plans[u.vol].dat_size - start)
+        _preadv_full(self.dats[u.vol], memoryview(row)[:real], start)
+        if real < self.chunk:
+            row[real:] = 0
+        t1 = time.perf_counter()
+        split["read_dat"] += t1 - t
+        self.writers[u.vol].write(i, [row[:real]], u.shard_off)
+        split["read_data_write"] += time.perf_counter() - t1
 
-    def get(self, q):
-        while not self.stop.is_set():
-            try:
-                return q.get(timeout=0.5)
-            except queue.Empty:
-                continue
-        return None
-
-    def _read_steps(self, buf: np.ndarray, steps: deque):
-        """One I/O worker's share of a batch: take (k, unit, row) steps
-        off the batch's deque until it is empty, and for each read the
-        row's real .dat bytes into its place in the staging slot (zero
-        past them) and write them to data shard `row`.  The split of the
-        two is taken with bare clock readings, one pair a row: a span
-        per 1 MiB row would cost more than it tells."""
-        t_dat = t_write = 0.0
-        t_in = t = time.perf_counter()
-        try:
-            while not self.stop.is_set():
-                try:
-                    k, u, i = steps.popleft()
-                except IndexError:
-                    break
-                row = buf[i, k] if self.layout == "kb" else buf[k, i]
-                start = u.row_start + i * u.block_size + u.col
-                real = min(self.chunk, self.plans[u.vol].dat_size - start)
-                _preadv_full(self.dats[u.vol], memoryview(row)[:real],
-                             start)
-                if real < self.chunk:
-                    row[real:] = 0
-                t1 = time.perf_counter()
-                t_dat += t1 - t
-                self.writers[u.vol].write(i, [row[:real]], u.shard_off)
-                t = time.perf_counter()
-                t_write += t - t1
-        except BaseException as e:  # fails the seal, stops the others
-            self.errors.append(e)
-            self.stop.set()
-        with self.tlock:
-            self.timers["read_dat"] += t_dat
-            self.timers["read_data_write"] += t_write
-            self.timers["read_worker_busy"] += t - t_in
-
-    def _read_batch(self, n: int, batch, buf: np.ndarray, k_max: int):
-        """Fan batch n's rows out to the I/O workers and wait for all of
-        them, so batches reach `ready` in order.  Padding rows up to the
-        compacted height are zeroed meanwhile: they feed the parity math
-        but neither files nor CRCs (files are ftruncate zeros, CRC is
-        the cached zeros CRC)."""
-        steps = deque((k, u, i) for k, u in enumerate(batch)
-                      for i in range(u.real_rows))
-        with tracing.stage("ec.encode.read", self.add_time, "read", n,
-                           len(steps) * self.chunk):
-            jobs = [self._workers.submit(self._read_steps, buf, steps)
-                    for _ in range(self.read_workers)]
-            for k, u in enumerate(batch):
-                for i in range(u.real_rows, k_max):
-                    if self.layout == "kb":
-                        buf[i, k].fill(0)
-                    else:
-                        buf[k, i].fill(0)
-            for job in jobs:
-                job.result()
-
-    def _reader(self):
-        tracing.swap(self.root)
-        try:
-            for n in range(self.n_batches):
-                batch = self.units[n * self.b:(n + 1) * self.b]
-                with tracing.stage("ec.encode.stage_wait", self.add_time,
-                                   "read_slot_wait", n):
-                    slot = self.get(self.free_slots)
-                if slot is None:
-                    return
+    def _zero_padding(self, batch, k_max: int, buf: np.ndarray):
+        """Padding rows up to the compacted height are zeroed while the
+        workers read: they feed the parity math but neither files nor
+        CRCs (files are ftruncate zeros, CRC is the cached zeros CRC)."""
+        for k, u in enumerate(batch):
+            for i in range(u.real_rows, k_max):
                 if self.layout == "kb":
-                    k_max = max(u.real_rows for u in batch)
+                    buf[i, k].fill(0)
                 else:
-                    k_max = DATA_SHARDS
-                self._read_batch(n, batch, slot.payload, k_max)
-                if not self.put(self.ready, (slot, batch, k_max)):
-                    return      # also when a stop cut the batch short
-            self.put(self.ready, None)
-        except BaseException as e:  # propagate to the main thread
-            self.errors.append(e)
-            self.stop.set()
-        finally:
-            tracing.restore(None)
+                    buf[k, i].fill(0)
+
+    def _batches(self):
+        for n in range(self.n_batches):
+            batch = self.units[n * self.b:(n + 1) * self.b]
+            if self.layout == "kb":
+                k_max = max(u.real_rows for u in batch)
+            else:
+                k_max = DATA_SHARDS
+            steps = deque((k, u, i) for k, u in enumerate(batch)
+                          for i in range(u.real_rows))
+            yield (steps,
+                   functools.partial(self._zero_padding, batch, k_max),
+                   (batch, k_max))
 
     def _writer(self):
         tracing.swap(self.root)
@@ -613,15 +678,13 @@ class _PipelineIO:
             tracing.restore(None)
 
     def start(self):
-        self._rt.start()
+        self.reads.start()
         self._wt.start()
 
     def finish(self):
         self.put(self.parity_q, None)
         self._wt.join(timeout=60)
-        self.stop.set()
-        self._rt.join(timeout=30)
-        self._workers.shutdown(wait=True)   # they saw the stop
+        self.reads.close()
         for fd in self.dats:
             os.close(fd)
         for w in self.writers.values():
@@ -1505,10 +1568,14 @@ def rebuild_matrix(present: list[int], missing: list[int],
     return chosen, np.array(rows, dtype=np.uint8, copy=True)
 
 
-# the keys of a rebuild's stage seconds in `stage_stats`, one per
-# `ec.rebuild.<key>` stage
-_REBUILD_STAGES = ("read", "dispatch", "h2d", "d2h_wait", "crc",
+# the keys of a rebuild's stage seconds in `stage_stats`: one per
+# `ec.rebuild.<key>` stage, but for the read stage's wait for a slot
+# (`ec.rebuild.stage_wait` -> read_slot_wait) and its workers'
+# thread-seconds (read_worker_busy: no span)
+_REBUILD_STAGES = ("read", "read_worker_busy", "read_slot_wait",
+                   "read_wait", "dispatch", "h2d", "d2h_wait", "crc",
                    "write_wait", "write")
+_REBUILD_SLOTS = 3  # staging slots: one being filled, two in flight
 
 
 def rebuild_shards(base: str, mesh=None,
@@ -1520,15 +1587,32 @@ def rebuild_shards(base: str, mesh=None,
     chunks batch into (B, 10, L) device dispatches with fused CRC32C of
     the rebuilt shards).  Returns {shard_id: crc32c of the rebuilt file}.
 
+    Three stages, each ahead of the next:
+
+      read stage      — the encode pipeline's (`_ReadStage`: a coordinator
+                        and a few I/O workers) fills pooled staging slots
+                        from the ten survivor files, a batch at a time
+                        and in batch order; a survivor that ends before
+                        its planned bytes fails the rebuild;
+      pipeline thread — (this one) uploads batch n and calls the step,
+                        then drains batch n-1: waits for its step and
+                        the copy back, gives the batch's slot back to
+                        the read stage, chains the rebuilt files'
+                        CRC32Cs and hands the rows to
+      writer thread   — which pwritev()s them, paced.
+
+    A rebuild that fails removes the shard files it created.
+
     stage_stats: optional dict filled with where the rebuild ran
     (backend, devices, platform, device_kind), its wall seconds, batch
-    count and transfer bytes, and the seconds of each `ec.rebuild.*`
-    stage: on the pipeline thread, disjoint, `read` (the ten survivors
-    of one batch into the staging slot), `dispatch` (upload + step call)
-    with `h2d` inside it, `d2h_wait` (step + copy back), `crc` and
+    count and transfer bytes, and the seconds of each stage.  On the
+    pipeline thread, disjoint: `read_wait` (blocked on a filled slot:
+    the read stage sets the pace), `dispatch` (upload + step call) with
+    `h2d` inside it, `d2h_wait` (step + copy back), `crc` and
     `write_wait` (blocked on the write-behind thread: its full queue,
-    and the join at the end); on the writer thread `write`, which
-    overlaps them.
+    and the join at the end).  Overlapping them: the read stage's `read`,
+    `read_slot_wait` and `read_worker_busy` over its `read_workers`
+    workers (see `_ReadStage`), and on the writer thread `write`.
     """
     import jax
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -1571,14 +1655,15 @@ def rebuild_shards(base: str, mesh=None,
         batch_units = max(1, TARGET_BATCH_BYTES // (DATA_SHARDS * chunk))
     b = min(batch_units, len(offsets))
     b = max(n_data, ((b + n_data - 1) // n_data) * n_data)
+    n_batches = -(-len(offsets) // b)
 
     step = make_sharded_apply(mesh, matrix)
     sharding = NamedSharding(mesh, P("data", None, "block"))
     pool = get_pool()
     dev_label = (str(mesh.devices.flat[0]) if mesh.devices.size == 1
                  else f"sharded:{mesh.devices.size}")
-    # two pooled staging buffers: a buffer is refilled only after its
-    # batch drained (which implies the host->device transfer completed);
+    # pooled staging buffers: a buffer is refilled only after its batch
+    # drained (which implies the host->device transfer completed);
     # leased from the slab pool so consecutive rebuilds with the same
     # geometry reuse them instead of reallocating.  The lease carries
     # the mesh's placement label: a rebuild against one device set must
@@ -1588,16 +1673,8 @@ def rebuild_shards(base: str, mesh=None,
                         lambda: np.zeros((b, DATA_SHARDS, chunk),
                                          dtype=np.uint8),
                         b * DATA_SHARDS * chunk, device=dev_label)
-             for _ in range(2)]
+             for _ in range(_REBUILD_SLOTS)]
 
-    inputs = [open(base + to_ext(i), "rb") for i in chosen]
-    _, _, flush_bytes, drop_cache = _write_knobs()
-    pacer = _WritebackPacer(flush_bytes, drop_cache)
-    out_fds = {sid: os.open(base + to_ext(sid),
-                            os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o644)
-               for sid in missing}
-    for fd in out_fds.values():
-        os.ftruncate(fd, shard_size)
     crcs = {sid: 0 for sid in missing}
     # write-behind: rebuilt batches are handed to a writer thread so the
     # next device dispatch isn't serialized behind checked pwritevs; the
@@ -1612,6 +1689,28 @@ def rebuild_shards(base: str, mesh=None,
         """The stage accumulator handed to tracing.stage()."""
         with tlock:
             timers[key] += seconds
+
+    # raw fds: the read stage's workers read positionally, sharing no
+    # file offset
+    in_fds: list[int] = []
+    out_fds: dict[int, int] = {}
+
+    def survivor_batches():
+        for start in range(0, len(offsets), b):
+            batch_offs = offsets[start:start + b]
+            yield (deque((k, i, off) for k, off in enumerate(batch_offs)
+                         for i in range(DATA_SHARDS)),
+                   None, (batch_offs,))
+
+    def read_row(buf: np.ndarray, step, split: dict):
+        """A worker's step: chunk `k` of survivor `i` into its row of
+        the staging slot, zero past a short last chunk."""
+        k, i, off = step
+        width = min(chunk, shard_size - off)
+        row = buf[k, i]
+        _preadv_full(in_fds[i], memoryview(row)[:width], off)
+        if width < chunk:
+            row[width:] = 0
 
     def wb_writer():
         tracing.swap(root)
@@ -1636,18 +1735,34 @@ def rebuild_shards(base: str, mesh=None,
         finally:
             tracing.restore(None)
 
-    wt = threading.Thread(target=wb_writer, daemon=True)
-    wt.start()
+    reads = wt = None
+    done = False
     try:
+        for i in chosen:
+            in_fds.append(os.open(base + to_ext(i), os.O_RDONLY))
+        reads = _ReadStage("ec.rebuild", survivor_batches(), read_row,
+                           slots, add_time)
+        reads.start()
+        _, _, flush_bytes, drop_cache = _write_knobs()
+        pacer = _WritebackPacer(flush_bytes, drop_cache)
+        for sid in missing:
+            out_fds[sid] = os.open(base + to_ext(sid),
+                                   os.O_CREAT | os.O_TRUNC | os.O_WRONLY,
+                                   0o644)
+            os.ftruncate(out_fds[sid], shard_size)
+        wt = threading.Thread(target=wb_writer, daemon=True,
+                              name="ec-rebuild-writer")
+        wt.start()
         inflight: list = []
 
         def drain_one():
-            n, batch_offs, out_dev, crc_dev = inflight.pop(0)
+            n, slot, batch_offs, out_dev, crc_dev = inflight.pop(0)
             with tracing.stage("ec.rebuild.d2h_wait", add_time, "d2h_wait",
                                n):
                 # blocks until the step is done, then copies back
                 out = np.ascontiguousarray(np.asarray(out_dev))
                 raw = np.asarray(crc_dev)
+            reads.free_slots.put(slot)  # the step read it: refill it
             pool.note_d2h(out.nbytes, device=dev_label)
             with tracing.stage("ec.rebuild.crc", add_time, "crc", n):
                 for k, off in enumerate(batch_offs):
@@ -1673,51 +1788,51 @@ def rebuild_shards(base: str, mesh=None,
                     except queue.Full:
                         continue
 
-        for step_i, start in enumerate(range(0, len(offsets), b)):
-            buf = slots[step_i % 2].payload
-            batch_offs = offsets[start:start + b]
-            with tracing.stage("ec.rebuild.read", add_time, "read", step_i,
-                               buf.nbytes):
-                for k, off in enumerate(batch_offs):
-                    width = min(chunk, shard_size - off)
-                    for i, f in enumerate(inputs):
-                        f.seek(off)
-                        view = memoryview(buf[k, i])[:width]
-                        got = f.readinto(view)
-                        if got < width:
-                            buf[k, i, got:width] = 0
-                        if width < chunk:
-                            buf[k, i, width:] = 0
+        for n in range(n_batches):
+            with tracing.stage("ec.rebuild.read_wait", add_time,
+                               "read_wait", n):
+                item = reads.get(reads.ready)
+            if item is None:    # stopped: by what is in `errors`
+                raise reads.errors[0]
+            slot, batch_offs = item
+            buf = slot.payload
             with tracing.stage("ec.rebuild.dispatch", add_time, "dispatch",
-                               step_i, buf.nbytes):
+                               n, buf.nbytes):
                 with tracing.stage("ec.rebuild.h2d", add_time, "h2d",
-                                   step_i, buf.nbytes):
+                                   n, buf.nbytes):
                     dev = jax.device_put(buf, sharding)
                 pool.note_h2d(buf.nbytes, device=dev_label)
                 out_dev, crc_dev = step(dev)
-            inflight.append((step_i, batch_offs, out_dev, crc_dev))
+            inflight.append((n, slot, batch_offs, out_dev, crc_dev))
             if len(inflight) >= 2:
                 drain_one()
         while inflight:
             drain_one()
+        done = True
     finally:
+        if wt is not None:
+            with tracing.stage("ec.rebuild.write_wait", add_time,
+                               "write_wait"):
+                try:
+                    wq.put(None, timeout=5)
+                except queue.Full:
+                    pass
+                wt.join(timeout=120)
+        if reads is not None:
+            reads.close()   # before its slots and files go
         for sl in slots:
             pool.release(sl)
-        with tracing.stage("ec.rebuild.write_wait", add_time, "write_wait"):
-            try:
-                wq.put(None, timeout=5)
-            except queue.Full:
-                pass
-            wt.join(timeout=120)
-        for f in inputs:
-            f.close()
+        for fd in in_fds:
+            os.close(fd)
         for fd in out_fds.values():
             os.close(fd)
+        if not done or werrs:
+            for sid in out_fds:     # they were missing: leave them so
+                os.unlink(base + to_ext(sid))
     if werrs:
         raise werrs[0]
     if stage_stats is not None:
         dev0 = mesh.devices.flat[0]
-        n_batches = -(-len(offsets) // b)
         stage_stats.update({
             "backend": "device-apply-xla",
             "devices": mesh.devices.size,
@@ -1726,6 +1841,7 @@ def rebuild_shards(base: str, mesh=None,
             "device_kind": dev0.device_kind,
             "wall": round(time.perf_counter() - wall0, 6),
             **{k: round(v, 6) for k, v in timers.items()},
+            "read_workers": reads.read_workers,
             "batches": n_batches,
             "batch_units": b,
             "missing": list(missing),

@@ -19,6 +19,7 @@ if "xla_force_host_platform_device_count" not in xla_flags:
 # spawned by tests inherit.
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
+import pytest
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)
@@ -37,6 +38,19 @@ def reference_fixture(relpath):
     return p if os.path.exists(p) else None
 
 
+@pytest.fixture
+def eager_switching():
+    """Threads switch every 10 us: a step taken twice or lost between
+    the workers of a read stage, were the hand-out not atomic, shows as a
+    wrong shard."""
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        yield
+    finally:
+        sys.setswitchinterval(interval)
+
+
 # Custom markers are registered in pytest.ini (the shared config) —
 # tests/test_markers_registered.py fails tier-1 if a test file uses a
 # marker that is not listed there.
@@ -48,8 +62,6 @@ def pytest_collection_modifyitems(config, items):
     smaller anyway — an outer XLA_FLAGS pinning the count, or a jax
     build that ignores the flag — skip rather than shard a 1-device
     mesh and silently not exercise the sharded path."""
-    import pytest
-
     if jax.device_count() < 4:
         skip = pytest.mark.skip(
             reason=f"multidevice needs >=4 devices, backend has "

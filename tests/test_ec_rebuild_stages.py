@@ -27,11 +27,19 @@ VID = 7
 # the benchmark's pattern (two data, two parity: a true inverse), one
 # all-data, one all-parity (the encode matrix again) and a single loss
 LOSSES = [(0, 3, 11, 13), (1, 4, 6, 8), (10, 11, 12, 13), (5,)]
-STAGES = ("read", "dispatch", "h2d", "d2h_wait", "crc", "write_wait",
-          "write")
-ON_THE_PIPELINE_THREAD = ("read", "dispatch", "d2h_wait", "crc",
+# a key of `stage_stats` -> the `ec.rebuild.*` stage that measures it;
+# `read_worker_busy` is thread-seconds inside the read stage's workers,
+# taken with bare clock readings: a key and no stage
+SPAN_OF = {"read": "read", "read_slot_wait": "stage_wait",
+           "read_wait": "read_wait", "dispatch": "dispatch", "h2d": "h2d",
+           "d2h_wait": "d2h_wait", "crc": "crc", "write_wait": "write_wait",
+           "write": "write"}
+STAGES = tuple(SPAN_OF) + ("read_worker_busy",)
+# disjoint on the pipeline thread; the read stage's three and the
+# writer's `write` overlap them
+ON_THE_PIPELINE_THREAD = ("read_wait", "dispatch", "d2h_wait", "crc",
                           "write_wait")
-REBUILD_SPANS = {"ec.rebuild." + k for k in STAGES}
+REBUILD_SPANS = {"ec.rebuild." + name for name in SPAN_OF.values()}
 
 
 def _ids(loss):
@@ -116,9 +124,14 @@ def test_reply_carries_every_stage_second(loss, rebuilds):
     st = rebuilds[loss]
     for key in STAGES + ("wall",):
         assert isinstance(st[key], float) and st[key] >= 0.0, key
-    # disjoint on the pipeline thread; `write`, on the writer's, overlaps
     assert sum(st[k] for k in ON_THE_PIPELINE_THREAD) <= st["wall"]
     assert st["h2d"] <= st["dispatch"]
+    # the read stage's coordinator: its fan-outs and its waits for a
+    # slot are disjoint too, and its workers are busy inside a fan-out
+    assert st["read"] + st["read_slot_wait"] <= st["wall"]
+    assert st["read_workers"] == be._read_workers()
+    assert 0.0 < st["read_worker_busy"] \
+        <= st["read_workers"] * st["read"] + 1e-3
     assert st["missing"] == list(loss)
 
 
@@ -148,6 +161,7 @@ def test_small_batches_take_the_same_path_batch_after_batch(sealed):
     be.rebuild_shards(sealed["base"], batch_units=1, stage_stats=stats)
     assert stats["batches"] == 3 and stats["batch_units"] == 1
     assert sum(stats[k] for k in ON_THE_PIPELINE_THREAD) <= stats["wall"]
+    assert stats["read"] + stats["read_slot_wait"] <= stats["wall"]
     for sid in loss:
         with open(sealed["base"] + to_ext(sid), "rb") as f:
             assert f.read() == sealed["files"][sid]
@@ -172,18 +186,20 @@ def test_stages_are_timed_once_a_batch_never_a_row(sealed, monkeypatch):
     be.rebuild_shards(sealed["base"], batch_units=1, stage_stats=stats)
     n = stats["batches"]
     assert set(names) == REBUILD_SPANS
-    # six a batch on the pipeline thread, one on the writer's, and the
-    # join at the end
-    assert len(names) == 7 * n + 1
+    # six a batch on the pipeline thread, two on the read stage's
+    # coordinator, one on the writer's, and the join at the end
+    assert len(names) == 9 * n + 1
     assert names.count("ec.rebuild.write_wait") == n + 1
+    for name in REBUILD_SPANS - {"ec.rebuild.write_wait"}:
+        assert names.count(name) == n, name
 
 
-def test_a_one_gib_rebuild_makes_fewer_than_130_stage_calls():
+def test_a_one_gib_rebuild_makes_fewer_than_160_stage_calls():
     shard_size = 97 << 20           # the 1,006,723,848 B volume's shards
     chunk = min(be.MAX_CHUNK_BYTES, shard_size)
     units = be.TARGET_BATCH_BYTES // (10 * chunk)
     batches = -(-(shard_size // chunk) // units)
-    assert batches == 17 and 7 * batches + 1 < 130 <= 10 * 97
+    assert batches == 17 and 9 * batches + 1 < 160 <= 10 * 97
 
 
 def test_spans_hang_under_a_sampled_request(sealed, monkeypatch):
@@ -199,11 +215,12 @@ def test_spans_hang_under_a_sampled_request(sealed, monkeypatch):
         root.finish()
     agg = tracing.RECORDER.aggregate("ec.rebuild.")
     assert set(agg) == REBUILD_SPANS
-    for key in STAGES:
+    for key, name in SPAN_OF.items():
         # one measurement is the counter and the span
-        assert agg["ec.rebuild." + key]["seconds"] == pytest.approx(
+        assert agg["ec.rebuild." + name]["seconds"] == pytest.approx(
             stats[key], abs=2e-6)
-    assert agg["ec.rebuild.read"]["count"] == stats["batches"]
+    for name in ("read", "stage_wait", "read_wait"):
+        assert agg["ec.rebuild." + name]["count"] == stats["batches"]
 
 
 def test_no_span_is_built_without_a_sampled_request(sealed, monkeypatch):

@@ -7,7 +7,6 @@ never timings: the CPU backend says nothing about the chip host's files.
 
 import functools
 import os
-import sys
 import threading
 
 import numpy as np
@@ -70,18 +69,6 @@ def _shards(base: str) -> list[bytes]:
 def _pipeline_threads() -> list[str]:
     return [t.name for t in threading.enumerate()
             if t.name.startswith("ec-encode")]
-
-
-@pytest.fixture
-def eager_switching():
-    """Threads switch every 10 us: a step taken twice or lost between
-    workers, were the hand-out not atomic, shows as a wrong shard."""
-    interval = sys.getswitchinterval()
-    sys.setswitchinterval(1e-5)
-    try:
-        yield
-    finally:
-        sys.setswitchinterval(interval)
 
 
 @pytest.mark.parametrize("layout", sorted(LAYOUTS))
