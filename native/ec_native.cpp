@@ -14,9 +14,10 @@
 //         cache-resident chunks, ~7 GiB/s streaming.
 //       * GFNI + AVX2 (VEX 256-bit) for GFNI cores without AVX-512.
 //       * AVX2 PSHUFB on 16-entry nibble product tables — the
-//         klauspost-classic kernel, kept callable via
-//         sw_gf_apply_matrix_force as bench.py's apples-to-apples
-//         reference-class baseline.
+//         klauspost-classic kernel; the one that runs on a core
+//         without GFNI, and callable on any core through
+//         sw_gf_apply_matrix_force, which the tests use to hold every
+//         level to the NumPy reference.
 //       * scalar table lookups.
 //  3. sw_encode_rows — fused span encode: parity plus CRC32C of every
 //     data+parity shard in ONE call, affine+CRC interleaved in 128 KiB
@@ -392,8 +393,8 @@ void sw_gf_apply_matrix(const uint8_t* matrix, int p, int d,
     gf_apply_matrix_level(matrix, p, d, data, len, out, gf_best_level());
 }
 
-// Pin a specific kernel level (bench baselines); level -1 = auto.  Levels
-// above the machine's capability clamp down to the best available.
+// Pin a specific kernel level (the tests' ladder check); level -1 = auto.
+// Levels above the machine's capability clamp down to the best available.
 void sw_gf_apply_matrix_force(const uint8_t* matrix, int p, int d,
                               const uint8_t* data, size_t len, uint8_t* out,
                               int level) {

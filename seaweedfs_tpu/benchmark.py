@@ -196,8 +196,7 @@ def _run_full_native(master_address: str, num_files: int, file_size: int,
 
 def _run_native(master_address: str, num_files: int, file_size: int,
                 concurrency: int, delete_percent: int, replication: str,
-                do_read: bool, quiet: bool, assign_batch: int,
-                http_phase: bool = False, pre_phase_hook=None):
+                do_read: bool, quiet: bool, assign_batch: int):
     """Native-engine benchmark: the load generator is the C++ driver in
     native/vol_native.cpp (like the reference's compiled Go benchmark
     client), hitting the volume server's native fast-path port.  File ids
@@ -208,11 +207,7 @@ def _run_native(master_address: str, num_files: int, file_size: int,
     JWT-secured clusters: assign replies carry fid-scoped tokens that
     ride with each fid; the cluster's jwt.signing expires_after_seconds
     must outlive the whole write phase (the harness uses 3600 s), since
-    every token is minted during the up-front assign loop.
-
-    pre_phase_hook(by_server): called after assigns, before the write
-    phase — e.g. to wait for replica-set propagation on replicated
-    volumes so the native plane serves the writes rather than 307ing."""
+    every token is minted during the up-front assign loop."""
     from .storage import native_engine
     from .wdclient.volume_tcp_client import VolumeTcpClient
 
@@ -263,21 +258,11 @@ def _run_native(master_address: str, num_files: int, file_size: int,
             result.seconds = max(result.seconds, secs)
             result.latencies_ms.extend(lat.tolist())
 
-    if pre_phase_hook is not None:
-        pre_phase_hook(by_server)
     run_phase("W", write, file_size)
 
     read = BenchResult()
     if do_read:
         run_phase("R", read, 0)
-    read.http_rps = 0.0
-    if http_phase:
-        # the native port also answers plain HTTP GETs: measure the
-        # reference benchmark's own modality (README.md:372-381)
-        http = BenchResult()
-        run_phase("H", http, 0)
-        read.http_rps = (http.requests / http.seconds
-                         if http.seconds else 0.0)
 
     if delete_percent > 0:
         for url, fids in by_server.items():

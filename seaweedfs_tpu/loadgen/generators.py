@@ -201,8 +201,8 @@ def build_schedule(seed: Optional[int] = None,
                    write_ratio: float = 0.05) -> list[Request]:
     """Full schedule: Poisson arrivals x zipf popularity x size
     mixture x diurnal tenant mix.  All knobs default from the
-    WEED_LOAD_* environment so `bench.py` phases and operators share
-    one configuration surface."""
+    WEED_LOAD_* environment, the one configuration surface of every
+    caller."""
     if seed is None:
         seed = load_seed()
     if duration_s is None:

@@ -89,8 +89,8 @@ class ScrubTarget:
 
 def local_target(base: str, volume: int = 0,
                  collection: str = "") -> ScrubTarget:
-    """Build a ScrubTarget over local .ecNN files (bench/offline path
-    and the worker's local-shard reads)."""
+    """Build a ScrubTarget over local .ecNN files (the offline path:
+    a scrub of shard files with no cluster around them)."""
     from ..storage.erasure_coding.encoder import load_volume_info
 
     info = load_volume_info(base) or {}

@@ -299,8 +299,8 @@ class MaintenanceWorker:
     # -- elasticity executors ------------------------------------------------
     def _exec_scale_up(self, job: dict) -> dict:
         """Grow the cluster by one volume server.  In-process when the
-        host installed a spawn seam (tests / bench on the 1-core
-        harness); otherwise fork a `weed.py volume` subprocess and wait
+        host installed a spawn seam (tests on the 1-core harness);
+        otherwise fork a `weed.py volume` subprocess and wait
         until the master's topology shows the newcomer."""
         spawn = getattr(self.server, "spawn_volume_server", None)
         if callable(spawn):

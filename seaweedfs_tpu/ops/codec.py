@@ -29,17 +29,11 @@ from .rs_numpy import (NumpyEncoder, ReconstructError,  # noqa: F401
 
 class NativeEncoder(RSCodecBase):
     """CPU codec backed by the C++ kernel ladder in native/ec_native.cpp
-    (GFNI+AVX-512 > GFNI+AVX2 > AVX2-PSHUFB > scalar, runtime-dispatched).
+    (GFNI+AVX-512 > GFNI+AVX2 > AVX2-PSHUFB > scalar, runtime-dispatched)."""
 
-    `level` pins a specific kernel (bench baselines): 1 = the AVX2 PSHUFB
-    nibble-table kernel, the same algorithm class as the klauspost codec
-    the reference vendors; -1 (default) = best available."""
-
-    def __init__(self, data_shards: int = 10, parity_shards: int = 4,
-                 level: int = -1):
+    def __init__(self, data_shards: int = 10, parity_shards: int = 4):
         super().__init__(data_shards, parity_shards)
         self._lib = native.lib()
-        self._level = level
         if self._lib is None:
             raise RuntimeError("native library unavailable")
 
@@ -49,11 +43,10 @@ class NativeEncoder(RSCodecBase):
         matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
         inputs = np.ascontiguousarray(inputs, dtype=np.uint8)
         out = np.zeros((p, length), dtype=np.uint8)
-        self._lib.sw_gf_apply_matrix_force(
+        self._lib.sw_gf_apply_matrix(
             matrix.ctypes.data_as(ctypes.c_char_p), p, d,
             inputs.ctypes.data_as(ctypes.c_char_p), length,
-            out.ctypes.data_as(ctypes.c_char_p), self._level,
-        )
+            out.ctypes.data_as(ctypes.c_char_p))
         return out
 
     def encode_rows(self, parity_matrix: np.ndarray, data: np.ndarray,

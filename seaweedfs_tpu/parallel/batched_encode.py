@@ -209,9 +209,8 @@ def _preadv_full(fd: int, view: memoryview, offset: int):
 def _pwritev_full(fd: int, bufs, offset: int) -> int:
     """pwritev that writes every byte or raises OSError.  A short kernel
     write must fail the encode, not silently truncate a shard whose CRC
-    was already computed from memory (ADVICE.md batched_encode.py:551):
-    partial progress is retried from where the kernel stopped; zero
-    progress is a hard error."""
+    was already computed from memory: partial progress is retried from
+    where the kernel stopped; zero progress is a hard error."""
     iovs = [memoryview(b) for b in bufs]
     total = sum(v.nbytes for v in iovs)
     written = 0
@@ -1211,7 +1210,7 @@ def _encode_units_host(plans, units, chunk, host_codec,
         from ..util.platform import available_cpu_count
 
         # affinity-aware: an affinity-restricted box must not over-spawn
-        # workers onto cores it cannot use (ADVICE.md bench.py:969)
+        # workers onto cores it cannot use
         nworkers = max(1, min(16, available_cpu_count()))
 
     write_behind, nwriters, flush_bytes, drop_cache = _write_knobs()

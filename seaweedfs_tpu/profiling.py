@@ -223,19 +223,6 @@ class StackSampler:
             items = items[:limit]
         return "".join("%s %d\n" % kv for kv in items)
 
-    def top_frames(self, n: int = 12) -> list[dict]:
-        """Self-time ranking by leaf frame (the bench JSON breakdown)."""
-        agg: dict[str, int] = {}
-        with self._lock:
-            total = self.total or 1
-            for stack, count in self.samples.items():
-                leaf = stack.rsplit(";", 1)[-1]
-                agg[leaf] = agg.get(leaf, 0) + count
-        ranked = sorted(agg.items(), key=lambda kv: -kv[1])[:n]
-        return [{"frame": frame, "samples": count,
-                 "pct": round(100.0 * count / total, 1)}
-                for frame, count in ranked]
-
     def snapshot(self) -> dict:
         with self._lock:
             return {"samples": self.total, "stacks": len(self.samples),

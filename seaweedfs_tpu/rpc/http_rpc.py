@@ -273,7 +273,7 @@ class RpcServer:
         # prefork (WEED_HTTP_WORKERS): only explicitly-bound ports shard
         # into worker processes — port-0 servers are ephemeral (test
         # fixtures, embedded sidecars) and must never fork the host
-        # process (pytest/bench carry JAX + thread pools)
+        # process (pytest carries JAX + thread pools)
         self._prefork = None
         self._prefork_workers = (
             _prefork.worker_count()

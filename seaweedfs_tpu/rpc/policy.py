@@ -397,20 +397,6 @@ class HedgeTracker:
 HEDGE = HedgeTracker()
 
 
-def reset_state():
-    """Drop all process-global policy state: circuit breakers, the
-    retry-budget bucket, and the hedge latency rings.  For bench/test
-    phase isolation — breakers and budgets are keyed by address, and a
-    later phase reusing an ephemeral port (or sharing the process) must
-    not inherit an earlier phase's failures."""
-    BREAKERS.reset()
-    with BUDGET._lock:
-        BUDGET._tokens = BUDGET.cap
-    with HEDGE._lock:
-        HEDGE._rings.clear()
-        HEDGE._pos.clear()
-
-
 def hedged(key: str, attempts: Sequence[Callable[[], object]]):
     """Run attempts[0]; if it hasn't answered after the adaptive p95
     delay (or fails), fire the next attempt.  First success wins, losers

@@ -33,8 +33,8 @@ hot read path stays local.
 
 Prefork only engages for explicitly-bound ports.  Ephemeral port-0
 servers (test fixtures, the embedded s3 filer, metrics sidecars) stay
-single-process — which also guarantees the pytest/bench process, which
-has JAX and a thread pool loaded, is never forked.
+single-process — which also guarantees the pytest process, which has
+JAX and a thread pool loaded, is never forked.
 """
 
 from __future__ import annotations

@@ -270,8 +270,8 @@ class AccessRecorder:
             return len(self.hot)
 
     def memory_bytes(self) -> int:
-        """Rough in-memory footprint of the sketch state (bench +
-        metrics; the point is the bound, not byte accuracy)."""
+        """Rough in-memory footprint of the sketch state (the
+        self-metrics gauges; the point is the bound, not byte accuracy)."""
         with self.lock:
             n = (len(self.hot) + len(self.vol_hot)) * 96 + self.distinct.m
             n += sum(len(lq.buckets) * 48 + 64

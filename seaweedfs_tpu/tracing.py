@@ -27,7 +27,7 @@ slow reply to the hop or kernel stage that caused it.  Here:
     session is on — a host event on the profiler's clock, next to the
     device planes.
 
-The daemons share one process in tests/bench (like stats.REGISTRY), so
+The daemons share one process in tests (like stats.REGISTRY), so
 the recorder is process-global and spans carry a ``service`` label —
 "spans two daemons" means two distinct services in one trace.
 

@@ -23,8 +23,8 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def ensure_compile_cache() -> str:
     """Place JAX's persistent compilation cache; returns its directory.
-    Call before the first jit of a process (weed.py, bench.py, the graft
-    entry points).
+    Call before the first jit of a process (weed.py, the graft entry
+    points).
 
     Where JAX_COMPILATION_CACHE_DIR is set, JAX reads it itself and
     nothing is set in code.  Otherwise the cache lives at the fixed path

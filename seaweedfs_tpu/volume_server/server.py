@@ -296,9 +296,9 @@ class VolumeServer:
         self._tele_prev = (time.monotonic(), 0, 0, 0)
         self._occ_peak = 0.0
         self.scale_children: list = []
-        # in-process spawn seam: tests and bench phases install a
-        # callable(job) -> url here so scale.up never forks on the
-        # 1-core CI harness; None means subprocess `weed.py volume`
+        # in-process spawn seam: tests install a callable(job) -> url
+        # here so scale.up never forks on the 1-core CI harness; None
+        # means subprocess `weed.py volume`
         self.spawn_volume_server = None
         # per-volume-id copy locks: concurrent copies of the SAME vid must
         # not race each other's temp files / exists-checks, but a slow copy

@@ -38,6 +38,17 @@ def reference_fixture(relpath):
     return p if os.path.exists(p) else None
 
 
+def pytest_configure(config):
+    """One build of native/*.so before xdist starts its workers.  On a
+    fresh checkout each of six workers ran `make` into the same two
+    files, and a worker whose own make had ended could load a library
+    that another's compiler was still writing."""
+    if not hasattr(config, "workerinput"):
+        from seaweedfs_tpu.ops import native
+
+        native.build()
+
+
 @pytest.fixture
 def eager_switching():
     """Threads switch every 10 us: a step taken twice or lost between

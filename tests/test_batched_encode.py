@@ -432,7 +432,7 @@ class TestWriteBehindStage:
     def test_stage_stats_schema(self, tmp_path, monkeypatch):
         """With the writer stage enabled and >=2 workers, stage stats
         attribute read / encode_crc / write / flush separately, plus the
-        pipeline-shape fields bench.py reports."""
+        pipeline-shape fields."""
         _, _, st = self._encode(
             tmp_path, monkeypatch, "ss",
             WEED_EC_HOST_WORKERS="2", WEED_EC_WRITE_BEHIND="1",

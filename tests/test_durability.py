@@ -131,12 +131,19 @@ class TestServerThrottle:
             # saturate the gate from another "request"
             vs.upload_gate.timeout = 1.0
             vs.upload_gate.acquire(900 << 10)
-            t0 = time.monotonic()
+            cond = vs.upload_gate._cond
+            parked, wait = [], cond.wait
+
+            def counted_wait(left):
+                parked.append(left)
+                return wait(left)
+
+            cond.wait = counted_wait
             with pytest.raises(RpcError) as e:
                 call(a["url"], f"/{a['fid']}", raw=b"y" * (300 << 10),
                      method="POST", timeout=60)
             assert e.value.status == 429
-            assert time.monotonic() - t0 >= 0.9  # waited before giving up
+            assert parked, "refused without waiting on the gate"
             vs.upload_gate.release(900 << 10)
             # and succeeds once the gate frees up
             w = call(a["url"], f"/{a['fid']}", raw=b"y" * (300 << 10),

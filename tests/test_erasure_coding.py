@@ -174,34 +174,39 @@ class TestEncode:
 
     def test_degraded_read_fans_out_survivor_fetches(self, encoded):
         """Remote survivor fetches must run in PARALLEL (the reference
-        fans out per-shard goroutines, store_ec.go:328-382): with every
-        survivor 150 ms away, a recovery needing 10 of them must finish
-        in ~one round-trip, not ten serial ones."""
-        import time as _t
+        fans out per-shard goroutines, store_ec.go:328-382): a fetch
+        that does not answer until a second one is out beside it would
+        hang a serial loop, and here the recovery completes."""
+        import threading
 
         base, d = encoded
         shard_bytes = {i: open(base + to_ext(i), "rb").read()
                        for i in range(TOTAL_SHARDS_COUNT)}
         ev = EcVolume(d, "", 1, large_block_size=LARGE,
                       small_block_size=SMALL)
-        # NO local shards: every survivor is a (slow) remote fetch
+        # NO local shards: every survivor is a remote fetch
         calls = []
+        beside = threading.Condition()
+        out = [0, 0]  # fetches out now, and the most at once
 
-        def slow_remote(sid, offset, size):
+        def remote(sid, offset, size):
             calls.append(sid)
             if sid == 0:  # the target shard is lost cluster-wide
                 return None
-            _t.sleep(0.15)
+            with beside:
+                out[0] += 1
+                out[1] = max(out)
+                beside.notify_all()
+                # a deadline so that serial fetches fail, not hang
+                beside.wait_for(lambda: out[1] >= 2, timeout=30)
+                out[0] -= 1
             return shard_bytes[sid][offset:offset + size]
 
-        ev.remote_reader = slow_remote
-        t0 = _t.monotonic()
+        ev.remote_reader = remote
         span = ev.read_shard_span(0, 0, 64)
-        elapsed = _t.monotonic() - t0
         assert span == shard_bytes[0][:64]
         assert len(calls) >= DATA_SHARDS_COUNT
-        # 10 serial fetches would take >= 1.5 s; parallel ~0.15-0.3 s
-        assert elapsed < 1.0, f"survivor fetches look serial: {elapsed:.2f}s"
+        assert out[1] >= 2, "survivor fetches look serial"
         ev.close()
 
     def test_degraded_read_survives_failing_survivors(self, encoded):

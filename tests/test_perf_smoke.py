@@ -120,12 +120,12 @@ def test_streamed_get_first_byte_before_last_chunk(stack, monkeypatch):
         sock.close()
 
 
-def test_profiler_overhead_under_five_percent():
-    """The always-on profiler must stay invisible: against a synthetic
-    multi-thread spin workload (the worst case for stack walking — all
-    threads busy with real frames), the sampler's self-measured duty
-    cycle at the default WEED_PROF_HZ stays under 5% (measured ~0.1%;
-    the bar is loose for loaded CI boxes)."""
+def test_profiler_samples_busy_threads_and_joins():
+    """The always-on sampler at its default rate (WEED_PROF_HZ) against
+    eight spinning threads — the worst case for stack walking, every
+    thread busy with real frames: it ticks, every spinner shows up in
+    the folded stacks, no tick errors, and `stop()` joins its thread.
+    What the duty cycle costs on a chip host is not measured."""
     from seaweedfs_tpu import profiling
 
     stop = threading.Event()
@@ -134,22 +134,29 @@ def test_profiler_overhead_under_five_percent():
         while not stop.is_set():
             sum(i * i for i in range(2000))
 
-    workers = [threading.Thread(target=spin, name=f"spin-{i}")
-               for i in range(8)]
+    names = {f"spin-{i}" for i in range(8)}
+    workers = [threading.Thread(target=spin, name=n) for n in names]
     for w in workers:
         w.start()
     sampler = profiling.StackSampler()  # default rate: WEED_PROF_HZ
     sampler.start()
+
+    def seen():
+        return {k.split(";", 1)[0] for k in list(sampler.samples)}
+
     try:
-        time.sleep(1.2)
+        deadline = time.monotonic() + 30  # a hang fails, not a slow box
+        while not names <= seen() and time.monotonic() < deadline:
+            time.sleep(0.02)
     finally:
         stop.set()
         for w in workers:
             w.join()
-    assert sampler.stop(), "sampler thread failed to join"
-    assert sampler.total > 0, "sampler never ticked"
-    ratio = sampler.overhead_ratio()
-    assert ratio < 0.05, f"profiler duty cycle {ratio:.4f} >= 5%"
+    assert sampler.stop(timeout=30), "sampler thread failed to join"
+    assert names <= seen(), seen()
+    assert sampler.total >= len(names)
+    assert sampler.errors == 0
+    assert 0.0 <= sampler.overhead_ratio() <= 1.0  # a share, measured
 
 
 def test_maintenance_scrub_paced_under_foreground_load(tmp_path):
@@ -158,8 +165,6 @@ def test_maintenance_scrub_paced_under_foreground_load(tmp_path):
     Fake clock: asserts the sleep arithmetic — every scrubbed byte is
     debited and the injected delay is exactly bytes/effective_rate
     minus the one-burst credit — not wall-clock numbers."""
-    import os
-
     import numpy as np
 
     from seaweedfs_tpu.maintenance.deep_scrub import (deep_scrub,
@@ -204,19 +209,40 @@ def test_maintenance_scrub_paced_under_foreground_load(tmp_path):
     assert pacer.throttled_seconds == pytest.approx(sum(slept))
 
 
-def test_device_scale_dispatch_smoke(tmp_path):
-    """Mini bench_e2e_device_scale (4 volumes, CPU-device mesh): asserts
-    the SHAPE of the pooled device pipeline — the pooled backend was
-    selected, the compiled-shape set stays bounded (one fixed batch
-    geometry, not one compile per volume), and repeat dispatches re-lease
-    slabs instead of allocating — not a GiB/s number."""
-    import bench
-    from seaweedfs_tpu.ops.device_pool import get_pool, reset_pool
+def test_device_scale_dispatch_smoke(tmp_path, monkeypatch):
+    """Two `encode_volumes` of four 256 KiB volumes on a CPU-device
+    mesh: the SHAPE of the pooled device pipeline — the pooled backend
+    was selected, the compiled-shape set stays bounded (one fixed batch
+    geometry, not one compile per volume), and the second run re-leases
+    the first run's slabs instead of allocating."""
+    import jax
+    import numpy as np
 
+    from seaweedfs_tpu.ops.device_pool import get_pool, reset_pool
+    from seaweedfs_tpu.parallel.batched_encode import encode_volumes
+    from seaweedfs_tpu.parallel.mesh import make_ec_mesh
+
+    def volumes(prefix, seed):
+        bases = []
+        for i in range(4):
+            base = str(tmp_path / f"{prefix}{i}")
+            rng = np.random.default_rng(seed + i)
+            with open(base + ".dat", "wb") as f:
+                f.write(rng.integers(0, 256, 256 << 10,
+                                     dtype=np.uint8).tobytes())
+            bases.append(base)
+        return bases
+
+    # a retention cap that holds one run's slabs: on the 8-device mesh
+    # the staging and output rings (4 x 80 MiB + 4 x 32 MiB) outgrow the
+    # default 256 MiB, and an evicted slab is an honest new allocation
+    monkeypatch.setenv("WEED_EC_DEVICE_POOL_MB", "1024")
     reset_pool()
-    rate, st = bench.bench_e2e_device_scale(
-        4, 256 << 10, str(tmp_path), link_capped=True)
-    assert rate > 0
+    mesh = make_ec_mesh(jax.devices("cpu"))
+    encode_volumes(volumes("dwarm", 500), mesh=mesh)
+    allocs_after_first = get_pool().snapshot()["allocs"]
+    st: dict = {}
+    encode_volumes(volumes("dvol", 0), mesh=mesh, stage_stats=st)
     assert st["backend"].startswith("device-pooled")
     assert st["batches"] >= 1
     # one fixed compiled geometry: k-compaction may retrace per distinct
@@ -224,105 +250,99 @@ def test_device_scale_dispatch_smoke(tmp_path):
     assert len(st["k_shapes"]) == 1
     assert st["inflight"] >= 1
     snap = get_pool().snapshot()
-    # the warm encode populated the pool; the timed run re-leased
+    # the first encode populated the pool; the second re-leased
     assert snap["lease_hits"] > 0, snap
-    assert st["pool"]["allocs"] == snap["allocs"], \
-        "timed window allocated fresh slabs"
+    assert snap["evictions"] == 0, snap
+    assert st["pool"]["allocs"] == snap["allocs"] == allocs_after_first, \
+        "second run allocated fresh slabs"
     reset_pool()
 
 
-def test_device_scale_two_devices_beat_one(tmp_path):
-    """Mini sharded device-scale phase (bench_device_scale_curve at
-    1 and 2 virtual devices): the shard_map dispatch at width 2 must
-    sustain >= 1.5x the width-1 rate.  Real scaling needs real
-    parallelism — on a box with fewer than 2 usable cores the two
-    virtual devices time-slice one core and the ratio measures the
-    scheduler, so skip there."""
-    import bench
-
-    try:
-        cores = len(os.sched_getaffinity(0))
-    except AttributeError:
-        cores = os.cpu_count() or 1
-    if cores < 2:
-        pytest.skip(f"sharded scaling needs >=2 cores, have {cores}")
-    curve = bench.bench_device_scale_curve(
-        str(tmp_path), vol_bytes=1 << 20, n_vols=8, counts=(1, 2))
-    assert curve.get("1") and curve.get("2"), curve
-    assert curve["2"] >= 1.5 * curve["1"], (
-        f"2-device throughput {curve['2']} GiB/s < 1.5x the 1-device "
-        f"{curve['1']} GiB/s")
-
-
 def test_cluster_scale_curve_smoke(tmp_path):
-    """Mini bench_cluster_scale (2 points, 1 and 2 volume servers):
-    asserts the SHAPE of the elasticity curve — the seeded replay ran
-    to completion at every point with zero failed reads and real
-    latency percentiles — not an absolute speedup.  The 4x/16x
-    multiplier gate only means anything with real parallelism, so skip
-    below 2 cores (matching the bench's own `gated` flag)."""
-    import bench
+    """The same seeded zipfian replay (loadgen), closed-loop against a
+    mini-cluster of 1 and of 2 volume servers: the replay runs to its
+    end at both points with zero failed reads and real latency
+    percentiles."""
+    from seaweedfs_tpu import loadgen
+    from seaweedfs_tpu.master.server import MasterServer
+    from seaweedfs_tpu.rpc import policy
+    from seaweedfs_tpu.rpc.http_rpc import RpcError, call
+    from seaweedfs_tpu.volume_server.server import VolumeServer
 
-    try:
-        cores = len(os.sched_getaffinity(0))
-    except AttributeError:
-        cores = os.cpu_count() or 1
-    if cores < 2:
-        pytest.skip(f"scale curve needs >=2 cores, have {cores}")
-    out = bench.bench_cluster_scale(counts=(1, 2), num_objects=60,
-                                    rate_rps=150.0, duration_s=1.5)
-    assert set(out["counts"]) == {"1", "2"}
-    for point in out["counts"].values():
-        assert point["failures"] == 0
-        assert point["rps"] > 0
-        assert point["p99_ms"] >= point["p50_ms"] > 0
-    assert out["gated"] is True
-    assert out["requests"] > 100  # the Poisson schedule actually ran
-    assert out["speedup_2x"] > 0
+    num_objects = 60
+    schedule = loadgen.build_schedule(
+        duration_s=1.5, rate_rps=150.0, n_objects=num_objects,
+        write_ratio=0.0)
+    assert len(schedule) > 100  # the Poisson schedule actually ran
+    payload = b"s" * 2048
+    for n_servers in (1, 2):
+        policy.BREAKERS.reset()  # keyed by address; ports come back
+        master = MasterServer(port=0, pulse_seconds=1.0,
+                              volume_size_limit_mb=1024,
+                              maintenance_interval=3600.0)
+        master.start()
+        servers = []
+        try:
+            for i in range(n_servers):
+                d = tmp_path / f"n{n_servers}vs{i}"
+                d.mkdir()
+                vs = VolumeServer([str(d)], master.address, port=0,
+                                  pulse_seconds=1.0,
+                                  max_volume_counts=[16])
+                vs.start()
+                vs.heartbeat_once()
+                servers.append(vs)
+            urls = []
+            for _ in range(num_objects):
+                a = call(master.address, "/dir/assign", timeout=30)
+                call(a["url"], f"/{a['fid']}", raw=payload,
+                     method="POST", timeout=30)
+                urls.append((a["url"], a["fid"]))
+
+            def send(req):
+                url, fid = urls[req.obj % num_objects]
+                try:
+                    body = call(url, f"/{fid}", timeout=30)
+                except RpcError as e:
+                    if e.status != 503:
+                        raise
+                    time.sleep(0.05)
+                    body = call(url, f"/{fid}", timeout=30)
+                return body == payload
+
+            out = loadgen.replay(schedule, send, workers=8,
+                                 open_loop=False)
+        finally:
+            for vs in servers:
+                vs.stop()
+            master.stop()
+        assert out["requests"] == len(schedule), (n_servers, out)
+        assert out["failures"] == 0, (n_servers, out)
+        assert out["p99_ms"] >= out["p50_ms"] > 0, (n_servers, out)
 
 
-def test_read_cache_warm_storm_beats_cold():
-    """Mini bench_read_cache (300 objects, 4 workers): the warm
-    smallfile storm on the filer object-GET path — where a chunk-cache
-    hit skips the internal filer->volume hop — must sustain >= 1.5x
-    the cold rate (full-size bench measures ~4x; the bar is loose for
-    loaded CI boxes, with two retries for scheduler noise), and the
-    cache's own accounting must show the RAM tier taking the hits."""
-    import bench
+def test_read_cache_warm_storm_takes_ram_hits(stack):
+    """Two GET passes over the same chunked objects on the filer
+    object-GET path, the first with every cache tier cleared: the
+    second pass is served by the chunk cache's RAM tier, and the
+    cache's own accounting shows it."""
+    from seaweedfs_tpu.rpc.http_rpc import call
 
-    out = {}
-    for attempt in range(3):
-        out = bench.bench_read_cache(num_objects=300, payload_bytes=4096,
-                                     workers=4)
-        if out["warm_vs_cold"] >= 1.5:
-            break
-    assert out["warm_vs_cold"] >= 1.5, out
-    fc = out["filer_cache"]
+    master, vs, filer = stack
+    payload = b"r" * 4096  # past the inline limit: 4 chunks of 1024
+    paths = [f"/rcache/f{i}" for i in range(40)]
+    for p in paths:
+        call(filer.address, p, raw=payload, method="POST")
+    filer.chunk_cache.clear()
+    vs.read_cache.clear()
+    for _ in range(2):
+        for p in paths:
+            assert call(filer.address, p) == payload
+    fc = filer.chunk_cache.stats_snapshot()
     assert fc["tier_hits"]["ram"] > 0
     assert 0.0 < fc["hit_ratio"] <= 1.0
     assert set(fc["tier_hits"]) == {"hbm", "ram", "disk"}
     assert set(fc["fills"]) == {"admitted", "qos_bypass"}
-
-
-@pytest.mark.multiproc
-def test_gateway_worker_curve_smoke():
-    """Mini bench_gateway_workers (1 and 2 workers, reduced storm):
-    sharding the volume gateway across 2 processes must buy >= 1.5x
-    the single-process smallfile read rate.  Only meaningful with real
-    parallelism — the multiproc marker auto-skips below 2 cores, the
-    same gate the bench's own `gated` flag reports (retried once for
-    scheduler noise on loaded CI boxes)."""
-    import bench
-
-    out = {}
-    for attempt in range(2):
-        out = bench.bench_gateway_workers(counts=(1, 2), num_files=120,
-                                          read_reqs=600)
-        if out.get("speedup_2x", 0) >= 1.5:
-            break
-    assert out["gated"] is True
-    assert out["counts"].get("1") and out["counts"].get("2"), out
-    assert out["speedup_2x"] >= 1.5, out
 
 
 def test_lint_dashboards_and_slo_rules():
@@ -334,24 +354,25 @@ def test_lint_dashboards_and_slo_rules():
     assert lint.run() == []
 
 
-def test_health_scrape_overhead_under_one_percent(stack):
-    """The leader's health plane must cost <= 1% of one core at the
-    default 5 s cadence.  Measured structurally: run scrape rounds
-    back-to-back against a live master+volume+filer stack.  The budget
-    is CPU, so measure thread CPU time — wall clock counts the server
-    threads answering /metrics and whatever else the box is running,
-    which is scheduler noise, not plane overhead."""
-    from seaweedfs_tpu.master import health as health_mod
-
+def test_health_scrape_round_touches_every_target(stack):
+    """Dedicated scrape rounds against a live master+volume+filer
+    stack: every target the master knows is scraped and up, each round
+    is counted, and the plane accounts its own busy time.  What a round
+    costs is a number for the chip host, not asserted here."""
     master, vs, filer = stack
     plane = master.health
-    # the loop thread may also be scraping; measure dedicated rounds
-    rounds = 5
-    t0 = time.thread_time()
+    want = {master.address: "master", vs.address: "volume",
+            filer.address: "filer"}
+    deadline = time.monotonic() + 30  # the filer registers by itself
+    while plane.targets() != want and time.monotonic() < deadline:
+        time.sleep(0.02)
+    targets = plane.targets()
+    assert targets == want
+    # the loop thread may also be scraping: count dedicated rounds
+    rounds, before = 5, plane.rounds
     for _ in range(rounds):
         plane.scrape_round()
-    busy = (time.thread_time() - t0) / rounds
-    # default cadence (not the test override): one round's CPU cost
-    # amortized over 5 s must stay under 1% of one core
-    assert busy / 5.0 <= 0.01, f"scrape round burned {busy * 1000:.1f} ms CPU"
-    assert plane.rounds >= rounds
+    assert plane.rounds >= before + rounds
+    assert {a: plane._up.get(a) for a in targets} == \
+        {a: 1 for a in targets}
+    assert plane.busy_seconds > 0

@@ -4,6 +4,7 @@ MasterClient failover order — all on fake clocks / injected faults, no
 real sleeps."""
 
 import http.client
+import threading
 import time
 
 import pytest
@@ -214,13 +215,16 @@ class TestHedging:
             policy.hedged("/k", [boom, boom])
 
     def test_slow_primary_loses_to_hedge(self):
-        def slow():
-            time.sleep(0.5)
+        answered = threading.Event()
+
+        def slow():  # still out when the hedge's answer is returned
+            assert answered.wait(30)
             return "slow"
 
-        t0 = time.monotonic()
-        assert policy.hedged("/k", [slow, lambda: "fast"]) == "fast"
-        assert time.monotonic() - t0 < 0.4
+        try:
+            assert policy.hedged("/k", [slow, lambda: "fast"]) == "fast"
+        finally:
+            answered.set()
 
     def test_adaptive_delay_is_p95(self):
         t = policy.HedgeTracker()

@@ -399,18 +399,21 @@ def _queued_decode():
     outs = []
     leader = threading.Thread(
         target=lambda: outs.append(batcher.decode((1,), 0, inputs)))
+    t0 = time.perf_counter()
     leader.start()
     assert entered.wait(5)
     outs.append(batcher.decode((1,), 0, inputs))     # the follower
     leader.join(5)
     assert len(outs) == 2
-    return stats_.snapshot()
+    return stats_.snapshot(), time.perf_counter() - t0
 
 
 def test_queue_wait_is_counted_for_the_follower_only():
-    snap = _queued_decode()
+    snap, around = _queued_decode()
     assert snap["decode_batches"] == 2 and snap["decode_blocks"] == 2
-    assert 0.005 < snap["decode_queue_seconds"] < 5
+    # a floor from the leader's own sleep, and a wait that lies inside
+    # the bracket this test put around both decodes
+    assert 0.005 < snap["decode_queue_seconds"] <= around
     # the wait lies outside the decode stage: it ends where a batch starts
     assert snap["decode_seconds"] >= 0.02
 
@@ -608,7 +611,8 @@ def test_a_sampled_seal_has_one_root_with_its_stage_totals(tmp_path,
     root = tree[0]
     # a real span: it started when the seal did, not back-dated from the end
     assert t_before <= root["start"] <= time.time()
-    assert root["duration_ms"] == pytest.approx(st["wall"] * 1e3, abs=250)
+    # and it holds the pipeline's `wall` (a figure rounded to a ms)
+    assert root["duration_ms"] >= st["wall"] * 1e3 - 1.0
     totals = {c["name"]: c for c in root["children"]
               if (c.get("tags") or {}).get("total")}
     keys = ("read", "encode_crc", "write", "flush") if host else \

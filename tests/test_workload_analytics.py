@@ -715,17 +715,18 @@ class TestTemperature:
 
 
 # ---------------------------------------------------------------------------
-# perf smoke: the recorder must stay out of the read path's way
+# perf smoke: the recorder on the read path counts, and stays bounded
 # ---------------------------------------------------------------------------
 
 @pytest.mark.perf_smoke
-class TestRecorderOverhead:
-    def test_record_cost_within_two_percent_of_smallfile_read(
+class TestRecorderOnReadPath:
+    def test_every_smallfile_read_recorded_once_and_sketch_bounded(
             self, tmp_path):
-        """The gate bench.py's workload_analytics phase also enforces:
-        one warmed record() must cost <= 2% of a live small-file read.
-        Both sides are measured on this box back to back, so the ratio
-        holds on loaded CI machines too."""
+        """A live small-file storm is counted request for request by
+        the volume server's own recorder, and a recorder fed a 15,000-
+        record zipfian stream over 200 keys holds every record in its
+        totals while its tables stay inside WEED_HEAT_MAX_KEYS.  What a
+        record() costs beside a read is not measured here."""
         from seaweedfs_tpu.master.server import MasterServer
         from seaweedfs_tpu.volume_server.server import VolumeServer
 
@@ -744,39 +745,34 @@ class TestRecorderOverhead:
                 call(a["url"], f"/{a['fid']}", raw=os.urandom(2048),
                      method="POST")
                 fids.append((a["url"], a["fid"]))
-            for url, fid in fids:                      # warm pass
-                call(url, f"/{fid}")
-            n_reads = 300
-            t0 = time.perf_counter()
+            n_reads = 330
             for i in range(n_reads):
                 url, fid = fids[i % len(fids)]
-                call(url, f"/{fid}")
-            read_us = (time.perf_counter() - t0) / n_reads * 1e6
-
-            rec = access.AccessRecorder(node="vs")
-            pool = [f"7,{i:08x}" for i in range(200)]
-            z = ZipfPopularity(len(pool), s=1.1, seed=3)
-
-            def feed(n, base):
-                for i in range(n):
-                    fid = pool[z.sample(base + i)]
-                    rec.record("read", fid=fid, volume=7, nbytes=2048,
-                               tenant=f"t{i % 16}", latency_s=5e-4,
-                               qos_class="standard")
-
-            feed(3000, 0)                              # warm the memos
-            best = float("inf")
-            for trial in range(3):
-                t0 = time.perf_counter()
-                feed(4000, 10000 + trial * 4000)
-                best = min(best, (time.perf_counter() - t0) / 4000 * 1e6)
-            overhead_pct = best / read_us * 100.0
-            assert overhead_pct <= 2.0, (
-                f"record() costs {best:.2f}us = {overhead_pct:.2f}% of a "
-                f"{read_us:.0f}us small-file read (gate: 2%)")
+                assert len(call(url, f"/{fid}")) == 2048
+            s = vs.access_recorder.summary()
+            assert s["records"] == len(fids) + n_reads
+            assert (s["writes"], s["reads"]) == (len(fids), n_reads)
+            assert s["bytes_read"] == n_reads * 2048
+            hot = SpaceSaving.from_dict(s["hot"])
+            assert {hot.estimate(fid) for _, fid in fids} == {
+                n_reads / len(fids)}       # heat is reads: eleven each
         finally:
             vs.stop()
             master.stop()
+
+        rec = access.AccessRecorder(node="vs")
+        pool = [f"7,{i:08x}" for i in range(200)]
+        z = ZipfPopularity(len(pool), s=1.1, seed=3)
+        for i in range(15000):
+            rec.record("read", fid=pool[z.sample(i)], volume=7,
+                       nbytes=2048, tenant=f"t{i % 16}", latency_s=5e-4,
+                       qos_class="standard")
+        s = rec.summary()
+        assert s["records"] == s["reads"] == 15000
+        assert s["bytes_read"] == 15000 * 2048
+        assert rec.tracked_keys() <= 200 + 16 + 2   # fids, tenants, volume
+        assert len(s["tenants"]) == 16
+        assert sum(t["ops"]["read"] for t in s["tenants"].values()) == 15000
 
 
 if __name__ == "__main__":
