@@ -22,7 +22,11 @@ parity+CRC step (parallel/mesh.py) with a three-stage pipeline:
                     device slab pool (ops/device_pool.py): staging slots
                     and donated output slots are leased once and recycled,
                     so the steady state performs zero per-batch device
-                    allocations;
+                    allocations.  On a mesh of N devices whole batches
+                    are dealt to the devices in turn (_DeviceLane): one
+                    contiguous upload, one one-device step and one
+                    single-device copy back a batch, never an array
+                    sharded over the mesh;
   completion thread — synchronizes finished batches, chains per-shard-file
                     rolling CRC32Cs, recycles slots, and hands parity to
   writer thread   — appends parity bytes to .ec10-.ec13.
@@ -548,9 +552,9 @@ class _PipelineIO:
     Staging slots are leased from the device slab pool so repeated
     encodes with the same geometry reuse the same buffers.  Two layouts:
 
-      "bk" — (B, 10, L): the TPU word/sharded steps' input layout; every
-             unit's 10 rows are zero-padded to the format (device CRC
-             covers all 14 shards).
+      "bk" — (B, 10, L): the TPU words step's and the XLA step's input
+             layout; every unit's 10 rows are zero-padded to the format
+             (device CRC covers all 14 shards).
       "kb" — (10, B, L): the pooled CPU parity step's layout — slicing
              [:k_max] off axis 0 compacts away trailing all-zero rows
              as one contiguous view, and each shard row stays contiguous
@@ -723,7 +727,8 @@ def _device_inflight() -> int:
     completion thread must drain one (default 3).  Depth hides dispatch
     and transfer latency — H2D, compute and D2H genuinely overlap: the
     staging slots (depth + 1 or more) are the double-buffered H2D ring,
-    the donated output slots (depth + 1) the D2H drain ring."""
+    the donated output slots (depth + 1 over the mesh, at least one a
+    device) the D2H drain rings."""
     try:
         return max(1, int(
             os.environ.get("WEED_EC_DEVICE_INFLIGHT", "") or _INFLIGHT))
@@ -747,12 +752,32 @@ def _fused_crc_on(platform: str) -> bool:
     return platform != "cpu"
 
 
+@dataclass
+class _DeviceLane:
+    """One device of the seal's mesh: whole batches are dealt to the
+    lanes in turn (batch n to lane n mod N), so everything a batch
+    touches on a device is single-device: its upload, the step compiled
+    for that device, the ring of donated output slots the step aliases
+    its parity into (pooled path), and the copy back.  `batches` counts
+    the batches it took in this seal."""
+    device: object
+    step: object
+    out_ring: "queue.Queue" = field(default_factory=queue.Queue)
+    batches: int = 0
+
+    @property
+    def label(self) -> str:
+        """What the slab pool and the link counters account the lane
+        under."""
+        return str(self.device)
+
+
 def _encode_units_device(plans, units, chunk, writers, mesh,
                          batch_units,
                          stage_stats: Optional[dict] = None
                          ) -> dict[str, list[int]]:
     import jax
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
 
     from ..ops import crc32c as crc_host
     from ..ops.crc_device import finalize
@@ -763,12 +788,15 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
     wall0 = time.perf_counter()
     if mesh is None:
         mesh = make_ec_mesh()  # WEED_EC_DEVICE_SHARD picks the width
-    n_data, n_block = mesh.devices.shape
-    platform = mesh.devices.flat[0].platform
+    devices = list(mesh.devices.flat)
+    n_dev = len(devices)
+    dev0 = devices[0]
+    platform = dev0.platform
     # Path selection: the single-TPU-device Pallas words step when it
-    # can serve; otherwise the pooled persistent kb step (shard_map over
-    # the batch axis on multi-device meshes) whenever the chunk packs
-    # into int32 words; the bk XLA step is the odd-chunk fallback.
+    # can serve; otherwise the pooled persistent kb step whenever the
+    # chunk packs into int32 words; the bk XLA step is the odd-chunk
+    # fallback.  Whichever it is, a mesh of N devices is N lanes of the
+    # one-device step: no batch is sharded.
     use_words = words_capable(mesh, chunk)
     pooled = (not use_words) and chunk % 4 == 0
     # Pooled-path CRC placement (WEED_EC_FUSED_CRC): fused — the parity
@@ -778,36 +806,20 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
     # GF(2) bit-matmul CRC's rate there).
     fused = pooled and _fused_crc_on(platform)
     host_crc = pooled and not fused
-    width = (chunk // 4) if pooled else chunk  # sharded trailing axis
-    if pooled and n_block != 1:
-        # the kb step shards the batch axis only — a shard row's CRC
-        # reduces over its whole width, so byte columns stay device-local
-        mesh = Mesh(mesh.devices.reshape(-1, 1), mesh.axis_names)
-        n_data, n_block = mesh.devices.shape
-    elif not pooled and width % n_block:
-        mesh = Mesh(mesh.devices.reshape(-1, 1), mesh.axis_names)
-        n_data, n_block = mesh.devices.shape
 
     if batch_units is None:
         batch_units = max(1, TARGET_BATCH_BYTES // (DATA_SHARDS * chunk))
-    # ONE fixed compiled shape for every batch in the call (the tail
-    # batch is shorter than b; its pad columns are never read back)
-    b = min(batch_units, len(units))
-    b = max(n_data, ((b + n_data - 1) // n_data) * n_data)
+    # `batch_units` is what one round of the deal holds; a batch is one
+    # lane's share of it, one execution of the step.  ONE fixed compiled
+    # shape for every batch in the call (the tail batch is shorter than
+    # b; its pad columns are never read back)
+    b = -(-min(batch_units, len(units)) // n_dev)
 
     depth = _device_inflight()
     pool = get_pool()
-    single = mesh.devices.size == 1
-    dev0 = mesh.devices.flat[0]
-    # the pool's free-lists and the link counters key per device; a
-    # sharded slab spans the mesh, so it accounts under one composite
-    # placement label
-    dev_label = str(dev0) if single else f"sharded:{mesh.devices.size}"
-    sharding = NamedSharding(mesh, P("data", None, "block"))
-    sharding_kb = NamedSharding(mesh, P(None, "data", "block"))
 
     if pooled:
-        step = make_parity_step(mesh, fused_crc=fused)
+        make_step = functools.partial(make_parity_step, fused_crc=fused)
         layout = "kb"
         backend = ("device-pooled-swar-fused-crc" if fused
                    else "device-pooled-swar")
@@ -815,54 +827,65 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
         # staging slot IS the device buffer, so H2D costs nothing (the
         # slot is recycled only after the completion thread synchronized
         # the batch, so the aliased memory is never overwritten mid-read)
-        zero_copy = single and aliases_host_memory(dev0)
+        zero_copy = n_dev == 1 and aliases_host_memory(dev0)
     else:
         # word-layout fast path: packed int32 views move host<->device
         # with no device bitcasts (the relayout costs 10x the kernel)
-        step = make_sharded_encoder(mesh, words=use_words)
+        make_step = functools.partial(make_sharded_encoder, words=use_words)
         layout = "bk"
         backend = "device-words" if use_words else "device-xla"
         zero_copy = False
+    # the step builders cache what they build, and JAX the executable
+    # of each device: a later seal finds its lanes' programs built
+    lanes = [_DeviceLane(d, make_step(
+                 Mesh(np.array([d]).reshape(1, 1), mesh.axis_names)))
+             for d in devices]
 
     # the staging slots double as the H2D ring: the reader fills slot
-    # N+1 while slot N's transfer/compute is in flight, at any depth
-    n_slots = max(_SLOTS, depth + 1)
+    # N+1 while slot N's transfer/compute is in flight, at any depth,
+    # and one more than the lanes so that every lane can hold a batch
+    # while the next is read
+    n_slots = max(_SLOTS, depth + 1, n_dev + 1)
     io = _PipelineIO(plans, units, chunk, writers, b, layout, pool,
                      n_slots=n_slots)
     timers = io.timers
     add_time = io.add_time
     root = io.root
 
-    # donated output-slot ring (pooled path): depth+1 device slots the
-    # persistent step aliases its parity into — the donation swap means
-    # the steady state allocates nothing on device per batch; the ring
-    # is also the D2H drain buffer (the completion thread copies out of
-    # slot N while slot N+1 is still computing)
-    out_ring: "queue.Queue" = queue.Queue()
+    # donated output-slot rings (pooled path), one a lane: device slots
+    # the persistent step aliases its parity into — the donation swap
+    # means the steady state allocates nothing on device per batch; a
+    # ring is also the D2H drain buffer (the completion thread copies
+    # out of one slot while the next is still computing).  depth + 1
+    # slots over the mesh, and at least one a lane.
     out_leases: list = []
     if pooled:
-        oshape = (PARITY_SHARDS, b, width)
+        oshape = (PARITY_SHARDS, b, chunk // 4)
 
-        def _out_factory():
-            z = np.zeros(oshape, dtype=np.int32)
-            return jax.device_put(z, dev0 if single else sharding_kb)
+        def _out_slot(device):
+            return jax.device_put(np.zeros(oshape, dtype=np.int32), device)
 
-        okey = ("ec-out", mesh, oshape)
-        for _ in range(depth + 1):
-            ls = pool.lease(okey, _out_factory, PARITY_SHARDS * b * chunk,
-                            device=dev_label)
-            out_leases.append(ls)
-            out_ring.put(ls)
+        for lane in lanes:
+            for _ in range(-(-(depth + 1) // n_dev)):
+                ls = pool.lease(("ec-out", oshape),
+                                functools.partial(_out_slot, lane.device),
+                                PARITY_SHARDS * b * chunk, device=lane.label)
+                out_leases.append(ls)
+                lane.out_ring.put(ls)
 
     zcrc = crc_host.crc32c_zeros(chunk)
-    done_q: "queue.Queue" = queue.Queue(maxsize=depth)
+    # every lane may hold a batch before the completion thread must
+    # drain one
+    done_q: "queue.Queue" = queue.Queue(maxsize=max(depth, n_dev))
     k_shapes: set = set()
     kernel_lats: list = []  # host-timed dispatch->ready per batch
 
-    def _complete(n, slot, batch, out, crc_dev, t_disp, k_rows):
-        """Synchronize batch n: D2H, per-chunk CRCs chained into the
-        rolling shard-file CRCs (FIFO order — CRC chaining is order-
-        dependent), slots recycled, parity handed to the writer."""
+    def _complete(n, lane, slot, batch, out, crc_dev, t_disp, k_rows):
+        """Synchronize batch n, which ran on `lane`: D2H, per-chunk CRCs
+        chained into the rolling shard-file CRCs (FIFO order — CRC
+        chaining is order-dependent — while the batches behind it upload
+        and compute on the other lanes), slots recycled, parity handed
+        to the writer."""
         buf = slot.payload
         t0 = time.perf_counter()
         if pooled:
@@ -880,16 +903,15 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
                 lat = time.perf_counter() - t_disp
                 kernel_lats.append(lat)
                 profiling.record_device_batch(lat, units=len(batch),
-                                              k=k_rows,
-                                              devices=mesh.devices.size)
+                                              k=k_rows, devices=n_dev)
             with tracing.stage("ec.encode.crc", add_time, "crc_host", n):
                 if out is not None:
-                    pool.note_d2h(parity32.nbytes, device=dev_label)
-                    out_ring.put(out)
+                    pool.note_d2h(parity32.nbytes, device=lane.label)
+                    lane.out_ring.put(out)
                     parity = parity32.view(np.uint8).reshape(
                         PARITY_SHARDS, b, chunk)
                     if fused:
-                        pool.note_d2h(raw.nbytes, device=dev_label)
+                        pool.note_d2h(raw.nbytes, device=lane.label)
                         fin = finalize(raw, chunk)  # (k_rows + 4, b)
                 if fused:
                     # the device already CRC'd every row (padding rows
@@ -937,17 +959,16 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
             parity_dev, crc_dev = out
             with tracing.stage("ec.encode.d2h_wait", add_time, "d2h_wait",
                                n):
-                # blocks until compute done; sharded gathers can come
-                # back non-contiguous, and file writes need a contiguous
-                # buffer
+                # blocks until compute done; file writes need a
+                # contiguous buffer
                 parity = np.ascontiguousarray(np.asarray(parity_dev))
                 raw = np.asarray(crc_dev)
             lat = time.perf_counter() - t_disp
             kernel_lats.append(lat)
             profiling.record_device_batch(lat, units=len(batch), k=k_rows,
-                                          devices=mesh.devices.size)
+                                          devices=n_dev)
             with tracing.stage("ec.encode.crc", add_time, "crc_host", n):
-                pool.note_d2h(parity.nbytes + raw.nbytes, device=dev_label)
+                pool.note_d2h(parity.nbytes + raw.nbytes, device=lane.label)
                 if use_words:  # packed int32 parity words -> bytes
                     parity = parity.view(np.uint8).reshape(
                         parity.shape[0], PARITY_SHARDS, chunk)
@@ -989,6 +1010,8 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
             n += 1
             slot, batch, k_max = item
             buf = slot.payload
+            lane = lanes[n % n_dev]     # whole batches, dealt in turn
+            lane.batches += 1
             # background device lane: bulk encode yields to in-flight
             # foreground (degraded-read recover) decodes per batch
             lane_wait = _lanes.LANES.background_checkpoint()
@@ -1008,31 +1031,33 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
                             if zero_copy:
                                 din = jax.dlpack.from_dlpack(words)
                             else:
-                                din = jax.device_put(
-                                    words, dev0 if single else sharding_kb)
+                                # one contiguous array onto one device
+                                din = jax.device_put(words, lane.device)
                                 pool.note_h2d(words.nbytes,
-                                              device=dev_label)
-                        out = io.get(out_ring)  # backpressure at `depth`
+                                              device=lane.label)
+                        # backpressure: the lane's ring is empty until
+                        # the completion thread has drained a batch of it
+                        out = io.get(lane.out_ring)
                         if out is None:
                             break
                         # donation swap: the step aliases its result into
                         # the slot's buffer; the old handle is dead
                         if fused:
-                            out.payload, crc_dev = step(din, out.payload)
+                            out.payload, crc_dev = lane.step(din,
+                                                             out.payload)
                         else:
-                            out.payload = step(din, out.payload)
+                            out.payload = lane.step(din, out.payload)
                 else:
                     with tracing.stage("ec.encode.h2d", add_time, "h2d",
                                        n, buf.nbytes):
-                        if use_words:
-                            # pin to the mesh's device: the caller may
-                            # run several 1-device meshes side by side
-                            din = jax.device_put(buf.view(np.int32), dev0)
-                        else:
-                            din = jax.device_put(buf, sharding)
-                        pool.note_h2d(buf.nbytes, device=dev_label)
-                    out = step(din)
-            if not io.put(done_q, (slot, batch, out, crc_dev, t0, k_max)):
+                        # packed int32 words for the words step
+                        din = jax.device_put(
+                            buf.view(np.int32) if use_words else buf,
+                            lane.device)
+                        pool.note_h2d(buf.nbytes, device=lane.label)
+                    out = lane.step(din)
+            if not io.put(done_q,
+                          (lane, slot, batch, out, crc_dev, t0, k_max)):
                 break
         io.put(done_q, None)
         ct.join(timeout=600)
@@ -1054,14 +1079,15 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
         stage_stats["wall"] = round(wall, 3)
         stage_stats["backend"] = backend
         stage_stats["batches"] = io.n_batches
-        stage_stats["batch_units"] = b
+        # one round of the deal: `devices` executions of b units each
+        stage_stats["batch_units"] = b * n_dev
+        stage_stats["device_batches"] = [lane.batches for lane in lanes]
         stage_stats["k_shapes"] = sorted(k_shapes)
         stage_stats["inflight"] = depth
         stage_stats["staging_slots"] = n_slots
         stage_stats["read_workers"] = io.read_workers
         stage_stats["zero_copy_h2d"] = zero_copy
-        stage_stats["devices"] = mesh.devices.size
-        stage_stats["device_shard"] = dev_label
+        stage_stats["devices"] = n_dev
         stage_stats["platform"] = platform
         stage_stats["device_kind"] = dev0.device_kind
         stage_stats["crc_path"] = "host" if host_crc else "fused-device"

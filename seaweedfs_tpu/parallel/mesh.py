@@ -174,7 +174,10 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
     which is what lets the steady state run with zero per-batch device
     allocations.
 
-    Multi-device meshes run the step through shard_map: the batch axis
+    The seal builds the step on one-device meshes only: it deals whole
+    batches to the devices of its mesh, a lane each
+    (batched_encode._DeviceLane).  Multi-device meshes (the deep scrub's)
+    run the step through shard_map: the batch axis
     partitions over "data" with PartitionSpec, every device computes the
     parity (and fused CRC) of its own batch slice, and no collective is
     needed because a shard row's bytes never cross devices (the mesh's
@@ -185,8 +188,9 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
     is ~30x the GF(2) bit-matmul CRC's rate on CPU, so the pipeline CRCs
     on host while the next batch is in flight.  TPU meshes fuse.
 
-    One jitted callable per (mesh, geometry, fused_crc), shared across
-    encode calls; XLA's shape-keyed trace cache handles per-k retraces.
+    One jitted callable per (mesh, geometry, fused_crc) — one for all
+    one-device meshes — shared across encode calls; XLA's shape-keyed
+    trace cache handles per-k retraces.
 
     matrix / key: an alternative GF(2^8) coefficient matrix (a code
     family's parity or lane generator rows) with an optional hashable
@@ -198,11 +202,14 @@ def make_parity_step(mesh: Mesh, data_shards: int = 10,
     from ..ops.crc_device import batched_crc32c_raw
     from ..ops.rs_jax import _SPREAD, _bit_constants_cached
 
+    # a one-device step runs where its arguments live: one jitted
+    # callable, traced once a shape, serves every one-device mesh
+    where = mesh if mesh.devices.size > 1 else None
     if matrix is None:
-        cache_key = (mesh, data_shards, parity_shards, fused_crc)
+        cache_key = (where, data_shards, parity_shards, fused_crc)
     else:
         matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
-        cache_key = (mesh, key if key is not None else matrix.tobytes(),
+        cache_key = (where, key if key is not None else matrix.tobytes(),
                      fused_crc)
     cached = _PARITY_STEP_CACHE.get(cache_key)
     if cached is not None:

@@ -285,8 +285,9 @@ def record_device_batch(latency_s: float, units: int = 0, k: int = 0,
                         devices: int = 1):
     """One EC device batch completed: host-observed dispatch->ready
     latency (rides the WEED_EC_DEVICE_INFLIGHT completion FIFO).
-    `devices` is the shard width of the dispatch — the histogram is
-    labeled by it, so a stall that only appears at a given mesh width
+    `devices` is the width of the mesh the job runs over (a seal deals
+    whole batches to its devices, a rebuild shards each) — the histogram
+    is labeled by it, so a stall that only appears at a given mesh width
     shows up as its own latency series."""
     _stats.EcKernelDispatchHistogram.labels(str(devices)).observe(latency_s)
     with _tl_lock:

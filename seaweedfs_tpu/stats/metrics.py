@@ -384,8 +384,9 @@ DevicePoolEvictionsCounter = REGISTRY.counter(
 EcDeviceH2dBytesCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_device_h2d_bytes_total",
     "bytes staged host->device by the EC device dispatch paths, by "
-    "target device (\"host\" = host staging, \"sharded:N\" = an N-way "
-    "sharded mesh transfer)", ("device",))
+    "target device (a seal deals whole batches to the devices of its "
+    "mesh, one label each; \"sharded:N\" = a rebuild's or a deep "
+    "scrub's transfer sharded over an N-device mesh)", ("device",))
 EcDeviceD2hBytesCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_device_d2h_bytes_total",
     "bytes fetched device->host by the EC device dispatch paths, by "

@@ -233,10 +233,10 @@ def test_device_scale_dispatch_smoke(tmp_path, monkeypatch):
             bases.append(base)
         return bases
 
-    # a retention cap that holds one run's slabs: on the 8-device mesh
-    # the staging and output rings (4 x 80 MiB + 4 x 32 MiB) outgrow the
-    # default 256 MiB, and an evicted slab is an honest new allocation
-    monkeypatch.setenv("WEED_EC_DEVICE_POOL_MB", "1024")
+    # the default retention cap holds one run's slabs on the 8-device
+    # mesh (9 staging slots of one unit and an output slot a device);
+    # an evicted slab would be an honest new allocation
+    monkeypatch.delenv("WEED_EC_DEVICE_POOL_MB", raising=False)
     reset_pool()
     mesh = make_ec_mesh(jax.devices("cpu"))
     encode_volumes(volumes("dwarm", 500), mesh=mesh)

@@ -1,14 +1,19 @@
-"""Sharded multi-device EC dispatch: shard_map parity + fused on-device
+"""Multi-device EC dispatch: the shard_map parity step + fused on-device
 CRC vs the single-device and host paths (parallel/mesh.make_parity_step,
-parallel/batched_encode device pipeline).
+which the deep scrub still runs over a whole mesh), and the seal's device
+pipeline (parallel/batched_encode), which deals whole batches to the
+devices of its mesh in turn: every upload, step and copy back is
+single-device.
 
 Runs on the conftest-forced 8-virtual-device CPU backend: the
 @multidevice tests build real 4-device meshes, so the shard_map
-partitioning, donation-under-shard_map and per-device pool keying are
+partitioning, donation, the dealing and per-device pool keying are
 exercised in tier-1 without TPU hardware.
 """
 
+import math
 import os
+import threading
 
 import numpy as np
 import pytest
@@ -16,8 +21,12 @@ import pytest
 from seaweedfs_tpu.ops import crc32c as crc_host
 from seaweedfs_tpu.ops import gf256
 from seaweedfs_tpu.ops.crc_device import finalize
-from seaweedfs_tpu.ops.rs_numpy import gf_apply_matrix
+from seaweedfs_tpu.ops.device_pool import get_pool
+from seaweedfs_tpu.ops.rs_numpy import NumpyEncoder, gf_apply_matrix
+from seaweedfs_tpu.parallel import batched_encode as be
+from seaweedfs_tpu.parallel import mesh as mesh_mod
 from seaweedfs_tpu.parallel.batched_encode import encode_volumes
+from seaweedfs_tpu.stats import metrics as stats_mod
 from seaweedfs_tpu.storage.erasure_coding import to_ext
 from seaweedfs_tpu.storage.erasure_coding.codes import get_family
 
@@ -109,8 +118,8 @@ class TestShardedParityStep:
 
 @pytest.mark.multidevice
 class TestShardedPipeline:
-    """encode_volumes end-to-end on a 4-device sharded mesh: fused and
-    host CRC paths both byte-identical to the host reference, across
+    """encode_volumes end-to-end on a 4-device mesh: fused and host CRC
+    paths both byte-identical to the host reference, across
     padded/masked tails and donation depths."""
 
     def _encode(self, tmp_path, monkeypatch, sizes, fused, inflight=3):
@@ -167,6 +176,200 @@ class TestShardedPipeline:
             tmp_path, monkeypatch, sizes, fused=True, inflight=inflight)
         assert stats["inflight"] == inflight
         self._check(tmp_path, bases, crcs)
+
+
+def _spy_parity_steps(monkeypatch, calls: list, fail=None):
+    """Every parity step the seal builds records (device of its mesh,
+    shape and devices of the data it is given, devices of the donated
+    slot); `fail(device, n_calls)` true raises in that lane's step."""
+    real = mesh_mod.make_parity_step
+
+    def make(mesh, *args, **kwargs):
+        (device,) = mesh.devices.flat   # the seal asks for one-device steps
+        step = real(mesh, *args, **kwargs)
+
+        def spied(din, out):
+            calls.append((device, din.shape, din.devices(), out.devices()))
+            if fail is not None and fail(device, len(calls)):
+                raise RuntimeError(f"injected into the lane of {device}")
+            return step(din, out)
+        return spied
+
+    monkeypatch.setattr(mesh_mod, "make_parity_step", make)
+
+
+def _link_bytes_by_device() -> dict:
+    return dict(get_pool().snapshot()["devices"])
+
+
+def _shard_files(base: str) -> list[bytes]:
+    out = []
+    for i in range(14):
+        with open(base + to_ext(i), "rb") as f:
+            out.append(f.read())
+    return out
+
+
+@pytest.mark.multidevice
+class TestDealtBatches:
+    """A mesh of N devices is N lanes of the one-device step: batch n
+    goes whole to device n mod N.  Results and exact counts."""
+
+    # a large row (100 units), small rows, partial tails and a one-byte
+    # volume: 27 batches of 4 units and a tail batch of 3
+    SIZES = [LARGE * 10 + SMALL * 10 * 3 + 41, SMALL * 10 * 5 + 7, 1]
+
+    def _seal(self, tmp_path, monkeypatch, n_dev, fused, tag,
+              batch_units=16):
+        monkeypatch.setenv("WEED_EC_DEVICE_SHARD", str(n_dev))
+        monkeypatch.setenv("WEED_EC_FUSED_CRC", "1" if fused else "0")
+        bases = [_make_volume(tmp_path, f"{tag}{k}", size, 17 * k + size)
+                 for k, size in enumerate(self.SIZES)]
+        stats = {}
+        crcs = encode_volumes(bases, large_block=LARGE, small_block=SMALL,
+                              batch_units=batch_units, stage_stats=stats)
+        return bases, crcs, stats
+
+    def _units(self, bases) -> int:
+        chunk = be._chunk_len(LARGE, SMALL)
+        plans = [be._plan_volume(b, LARGE, SMALL) for b in bases]
+        return len(be._make_units(plans, chunk))
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["host-crc", "fused-crc"])
+    def test_seal_is_exact_and_every_batch_ran_on_its_device(
+            self, tmp_path, monkeypatch, fused):
+        calls: list = []
+        _spy_parity_steps(monkeypatch, calls)
+        devices = jax.devices()[:4]
+        before = _link_bytes_by_device()
+        bases, crcs, st = self._seal(tmp_path, monkeypatch, 4, fused, "v")
+
+        assert st["backend"] == ("device-pooled-swar-fused-crc" if fused
+                                 else "device-pooled-swar")
+        assert st["devices"] == 4 and st["platform"] == "cpu"
+        assert st["crc_path"] == ("fused-device" if fused else "host")
+        # one execution handles batch_units / devices units on one chip
+        per_step = st["batch_units"] / st["devices"]
+        assert per_step == 4
+        n_units = self._units(bases)
+        assert st["batches"] == math.ceil(n_units / per_step) == 28
+        assert n_units % per_step == 3              # a short tail batch
+        assert len(calls) == st["batches"]
+        for n, (device, shape, din_on, out_on) in enumerate(calls):
+            assert device == devices[n % 4], n      # dealt in turn
+            assert din_on == out_on == {device}, n  # single-device arrays
+            assert shape[1] == per_step, (n, shape)
+        assert st["device_batches"] == [7, 7, 7, 7]
+        assert sum(st["device_batches"]) == st["batches"]
+
+        # the link counters: one label a device, together the .dat bytes
+        after = _link_bytes_by_device()
+        grew = {d: after[d]["h2d_bytes"] - before.get(d, {}).get(
+            "h2d_bytes", 0) for d in after}
+        assert {d for d, n in grew.items() if n} == {str(d) for d in devices}
+        assert sum(grew.values()) >= sum(self.SIZES)
+        assert not any(d.startswith("sharded:") and n
+                       for d, n in grew.items())
+        for d in devices:
+            assert stats_mod.EcDeviceH2dBytesCounter._values[
+                (str(d),)] >= grew[str(d)]
+
+        # byte- and CRC-exact against the plain numpy codec
+        for base in bases:
+            got = _shard_files(base)
+            full = NumpyEncoder(10, 4).encode(
+                [np.frombuffer(s, dtype=np.uint8) for s in got[:10]]
+                + [None] * 4)
+            assert [np.asarray(p).tobytes() for p in full[10:]] == got[10:]
+            assert crcs[base] == [crc_host.crc32c(s) for s in got]
+            with open(base + ".dat", "rb") as f:
+                dat = f.read()
+            striped = b"".join(
+                got[i][off:off + blk]
+                for _, off, blk in be._plan_volume(base, LARGE, SMALL).rows
+                for i in range(10))
+            assert striped[:len(dat)] == dat
+            assert not any(striped[len(dat):])
+
+    @pytest.mark.parametrize("n_dev", [1, 2, 3, 4])
+    def test_batches_differ_by_at_most_one_a_device(
+            self, tmp_path, monkeypatch, n_dev):
+        """The deal follows the mesh's width, whatever it is: 111 units
+        in rounds of 16 (18 over three devices: the quotient is exact),
+        and the rest of the count on the first lanes."""
+        _, _, st = self._seal(tmp_path, monkeypatch, n_dev, False, "w")
+        per_step = math.ceil(16 / n_dev)
+        assert st["devices"] == n_dev
+        assert st["batch_units"] == per_step * n_dev
+        batches = math.ceil(111 / per_step)
+        assert st["batches"] == batches
+        assert sum(st["device_batches"]) == batches
+        assert max(st["device_batches"]) - min(st["device_batches"]) <= 1
+        assert st["device_batches"] == sorted(st["device_batches"],
+                                              reverse=True)
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["host-crc", "fused-crc"])
+    def test_one_device_gives_the_same_files_as_four(
+            self, tmp_path, monkeypatch, fused):
+        four, crcs4, st4 = self._seal(tmp_path, monkeypatch, 4, fused, "f")
+        one, crcs1, st1 = self._seal(tmp_path, monkeypatch, 1, fused, "o")
+        assert (st4["devices"], st1["devices"]) == (4, 1)
+        assert st1["device_batches"] == [st1["batches"]]
+        for b4, b1 in zip(four, one):
+            assert _shard_files(b4) == _shard_files(b1)
+            assert crcs4[b4] == crcs1[b1]
+
+    @pytest.mark.parametrize("fused", [False, True],
+                             ids=["host-crc", "fused-crc"])
+    def test_failure_in_one_lane_fails_the_seal_and_frees_the_pool(
+            self, tmp_path, monkeypatch, fused):
+        calls: list = []
+        third = jax.devices()[2]
+        # the third lane's second batch (the eleventh of the seal)
+        _spy_parity_steps(
+            monkeypatch, calls,
+            fail=lambda device, n: device == third and n > 4)
+        with pytest.raises(RuntimeError, match="injected into the lane"):
+            self._seal(tmp_path, monkeypatch, 4, fused, "x")
+        assert [c[0] for c in calls].count(third) == 2
+        assert len(calls) == 7          # nothing was dealt after it
+        snap = get_pool().snapshot()
+        assert snap["leased_slots"] == 0
+        assert not [t.name for t in threading.enumerate()
+                    if t.name.startswith("ec-encode")]
+        # and the pool's slabs serve the next seal
+        monkeypatch.undo()
+        bases, crcs, st = self._seal(tmp_path, monkeypatch, 4, fused, "y")
+        assert sum(st["device_batches"]) == st["batches"]
+        for base in bases:
+            assert crcs[base] == [crc_host.crc32c(s)
+                                  for s in _shard_files(base)]
+
+    def test_rings_and_staging_fit_the_pool_on_four_devices(
+            self, tmp_path, monkeypatch):
+        """At the chip's geometry (1 MiB chunks, the default target
+        batch) a four-device seal holds five staging slots of two units
+        and one output slot a device: 132 MiB of the pool's 256."""
+        monkeypatch.setenv("WEED_EC_DEVICE_SHARD", "4")
+        monkeypatch.setenv("WEED_EC_FUSED_CRC", "0")
+        monkeypatch.delenv("WEED_EC_DEVICE_INFLIGHT", raising=False)
+        monkeypatch.delenv("WEED_EC_DEVICE_POOL_MB", raising=False)
+        base = _make_volume(tmp_path, "big", (10 << 20) * 9 + 5, 3)
+        pool = get_pool()
+        pool.clear()
+        evictions = pool.snapshot()["evictions"]
+        st = {}
+        encode_volumes([base], stage_stats=st)
+        assert st["batch_units"] == 8 and st["batches"] == 5
+        assert st["device_batches"] == [2, 1, 1, 1]
+        assert st["staging_slots"] == 5
+        snap = st["pool"]
+        assert snap["evictions"] == evictions
+        assert snap["bytes"] == 5 * (20 << 20) + 4 * (8 << 20)
+        assert snap["bytes"] <= 256 << 20
+        pool.clear()
 
 
 class TestDeviceShardKnob:
