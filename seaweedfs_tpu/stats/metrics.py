@@ -346,6 +346,29 @@ EcRecoverDeviceCounter = REGISTRY.counter(
     "degraded-read decodes dispatched to the device, by outcome "
     "(ok / fallback = the dispatch failed and the host codec served)",
     ("result",))
+# sealed reads (storage/erasure_coding/ec_volume.py ReadStats): every
+# needle EcVolume.read_needle served, plain or through a recovery
+EcReadNeedleCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_read_needles_total",
+    "needles served from EC shards (sealed and inline volumes): all, and "
+    "those whose stages were timed (a sampled request, or a profiler "
+    "session): the needles ec_read_stage_seconds is the sum over",
+    ("needles",))
+EcReadIntervalCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_read_intervals_total",
+    "shard intervals of those needles, by how each was served (plain = "
+    "a local shard, the tail stripe or a remote holder; recovered = "
+    "reconstructed from survivors or the recovered-block cache)",
+    ("served",))
+EcReadBytesCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_read_bytes_total",
+    "on-disk needle bytes of those intervals, by how each was served",
+    ("served",))
+EcReadStageSeconds = REGISTRY.gauge(
+    "SeaweedFS_volumeServer_ec_read_stage_seconds",
+    "busy seconds per sealed-read stage, summed over the timed needles "
+    "(locate = .ecx search + interval maths, shard = plain local shard "
+    "reads, assemble = join + needle parse with its CRC)", ("stage",))
 # inline write-path EC (storage/erasure_coding/inline.py): needles
 # stream straight into striped shard logs, parity commits per stripe
 EcInlineStripesCommitted = REGISTRY.counter(

@@ -51,6 +51,94 @@ def _recover_pool():
         return _recover_pool_inst
 
 
+# Stage keys of ReadStats.add_stage, in reply order.
+_READ_STAGES = ("locate", "shard", "assemble")
+
+
+class ReadStats:
+    """Cumulative sealed-read telemetry, process-wide like RecoverStats:
+    needles served by `EcVolume.read_needle`, their intervals and bytes
+    split by how each was served (plain = a local shard, the tail stripe
+    or a remote holder; recovered = through `_recover_span`), and busy
+    seconds of the three `ec.read.*` stages (locate = .ecx search +
+    interval maths, shard = the plain local `read_at` of one interval,
+    assemble = join + needle parse with its CRC).  The counts are of
+    every needle; the seconds are of the `timed_needles` among them
+    (`tracing.sampled_stage`: a sampled request, or a profiler session),
+    so a stage's cost a needle is its seconds over `timed_needles`.
+    Updated on every GET of a sealed volume, so an update is one lock
+    and a few additions; the Prometheus vectors are brought up to it at
+    scrape time (`export`), as the native engine's counters are."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.reset()
+
+    def reset(self):
+        with self._lock:
+            self._seconds = dict.fromkeys(_READ_STAGES, 0.0)
+            self.needles = 0
+            self.timed_needles = 0
+            self.intervals_plain = 0
+            self.intervals_recovered = 0
+            self.bytes_plain = 0
+            self.bytes_recovered = 0
+
+    def add_stage(self, stage: str, seconds: float):
+        """The stage accumulator handed to tracing.stage()."""
+        with self._lock:
+            self._seconds[stage] += seconds
+
+    def needle(self, intervals: int, nbytes: int, recovered: int,
+               recovered_bytes: int, timed: bool):
+        """One needle served: `recovered` of its `intervals` (and
+        `recovered_bytes` of its `nbytes`) came through a recovery;
+        `timed`: its stages added their seconds."""
+        with self._lock:
+            self.needles += 1
+            self.timed_needles += timed
+            self.intervals_plain += intervals - recovered
+            self.intervals_recovered += recovered
+            self.bytes_plain += nbytes - recovered_bytes
+            self.bytes_recovered += recovered_bytes
+
+    def snapshot(self) -> dict:
+        with self._lock:
+            out = {f"{k}_seconds": round(self._seconds[k], 6)
+                   for k in _READ_STAGES}
+            out.update({
+                "needles": self.needles,
+                "timed_needles": self.timed_needles,
+                "intervals": self.intervals_plain + self.intervals_recovered,
+                "intervals_plain": self.intervals_plain,
+                "intervals_recovered": self.intervals_recovered,
+                "bytes_plain": self.bytes_plain,
+                "bytes_recovered": self.bytes_recovered,
+            })
+        return out
+
+    def export(self):
+        """Bring the Prometheus ec_read_* vectors up to the counters."""
+        from ...stats import metrics as stats
+
+        snap = self.snapshot()
+        stats.EcReadNeedleCounter.labels("all").set_cumulative(
+            snap["needles"])
+        stats.EcReadNeedleCounter.labels("timed").set_cumulative(
+            snap["timed_needles"])
+        for served in ("plain", "recovered"):
+            stats.EcReadIntervalCounter.labels(served).set_cumulative(
+                snap["intervals_" + served])
+            stats.EcReadBytesCounter.labels(served).set_cumulative(
+                snap["bytes_" + served])
+        for stage in _READ_STAGES:
+            stats.EcReadStageSeconds.labels(stage).set(
+                snap[stage + "_seconds"])
+
+
+READ_STATS = ReadStats()
+
+
 class EcError(Exception):
     pass
 
@@ -268,11 +356,20 @@ class EcVolume:
 
     def read_needle(self, needle_id: int,
                     cookie: Optional[int] = None) -> Needle:
-        offset, size, intervals = self.locate_needle(needle_id)
+        with tracing.sampled_stage("ec.read.locate", READ_STATS.add_stage,
+                                   "locate") as timed:
+            offset, size, intervals = self.locate_needle(needle_id)
+        tls = self._tls
+        tls.recovered = tls.recovered_bytes = 0
         parts = [self._read_interval(iv) for iv in intervals]
-        blob = b"".join(parts)
-        n = Needle()
-        n.read_bytes(blob, offset, size, self.version)
+        nbytes = sum(iv.size for iv in intervals)
+        with tracing.sampled_stage("ec.read.assemble", READ_STATS.add_stage,
+                                   "assemble", len(parts), nbytes):
+            blob = b"".join(parts)
+            n = Needle()
+            n.read_bytes(blob, offset, size, self.version)
+        READ_STATS.needle(len(parts), nbytes, tls.recovered,
+                          tls.recovered_bytes, timed is not None)
         if cookie is not None and n.cookie != cookie:
             raise EcError(f"cookie mismatch for needle {needle_id:x}")
         return n
@@ -288,7 +385,10 @@ class EcVolume:
         volumes) -> remote hook -> reconstruct."""
         shard = self.shards.get(shard_id)
         if shard is not None:
-            data = shard.read_at(size, offset)
+            with tracing.sampled_stage("ec.read.shard",
+                                       READ_STATS.add_stage, "shard",
+                                       shard_id, size):
+                data = shard.read_at(size, offset)
             if len(data) == size:
                 return data
             if self.tail_reader is not None:
@@ -378,10 +478,15 @@ class EcVolume:
                 raise EcError(
                     f"recovered span short for shard {target_shard} at "
                     f"{offset}+{size}: got {len(out)}")
+        # read_needle's count of the intervals it served through here
+        tls = self._tls
+        tls.recovered = getattr(tls, "recovered", 0) + 1
+        tls.recovered_bytes = getattr(tls, "recovered_bytes", 0) + size
         return out
 
     # per-thread fetch+decode busy seconds inside the current span, so
-    # the serve stage reports assembly/wait overhead, not a double count
+    # the serve stage reports assembly/wait overhead, not a double count;
+    # and the intervals (and their bytes) the current needle recovered
     _tls = threading.local()
 
     def _add_serve(self, stage: str, seconds: float):

@@ -335,9 +335,10 @@ def _annotation_class():
 class stage:
     """``with stage(name, add, key[, n, nbytes]):`` — one pipeline stage
     of a device EC path, timed once.  `name` is one of the fixed
-    ``ec.encode.*`` / ``ec.recover.*`` / ``ec.rebuild.*`` names.  Meant
-    for per-batch and per-block sites — a few hundred calls a GiB —
-    never per row or per request.
+    ``ec.encode.*`` / ``ec.recover.*`` / ``ec.rebuild.*`` /
+    ``ec.read.*`` names.  Meant for per-batch and per-block sites — a
+    few hundred calls a GiB — never per row or per request: a site that
+    runs on every GET goes through ``sampled_stage()``.
 
       * ``add(key, seconds)``, the stage accumulator (the encode
         pipeline's and the rebuild's timers, ``RecoverStats.add_stage``),
@@ -398,6 +399,40 @@ class stage:
             self._ann.__exit__(exc_type, exc, tb)
         self.add(self.key, seconds)
         return False
+
+
+class _Unstaged:
+    """What ``sampled_stage()`` hands out when nobody is looking: a
+    block that measures nothing.  ``with ... as st`` gives None."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return None
+
+    def __exit__(self, exc_type, exc, tb):
+        return False
+
+
+_UNSTAGED = _Unstaged()
+
+
+def sampled_stage(name: str, add, key: str, n: int = -1, nbytes: int = -1):
+    """``stage()`` for a site on the path of every request (the sealed
+    read's ``ec.read.*``: three to eight blocks a GET).  A ``stage()``
+    there costs a GIL-bound server a few percent of its GETs (PERF.md,
+    PR 36: 4.6% of ``degraded-get-cached``), so the block is timed only
+    where it leaves more than a counter: the thread's span is sampled
+    (``WEED_TRACE_SAMPLE``, or the caller's header), or a jax.profiler
+    session is on.  Otherwise it measures nothing and ``add`` is not
+    called: the stage's counter is the busy seconds of the requests that
+    were timed, to be read beside how many those were."""
+    parent = getattr(_ctx, "span", None)
+    if parent is None or not parent.sampled:
+        ann = _trace_annotation or _annotation_class()
+        if ann is None or not ann.is_enabled():
+            return _UNSTAGED
+    return stage(name, add, key, n, nbytes)
 
 
 class Recorder:

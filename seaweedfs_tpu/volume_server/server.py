@@ -43,7 +43,7 @@ from ..storage.erasure_coding import TOTAL_SHARDS_COUNT, to_ext
 from ..storage.erasure_coding import codes as ec_codes
 from ..storage.erasure_coding import decoder as ec_decoder
 from ..storage.erasure_coding.encoder import load_volume_info
-from ..storage.erasure_coding.ec_volume import (EcDeletedError,
+from ..storage.erasure_coding.ec_volume import (READ_STATS, EcDeletedError,
                                                 EcNotFoundError,
                                                 rebuild_ecx_file)
 from ..storage import volume_backup
@@ -609,7 +609,9 @@ class VolumeServer:
 
     def _h_metrics(self, req: Request):
         """Prometheus exposition, with the native engine's off-GIL
-        request counters folded in at scrape time."""
+        request counters and the sealed read's per-GET counters folded
+        in at scrape time."""
+        READ_STATS.export()
         if getattr(self, "_native_owner", False):
             from ..storage import native_engine
 
@@ -707,6 +709,7 @@ class VolumeServer:
         s.add("POST", "/admin/ec/to_volume", g(self._h_ec_to_volume))
         s.add("POST", "/admin/ec/scrub", g(self._h_ec_scrub))
         s.add("GET", "/admin/ec/recover_stats", g(self._h_ec_recover_stats))
+        s.add("GET", "/admin/ec/read_stats", g(self._h_ec_read_stats))
         s.add("GET", "/admin/ec/codes", g(self._h_ec_codes))
         s.add("GET", "/admin/ec/inline_status", g(self._h_ec_inline_status))
         s.add("GET", "/admin/ec/shard_file", self._h_ec_shard_file)
@@ -1877,6 +1880,12 @@ class VolumeServer:
         # it in this process (None: no backend, or a prefork worker)
         out["device"] = platform_util.device_info()
         return out
+
+    def _h_ec_read_stats(self, req: Request):
+        """Sealed-read telemetry: the process-wide needle, interval and
+        byte counts (plain against recovered) and the `ec.read.*` stage
+        seconds (same numbers the Prometheus ec_read_* vectors export)."""
+        return READ_STATS.snapshot()
 
     def _h_ec_shard_file(self, req: Request):
         vid = int(req.param("volume", "0"))

@@ -23,7 +23,8 @@ from seaweedfs_tpu.stats import metrics as stats
 from seaweedfs_tpu.storage.erasure_coding import TOTAL_SHARDS_COUNT
 from seaweedfs_tpu.storage.erasure_coding import encoder as enc
 from seaweedfs_tpu.storage.erasure_coding import recover as recover_mod
-from seaweedfs_tpu.storage.erasure_coding.ec_volume import (EcVolume,
+from seaweedfs_tpu.storage.erasure_coding.ec_volume import (READ_STATS,
+                                                            EcVolume,
                                                             EcVolumeShard)
 from seaweedfs_tpu.storage.needle import Needle
 from seaweedfs_tpu.storage.needle_map import load_needle_map_from_idx
@@ -42,6 +43,8 @@ RECOVER_SPANS = {"ec.recover.fetch", "ec.recover.decode.queue",
                  "ec.recover.decode", "ec.recover.decode.stack",
                  "ec.recover.decode.h2d", "ec.recover.decode.apply",
                  "ec.recover.serve"}
+# the sealed read's own (PR 36): a degraded read is a sealed read first
+READ_SPANS = {"ec.read.locate", "ec.read.shard", "ec.read.assemble"}
 REBUILD_LOST = (0, 3, 11, 13)
 NEW_ENCODE_KEYS = ("read_dat", "read_data_write", "read_slot_wait", "h2d",
                    "d2h_wait", "crc_host", "read_worker_busy")
@@ -129,6 +132,7 @@ def paths(tmp_path_factory):
         reads = _read_all(ev, base)
         ev.close()
         after = recover_mod.STATS.snapshot()
+        read_stats = READ_STATS.snapshot()
         stacks_after = _stack_counts()
         for sid in REBUILD_LOST:
             os.remove(base + enc.to_ext(sid))
@@ -138,7 +142,7 @@ def paths(tmp_path_factory):
         mp.undo()
     assert reads > 50
     return {"dir": d, "base": base, "stage_stats": stage_stats,
-            "rebuild_stats": rebuild_stats,
+            "rebuild_stats": rebuild_stats, "read_stats": read_stats,
             "recover_before": before, "recover_after": after,
             "stacks_before": stacks_before, "stacks_after": stacks_after}
 
@@ -163,13 +167,21 @@ def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch):
     reader = spec["reader"]
     kind = reader["kind"]
     if kind == "harness_record":
-        section, _, key = reader["key"].partition(".")
-        assert section == "stage_stats"
-        # the replies a driver keeps: a seal's or a rebuild's
-        got = paths[{"seal": "stage_stats",
-                     "rebuild": "rebuild_stats"}[reader["record"]]]
-        assert key in got, sorted(got)
-        assert isinstance(got[key], float)
+        # the replies a driver keeps: a seal's or a rebuild's, or the
+        # window's delta of /admin/ec/read_stats
+        section, got = {
+            "seal": ("stage_stats", paths["stage_stats"]),
+            "rebuild": ("stage_stats", paths["rebuild_stats"]),
+            "sealed_read": ("read_stats", paths["read_stats"]),
+        }[reader["record"]]
+        for dotted in (reader["key"], reader["per"]):
+            if dotted == "gib":     # the driver's own, not the program's
+                continue
+            assert dotted.partition(".")[0] == section
+            key = dotted.partition(".")[2]
+            assert key in got, sorted(got)
+            assert isinstance(got[key], float if section == "stage_stats"
+                              or key.endswith("_seconds") else int)
     elif kind == "admin_json":
         assert reader["path"] == "/admin/ec/recover_stats"
         after = paths["recover_after"]
@@ -438,8 +450,8 @@ def test_profiler_session_sees_exactly_the_stage_names(tmp_path,
     finally:
         jax.profiler.stop_trace()
     names = _host_event_names(logdir)
-    assert names == ENCODE_SPANS | RECOVER_SPANS, sorted(
-        names ^ (ENCODE_SPANS | RECOVER_SPANS))
+    assert names == ENCODE_SPANS | RECOVER_SPANS | READ_SPANS, sorted(
+        names ^ (ENCODE_SPANS | RECOVER_SPANS | READ_SPANS))
 
 
 def test_host_pipeline_uses_the_same_span_names(tmp_path):
