@@ -364,6 +364,11 @@ EcReadBytesCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_read_bytes_total",
     "on-disk needle bytes of those intervals, by how each was served",
     ("served",))
+EcReadIndexPreadCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_read_index_preads_total",
+    "preads of the .ecx those needles' lookups made: a mounted volume "
+    "searches a mapping of its index, so any count here is a path that "
+    "still reads the file")
 EcReadStageSeconds = REGISTRY.gauge(
     "SeaweedFS_volumeServer_ec_read_stage_seconds",
     "busy seconds per sealed-read stage, summed over the timed needles "

@@ -2,7 +2,8 @@
 
 Parity with ec_volume.go / ec_shard.go / ec_volume_delete.go / store_ec.go:
   * .ecx binary search over 16-byte sorted entries (SearchNeedleFromSortedIndex,
-    ec_volume.go:230-255)
+    ec_volume.go:230-255); a mounted volume searches a read-only shared
+    mapping of the file, so a lookup makes no system call
   * read ladder per interval: local shard pread, else remote fetch (hook),
     else reconstruct the interval from >=10 other shards
     (readOneEcShardInterval/recoverOneRemoteEcShardInterval,
@@ -15,6 +16,7 @@ Parity with ec_volume.go / ec_shard.go / ec_volume_delete.go / store_ec.go:
 from __future__ import annotations
 
 import hashlib
+import mmap
 import os
 import struct
 import threading
@@ -24,6 +26,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from ...ops import codec as codec_mod
+from .. import idx as idx_mod
 from .. import types as t
 from ..needle import Needle, get_actual_size
 from . import (DATA_SHARDS_COUNT, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE,
@@ -62,8 +65,10 @@ class ReadStats:
     or a remote holder; recovered = through `_recover_span`), and busy
     seconds of the three `ec.read.*` stages (locate = .ecx search +
     interval maths, shard = the plain local `read_at` of one interval,
-    assemble = join + needle parse with its CRC).  The counts are of
-    every needle; the seconds are of the `timed_needles` among them
+    assemble = join + needle parse with its CRC), and `index_preads`,
+    the `.ecx` preads their lookups made (`search_sorted_index` counts
+    its probes; the mounted volume's mapping makes none).  The counts are
+    of every needle; the seconds are of the `timed_needles` among them
     (`tracing.sampled_stage`: a sampled request, or a profiler session),
     so a stage's cost a needle is its seconds over `timed_needles`.
     Updated on every GET of a sealed volume, so an update is one lock
@@ -83,6 +88,7 @@ class ReadStats:
             self.intervals_recovered = 0
             self.bytes_plain = 0
             self.bytes_recovered = 0
+            self.index_preads = 0
 
     def add_stage(self, stage: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -90,12 +96,14 @@ class ReadStats:
             self._seconds[stage] += seconds
 
     def needle(self, intervals: int, nbytes: int, recovered: int,
-               recovered_bytes: int, timed: bool):
+               recovered_bytes: int, timed: bool, index_preads: int = 0):
         """One needle served: `recovered` of its `intervals` (and
         `recovered_bytes` of its `nbytes`) came through a recovery;
-        `timed`: its stages added their seconds."""
+        `timed`: its stages added their seconds; `index_preads`: the
+        preads of the `.ecx` its lookup made."""
         with self._lock:
             self.needles += 1
+            self.index_preads += index_preads
             self.timed_needles += timed
             self.intervals_plain += intervals - recovered
             self.intervals_recovered += recovered
@@ -114,6 +122,7 @@ class ReadStats:
                 "intervals_recovered": self.intervals_recovered,
                 "bytes_plain": self.bytes_plain,
                 "bytes_recovered": self.bytes_recovered,
+                "index_preads": self.index_preads,
             })
         return out
 
@@ -131,6 +140,7 @@ class ReadStats:
                 snap["intervals_" + served])
             stats.EcReadBytesCounter.labels(served).set_cumulative(
                 snap["bytes_" + served])
+        stats.EcReadIndexPreadCounter.set_cumulative(snap["index_preads"])
         for stage in _READ_STAGES:
             stats.EcReadStageSeconds.labels(stage).set(
                 snap[stage + "_seconds"])
@@ -227,25 +237,40 @@ class EcVolumeShard:
 ShardReader = Callable[[int, int, int], Optional[bytes]]
 
 
+# per thread: the `.ecx` preads since its read_needle began
+# (search_sorted_index adds its probes; read_needle hands the sum to
+# ReadStats); the intervals (and their bytes) the current needle
+# recovered; and the fetch+decode busy seconds inside the current
+# recover span, so the serve stage reports assembly/wait overhead, not a
+# double count
+_tls = threading.local()
+
+_KEY = struct.Struct(">Q").unpack_from
+
+
 def search_sorted_index(fileno: int, n_entries: int,
                         needle_id: int) -> Optional[int]:
     """Binary search 16-byte sorted entries by pread; returns entry index
-    (SearchNeedleFromSortedIndex, ec_volume.go:230-255)."""
-    from .. import idx as idx_mod
-
+    (SearchNeedleFromSortedIndex, ec_volume.go:230-255).  For a file
+    nobody has mounted (`rebuild_ecx_file`): a mounted volume searches
+    its mapping (`EcVolume._search_ecx`)."""
     lo, hi = 0, n_entries
-    while lo < hi:
-        mid = (lo + hi) // 2
-        buf = os.pread(fileno, t.NEEDLE_MAP_ENTRY_SIZE,
-                       mid * t.NEEDLE_MAP_ENTRY_SIZE)
-        key, _, _ = idx_mod.unpack_entry(buf)
-        if key == needle_id:
-            return mid
-        if key < needle_id:
-            lo = mid + 1
-        else:
-            hi = mid
-    return None
+    probes = 0
+    try:
+        while lo < hi:
+            mid = (lo + hi) // 2
+            probes += 1
+            key, = _KEY(os.pread(fileno, t.NEEDLE_MAP_ENTRY_SIZE,
+                                 mid * t.NEEDLE_MAP_ENTRY_SIZE))
+            if key == needle_id:
+                return mid
+            if key < needle_id:
+                lo = mid + 1
+            else:
+                hi = mid
+        return None
+    finally:
+        _tls.index_preads = getattr(_tls, "index_preads", 0) + probes
 
 
 class EcVolume:
@@ -289,6 +314,16 @@ class EcVolume:
         base = self.base_file_name()
         self._ecx = open(base + ".ecx", "r+b")
         self.ecx_file_size = os.path.getsize(base + ".ecx")
+        # the index as reads see it: a read-only shared mapping of the
+        # file, so a lookup is loads and no system call (a pread a probe
+        # gave the GIL up 12-13 times a GET), the page cache is the only
+        # memory it takes, and the size field `_mark_ecx_deleted` pwrites
+        # shows in it at once: the mapping is the file.  An empty file
+        # cannot be mapped: no entries, nothing is found.
+        self._ecx_map = (mmap.mmap(self._ecx.fileno(), 0, mmap.MAP_SHARED,
+                                   mmap.PROT_READ)
+                         if self.ecx_file_size >= t.NEEDLE_MAP_ENTRY_SIZE
+                         else None)
         self._ecj = open(base + ".ecj", "a+b")
         self.ecj_file_size = os.path.getsize(base + ".ecj")
 
@@ -330,16 +365,27 @@ class EcVolume:
         return offset, size
 
     def _read_ecx_entry(self, pos: int) -> tuple[int, int, int]:
-        buf = os.pread(self._ecx.fileno(), t.NEEDLE_MAP_ENTRY_SIZE,
-                       pos * t.NEEDLE_MAP_ENTRY_SIZE)
-        from .. import idx as idx_mod
-
-        return idx_mod.unpack_entry(buf)
+        """Entry `pos` -> (needle_id, offset, size), from one 16-byte
+        copy out of the mapping: the size field a delete overwrites is
+        read whole."""
+        at = pos * t.NEEDLE_MAP_ENTRY_SIZE
+        return idx_mod.unpack_entry(
+            self._ecx_map[at:at + t.NEEDLE_MAP_ENTRY_SIZE])
 
     def _search_ecx(self, needle_id: int) -> Optional[int]:
-        return search_sorted_index(
-            self._ecx.fileno(),
-            self.ecx_file_size // t.NEEDLE_MAP_ENTRY_SIZE, needle_id)
+        """Binary search of the mapped index -> the entry's position."""
+        index, key_at, width = self._ecx_map, _KEY, t.NEEDLE_MAP_ENTRY_SIZE
+        lo, hi = 0, self.ecx_file_size // width
+        while lo < hi:
+            mid = (lo + hi) >> 1
+            key, = key_at(index, mid * width)
+            if key < needle_id:
+                lo = mid + 1
+            elif key > needle_id:
+                hi = mid
+            else:
+                return mid
+        return None
 
     # -- needle read (store_ec.go ReadEcShardNeedle:125-163) ------------------
     def locate_needle(self, needle_id: int
@@ -356,11 +402,11 @@ class EcVolume:
 
     def read_needle(self, needle_id: int,
                     cookie: Optional[int] = None) -> Needle:
+        tls = _tls
+        tls.recovered = tls.recovered_bytes = tls.index_preads = 0
         with tracing.sampled_stage("ec.read.locate", READ_STATS.add_stage,
                                    "locate") as timed:
             offset, size, intervals = self.locate_needle(needle_id)
-        tls = self._tls
-        tls.recovered = tls.recovered_bytes = 0
         parts = [self._read_interval(iv) for iv in intervals]
         nbytes = sum(iv.size for iv in intervals)
         with tracing.sampled_stage("ec.read.assemble", READ_STATS.add_stage,
@@ -369,7 +415,8 @@ class EcVolume:
             n = Needle()
             n.read_bytes(blob, offset, size, self.version)
         READ_STATS.needle(len(parts), nbytes, tls.recovered,
-                          tls.recovered_bytes, timed is not None)
+                          tls.recovered_bytes, timed is not None,
+                          tls.index_preads)
         if cookie is not None and n.cookie != cookie:
             raise EcError(f"cookie mismatch for needle {needle_id:x}")
         return n
@@ -444,7 +491,7 @@ class EcVolume:
         mat-vec (recover.py).  With no local shard to size blocks
         against (shard_size unknown) the exact span becomes the unit —
         still coalesced and cached."""
-        self._tls.busy = 0.0
+        _tls.busy = 0.0
         with tracing.stage("ec.recover.serve", self._add_serve, "serve",
                            target_shard, size):
             cache_bytes, block, coalesce = recover_knobs()
@@ -479,22 +526,17 @@ class EcVolume:
                     f"recovered span short for shard {target_shard} at "
                     f"{offset}+{size}: got {len(out)}")
         # read_needle's count of the intervals it served through here
-        tls = self._tls
+        tls = _tls
         tls.recovered = getattr(tls, "recovered", 0) + 1
         tls.recovered_bytes = getattr(tls, "recovered_bytes", 0) + size
         return out
-
-    # per-thread fetch+decode busy seconds inside the current span, so
-    # the serve stage reports assembly/wait overhead, not a double count;
-    # and the intervals (and their bytes) the current needle recovered
-    _tls = threading.local()
 
     def _add_serve(self, stage: str, seconds: float):
         """ec.recover.serve's accumulator: the span measured the whole
         degraded read; the serve stage is that wall minus this thread's
         fetch+decode busy seconds."""
         RECOVER_STATS.add_stage(
-            stage, max(0.0, seconds - getattr(self._tls, "busy", 0.0)))
+            stage, max(0.0, seconds - getattr(_tls, "busy", 0.0)))
 
     def _recover_block(self, target_shard: int, offset: int,
                        size: int) -> bytes:
@@ -512,8 +554,8 @@ class EcVolume:
                 survivors, target_shard, inputs)
             return np.ascontiguousarray(out).tobytes()
         finally:
-            self._tls.busy = (getattr(self._tls, "busy", 0.0)
-                              + (time.perf_counter() - blk0))
+            _tls.busy = (getattr(_tls, "busy", 0.0)
+                         + (time.perf_counter() - blk0))
 
     def _fetch_survivors(self, target_shard: int, offset: int,
                          size: int) -> tuple[tuple, np.ndarray]:
@@ -661,6 +703,9 @@ class EcVolume:
             shard.close()
         self.shards.clear()
         self._recover_cache.clear()
+        if self._ecx_map is not None:
+            self._ecx_map.close()
+            self._ecx_map = None
         if self._ecx:
             self._ecx.close()
             self._ecx = None

@@ -41,7 +41,8 @@ SIZES = [4 << 20] * 3 + [32 << 10] * 40     # 13.25 MiB: two 10 MiB rows
 LOOKUPS = ("cache_hits", "cache_misses", "coalesced")
 READ_SPANS = ("ec.read.locate", "ec.read.shard", "ec.read.assemble")
 COUNTERS = ("needles", "timed_needles", "intervals", "intervals_plain",
-            "intervals_recovered", "bytes_plain", "bytes_recovered")
+            "intervals_recovered", "bytes_plain", "bytes_recovered",
+            "index_preads")
 SECONDS = ("locate_seconds", "shard_seconds", "assemble_seconds")
 
 
@@ -115,6 +116,7 @@ def test_reads_counters_lookups_and_reconstruction_against_the_reference(
         intervals = sum(len(p["intervals"]) for p in plans.values())
         recovered = [iv for p in plans.values() for iv in p["recovered"]]
         assert read["needles"] == len(sealed["bodies"])
+        assert read["index_preads"] == 0      # the index is a mapping
         assert read["intervals_plain"] + read["intervals_recovered"] \
             == read["intervals"] == intervals
         assert read["intervals_recovered"] == len(recovered)
@@ -299,7 +301,8 @@ def test_a_get_nobody_samples_is_counted_and_not_timed(sealed, monkeypatch,
                  "intervals_recovered": 1,
                  "bytes_plain": sum(n for _, _, n in plan["intervals"])
                  - plan["recovered"][0][2],
-                 "bytes_recovered": plan["recovered"][0][2]}
+                 "bytes_recovered": plan["recovered"][0][2],
+                 "index_preads": 0}
 
 
 def test_sampled_stage_is_a_stage_when_sampled_or_profiled(monkeypatch):
@@ -347,6 +350,98 @@ def test_a_read_that_fails_counts_no_needle(sealed):
     assert after["locate_seconds"] >= before["locate_seconds"]
 
 
+# -- the index: searched in memory, counted where a path reads the file ----------
+
+def _count_ecx_preads(monkeypatch):
+    """The `os.pread` calls made on any descriptor of an `.ecx` file."""
+    calls = []
+    real = os.pread
+
+    def counting(fileno, *a):
+        if os.readlink(f"/proc/self/fd/{fileno}").endswith(".ecx"):
+            calls.append(a)
+        return real(fileno, *a)
+
+    monkeypatch.setattr(os, "pread", counting)
+    return calls
+
+
+def test_index_preads_counts_the_preads_of_a_lookup_sent_to_the_file(
+        sealed, monkeypatch):
+    """The counter counts: a `_search_ecx` that goes to the file as
+    `rebuild_ecx_file`'s search does costs a needle its probes, the
+    number of `os.pread` calls the `.ecx`'s descriptor saw; the search
+    that is there costs none."""
+    from seaweedfs_tpu.storage.erasure_coding import ec_volume as ecv
+
+    ev = _mount(sealed, None)
+    calls = _count_ecx_preads(monkeypatch)
+    nids = list(sealed["bodies"])[:7]
+    try:
+        before = READ_STATS.snapshot()
+        for nid in nids:
+            ev.read_needle(nid)
+        assert calls == []
+        assert _delta(before, READ_STATS.snapshot(),
+                      ("needles", "index_preads")) == {
+            "needles": len(nids), "index_preads": 0}
+
+        monkeypatch.setattr(
+            EcVolume, "_search_ecx",
+            lambda self, nid: ecv.search_sorted_index(
+                self._ecx.fileno(), self.ecx_file_size // 16, nid))
+        before = READ_STATS.snapshot()
+        for nid in nids:
+            ev.read_needle(nid)
+        d = _delta(before, READ_STATS.snapshot(), ("needles", "index_preads"))
+        assert d == {"needles": len(nids), "index_preads": len(calls)}
+        # 43 entries: at most six probes a lookup, and never none
+        assert len(nids) <= len(calls) <= 6 * len(nids)
+        # a search outside a read leaks into no needle's count
+        ecv.search_sorted_index(ev._ecx.fileno(), ev.ecx_file_size // 16, 1)
+        monkeypatch.undo()
+        before = READ_STATS.snapshot()
+        ev.read_needle(nids[0])
+        assert READ_STATS.snapshot()["index_preads"] \
+            == before["index_preads"]
+    finally:
+        ev.close()
+
+
+def test_deep_scrub_s_needle_walk_skips_a_tombstone(sealed, tmp_path,
+                                                    monkeypatch):
+    """The curator's walk over every `.ecx` entry of a sealed volume
+    (`deep_scrub_host`): a deleted needle is not re-read, every other
+    one is, and the walk preads the `.ecx` for none of them."""
+    import shutil
+
+    from seaweedfs_tpu.maintenance.deep_scrub import deep_scrub_host
+    from seaweedfs_tpu.storage.tools import shard_file_crc32c
+
+    for name in os.listdir(sealed["dir"]):
+        if not name.endswith((".dat", ".idx")):
+            shutil.copy(os.path.join(sealed["dir"], name), tmp_path)
+    base = str(tmp_path / str(VID))
+    enc.save_volume_info(base, version=3, extra={"shard_crc32c": [
+        shard_file_crc32c(base + reference.shard_ext(sid))
+        for sid in range(reference.TOTAL_SHARDS)]})
+    ev = EcVolume(str(tmp_path), "", VID)
+    gone = sorted(sealed["bodies"])[len(sealed["bodies"]) // 2]
+    ev.delete_needle(gone)
+    ev.close()
+    ecx_reads = _count_ecx_preads(monkeypatch)
+    before = READ_STATS.snapshot()
+    report = deep_scrub_host(str(tmp_path), "", VID)
+    monkeypatch.undo()
+    live = len(sealed["bodies"]) - 1
+    assert report["ok"] and report["needles_bad"] == 0
+    assert report["needles_checked"] == live
+    assert _delta(before, READ_STATS.snapshot(),
+                  ("needles", "index_preads")) == {
+        "needles": live, "index_preads": 0}
+    assert ecx_reads == []
+
+
 # -- the counters' own arithmetic, the route and the families -------------------
 
 def test_read_stats_snapshot_and_reset():
@@ -355,7 +450,7 @@ def test_read_stats_snapshot_and_reset():
                             **dict.fromkeys(COUNTERS, 0)}
     s.add_stage("locate", 0.0000123)
     s.add_stage("locate", 0.0004)
-    s.needle(5, 4194336, 1, 1000, True)
+    s.needle(5, 4194336, 1, 1000, True, 13)
     s.needle(1, 32800, 0, 0, False)
     snap = s.snapshot()
     assert list(snap) == list(SECONDS) + list(COUNTERS)
@@ -365,6 +460,7 @@ def test_read_stats_snapshot_and_reset():
     assert snap["intervals_plain"] == 5 and snap["intervals_recovered"] == 1
     assert snap["bytes_plain"] == 4194336 - 1000 + 32800
     assert snap["bytes_recovered"] == 1000
+    assert snap["index_preads"] == 13     # an addend, 0 unless given
     with pytest.raises(KeyError):
         s.add_stage("no_such_stage", 1.0)
     s.reset()
@@ -381,7 +477,7 @@ def test_read_stats_loses_no_update_under_many_threads():
 
     def work():
         for _ in range(rounds):
-            s.needle(5, 1000, 1, 100, True)
+            s.needle(5, 1000, 1, 100, True, 12)
             s.add_stage("shard", 0.5)
 
     interval = sys.getswitchinterval()
@@ -401,7 +497,7 @@ def test_read_stats_loses_no_update_under_many_threads():
         "assemble_seconds": 0.0, "needles": total, "timed_needles": total,
         "intervals": 5 * total, "intervals_plain": 4 * total,
         "intervals_recovered": total, "bytes_plain": 900 * total,
-        "bytes_recovered": 100 * total}
+        "bytes_recovered": 100 * total, "index_preads": 12 * total}
 
 
 def _family(text, family):
@@ -424,7 +520,8 @@ def test_prometheus_families_move_with_the_counters(sealed):
     finally:
         ev.close()
     # a GET touches no ec_read vector: they move at a scrape
-    for family in ("needles_total", "intervals_total", "bytes_total"):
+    for family in ("needles_total", "intervals_total", "bytes_total",
+                   "index_preads_total"):
         assert _family(stats.REGISTRY.expose(), fam + family) \
             == _family(text0, fam + family)
     text1, snap1 = scrape()
@@ -443,12 +540,13 @@ def test_prometheus_families_move_with_the_counters(sealed):
         assert moved(fam + "intervals_total", labels) \
             == d["intervals_" + served]
         assert moved(fam + "bytes_total", labels) == d["bytes_" + served]
+    assert moved(fam + "index_preads_total") == d["index_preads"] == 0
     for stage, key in zip(("locate", "shard", "assemble"), SECONDS):
         # the gauge is the process's cumulative seconds, as the route's
         assert _family(text1, fam + "stage_seconds")[
             f'{{stage="{stage}"}}'] == pytest.approx(snap1[key], abs=2e-6)
     for family in ("needles_total", "intervals_total", "bytes_total",
-                   "stage_seconds"):
+                   "index_preads_total", "stage_seconds"):
         assert f"# TYPE {fam}{family} " in text1
 
 
@@ -492,6 +590,7 @@ def test_admin_route_over_a_served_volume_with_shard_0_deleted(
                                            sealed["dat_size"], [0])
                  for offset, length in sealed["extents"].values()]
         assert d["needles"] == d["timed_needles"] == len(plans)
+        assert d["index_preads"] == 0
         assert d["intervals"] == sum(len(p["intervals"]) for p in plans)
         assert d["intervals_recovered"] == sum(
             len(p["recovered"]) for p in plans) > 0
@@ -504,7 +603,7 @@ def test_admin_route_over_a_served_volume_with_shard_0_deleted(
         assert not set(recover) & set(COUNTERS)
         text = call(vs.address, "/metrics", parse=False).decode()
         for family in ("needles_total", "intervals_total", "bytes_total",
-                       "stage_seconds"):
+                       "index_preads_total", "stage_seconds"):
             assert f"SeaweedFS_volumeServer_ec_read_{family}" in text
         with pytest.raises(RpcError):    # a POST is no such route
             call(vs.address, "/admin/ec/read_stats", payload={})
