@@ -350,9 +350,10 @@ EcRecoverDeviceCounter = REGISTRY.counter(
 # needle EcVolume.read_needle served, plain or through a recovery
 EcReadNeedleCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_read_needles_total",
-    "needles served from EC shards (sealed and inline volumes): all, and "
+    "needles served from EC shards (sealed and inline volumes): all, "
     "those whose stages were timed (a sampled request, or a profiler "
-    "session): the needles ec_read_stage_seconds is the sum over",
+    "session): the needles ec_read_stage_seconds is the sum over, and "
+    "those served beside_job (while ec_bulk_jobs_in_flight was not 0)",
     ("needles",))
 EcReadIntervalCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_ec_read_intervals_total",
@@ -374,6 +375,16 @@ EcReadStageSeconds = REGISTRY.gauge(
     "busy seconds per sealed-read stage, summed over the timed needles "
     "(locate = .ecx search + interval maths, shard = plain local shard "
     "reads, assemble = join + needle parse with its CRC)", ("stage",))
+EcReadLocalFallbackCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_ec_read_local_fallbacks_total",
+    "intervals whose mounted local shard failed or read short after the "
+    "lookup (unmounted or deleted beside the read, an I/O error, a "
+    "truncated file) and were served by the remote hook or by "
+    "reconstruction instead")
+EcBulkJobsInFlight = REGISTRY.gauge(
+    "SeaweedFS_volumeServer_ec_bulk_jobs_in_flight",
+    "EC bulk jobs (generate, rebuild) running in this volume server at "
+    "the scrape")
 # inline write-path EC (storage/erasure_coding/inline.py): needles
 # stream straight into striped shard logs, parity commits per stripe
 EcInlineStripesCommitted = REGISTRY.counter(

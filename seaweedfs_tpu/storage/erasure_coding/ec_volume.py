@@ -33,8 +33,8 @@ from . import (DATA_SHARDS_COUNT, LARGE_BLOCK_SIZE, SMALL_BLOCK_SIZE,
                TOTAL_SHARDS_COUNT, to_ext)
 from ... import tracing
 from .locate import Interval, locate_data
-from .recover import (STATS as RECOVER_STATS, RecoveredBlockCache,
-                      SpanDecodeBatcher, recover_knobs)
+from .recover import (BULK_JOBS, STATS as RECOVER_STATS,
+                      RecoveredBlockCache, SpanDecodeBatcher, recover_knobs)
 
 _recover_pool_lock = threading.Lock()
 _recover_pool_inst = None
@@ -67,7 +67,15 @@ class ReadStats:
     interval maths, shard = the plain local `read_at` of one interval,
     assemble = join + needle parse with its CRC), and `index_preads`,
     the `.ecx` preads their lookups made (`search_sorted_index` counts
-    its probes; the mounted volume's mapping makes none).  The counts are
+    its probes; the mounted volume's mapping makes none).
+    `needles_beside_job` are the needles served while an EC bulk job
+    (`generate`, `rebuild`) was in flight in this process; a
+    `local_fallbacks` is an interval whose mounted local shard failed or
+    read short after the lookup (unmounted or deleted beside the read, an
+    I/O error, a truncated file) and which went on down the ladder to the
+    remote hook and to reconstruction (the span `ec.read.local_fallback`
+    is the rest of such an interval's ladder; it has no seconds here:
+    `ec.recover.*` count them).  The counts are
     of every needle; the seconds are of the `timed_needles` among them
     (`tracing.sampled_stage`: a sampled request, or a profiler session),
     so a stage's cost a needle is its seconds over `timed_needles`.
@@ -89,6 +97,8 @@ class ReadStats:
             self.bytes_plain = 0
             self.bytes_recovered = 0
             self.index_preads = 0
+            self.needles_beside_job = 0
+            self.local_fallbacks = 0
 
     def add_stage(self, stage: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -109,6 +119,12 @@ class ReadStats:
             self.intervals_recovered += recovered
             self.bytes_plain += nbytes - recovered_bytes
             self.bytes_recovered += recovered_bytes
+            if BULK_JOBS.in_flight:
+                self.needles_beside_job += 1
+
+    def local_fallback(self):
+        with self._lock:
+            self.local_fallbacks += 1
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -123,6 +139,8 @@ class ReadStats:
                 "bytes_plain": self.bytes_plain,
                 "bytes_recovered": self.bytes_recovered,
                 "index_preads": self.index_preads,
+                "needles_beside_job": self.needles_beside_job,
+                "local_fallbacks": self.local_fallbacks,
             })
         return out
 
@@ -135,6 +153,10 @@ class ReadStats:
             snap["needles"])
         stats.EcReadNeedleCounter.labels("timed").set_cumulative(
             snap["timed_needles"])
+        stats.EcReadNeedleCounter.labels("beside_job").set_cumulative(
+            snap["needles_beside_job"])
+        stats.EcReadLocalFallbackCounter.set_cumulative(
+            snap["local_fallbacks"])
         for served in ("plain", "recovered"):
             stats.EcReadIntervalCounter.labels(served).set_cumulative(
                 snap["intervals_" + served])
@@ -147,6 +169,10 @@ class ReadStats:
 
 
 READ_STATS = ReadStats()
+
+
+def _no_counter(key: str, seconds: float):
+    """The accumulator of a stage that is a span and no counter."""
 
 
 class EcError(Exception):
@@ -200,6 +226,20 @@ class ShardBits:
         return f"ShardBits({self.shard_ids()})"
 
 
+class _ShardFile:
+    """An open shard file's descriptor, closed when its last holder lets
+    go of it: the shard that opened it, or a read that took it before the
+    shard was closed."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self, fd: int):
+        self.fd = fd
+
+    def __del__(self):
+        os.close(self.fd)
+
+
 class EcVolumeShard:
     """One open .ecNN file (ec_shard.go:17-97)."""
 
@@ -209,7 +249,8 @@ class EcVolumeShard:
         self.collection = collection
         self.volume_id = vid
         self.shard_id = shard_id
-        self._f = open(self.file_name(), "rb")
+        self._f: Optional[_ShardFile] = _ShardFile(
+            os.open(self.file_name(), os.O_RDONLY))
         self.ecd_file_size = os.path.getsize(self.file_name())
 
     def base_file_name(self) -> str:
@@ -221,12 +262,18 @@ class EcVolumeShard:
         return self.base_file_name() + to_ext(self.shard_id)
 
     def read_at(self, size: int, offset: int) -> bytes:
-        return os.pread(self._f.fileno(), size, offset)
+        """Reads hold no lock against `close()` (an unmount, a
+        `delete_shards` beside them), they hold the file: a read that
+        took it before the close finishes on a descriptor that is still
+        this file's, and one that comes after raises, as upstream's
+        `ReadAt` of a closed file does."""
+        f = self._f
+        if f is None:
+            raise EcError(f"shard {self.volume_id}.{self.shard_id} is closed")
+        return os.pread(f.fd, size, offset)
 
     def close(self):
-        if self._f:
-            self._f.close()
-            self._f = None
+        self._f = None
 
     def destroy(self):
         self.close()
@@ -350,9 +397,13 @@ class EcVolume:
 
     @property
     def shard_size(self) -> int:
-        if not self.shards:
-            return 0
-        return next(iter(self.shards.values())).ecd_file_size
+        while True:
+            try:
+                return next(iter(self.shards.values())).ecd_file_size
+            except StopIteration:
+                return 0
+            except RuntimeError:
+                continue    # a mount or unmount changed the dict: again
 
     # -- sorted-index search -------------------------------------------------
     def find_needle_from_ecx(self, needle_id: int) -> tuple[int, int]:
@@ -429,32 +480,53 @@ class EcVolume:
 
     def read_shard_span(self, shard_id: int, offset: int, size: int) -> bytes:
         """Read ladder: local shard -> in-memory tail stripe (inline
-        volumes) -> remote hook -> reconstruct."""
+        volumes) -> remote hook -> reconstruct.  A mounted local shard
+        that cannot give the span goes on down the ladder, counted
+        (readOneEcShardInterval, store_ec.go:188-218: any local error
+        goes on to the remote read and to recovery): closed beside this
+        read (the lookup holds no lock against an unmount), an I/O
+        error, or a sealed shard file that ends early."""
         shard = self.shards.get(shard_id)
-        if shard is not None:
+        if shard is None:
+            return self._read_elsewhere(shard_id, offset, size)
+        try:
             with tracing.sampled_stage("ec.read.shard",
                                        READ_STATS.add_stage, "shard",
                                        shard_id, size):
                 data = shard.read_at(size, offset)
+        except (OSError, EcError):
+            pass
+        else:
             if len(data) == size:
                 return data
             if self.tail_reader is not None:
-                # the span runs past the shard log's durable extent:
-                # the remainder lives in the partially-filled tail
-                # stripe (data still buffered, or parity not yet
-                # committed for the current row)
-                rest = self.tail_reader(shard_id, offset + len(data),
-                                        size - len(data))
-                if rest is None:
-                    # the flusher committed the row between the pread
-                    # and the tail lookup — the bytes are on disk now
-                    data = shard.read_at(size, offset)
-                    if len(data) == size:
-                        return data
-                else:
-                    return data + rest
-            raise EcError(
-                f"short read shard {shard_id} at {offset}+{size}")
+                return self._read_past_the_log(shard, data, offset, size)
+        READ_STATS.local_fallback()
+        with tracing.sampled_stage("ec.read.local_fallback", _no_counter,
+                                   "local_fallback", shard_id, size):
+            return self._read_elsewhere(shard_id, offset, size)
+
+    def _read_past_the_log(self, shard: EcVolumeShard, data: bytes,
+                           offset: int, size: int) -> bytes:
+        """Inline volumes: the span runs past the shard log's durable
+        extent, and the remainder lives in the partially-filled tail
+        stripe (data still buffered, or parity not yet committed for
+        the current row)."""
+        shard_id = shard.shard_id
+        rest = self.tail_reader(shard_id, offset + len(data),
+                                size - len(data))
+        if rest is not None:
+            return data + rest
+        # the flusher committed the row between the pread and the tail
+        # lookup — the bytes are on disk now
+        data = shard.read_at(size, offset)
+        if len(data) == size:
+            return data
+        raise EcError(f"short read shard {shard_id} at {offset}+{size}")
+
+    def _read_elsewhere(self, shard_id: int, offset: int,
+                        size: int) -> bytes:
+        """The ladder below the local shard."""
         if self.tail_reader is not None:
             data = self.tail_reader(shard_id, offset, size)
             if data is not None:
@@ -582,7 +654,10 @@ class EcVolume:
             if shard is not None:
                 if len(shards) >= k:
                     continue  # reconstruct needs exactly k survivors
-                data = shard.read_at(size, offset)
+                try:
+                    data = shard.read_at(size, offset)
+                except (OSError, EcError):
+                    continue  # closed beside this read: not a survivor
                 if len(data) != size and self.tail_reader is not None:
                     # inline volume: the span runs past the shard log's
                     # durable extent.  The tail stripe serves pending

@@ -29,6 +29,7 @@ Knobs (env, read per call so daemons/tests flip them live):
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -52,6 +53,30 @@ def recover_knobs() -> tuple[int, int, bool]:
     return cache_bytes, block_bytes, coalesce
 
 
+class BulkJobs:
+    """EC bulk jobs (`generate`, `rebuild`) in flight in this process:
+    one integer, raised by the volume server around each job.  The read
+    paths only compare it with 0 (`ReadStats.needle`, `RecoverStats`),
+    so that their counters can say what was served beside a job."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.in_flight = 0
+
+    @contextlib.contextmanager
+    def job(self):
+        with self._lock:
+            self.in_flight += 1
+        try:
+            yield
+        finally:
+            with self._lock:
+                self.in_flight -= 1
+
+
+BULK_JOBS = BulkJobs()
+
+
 # Stage keys of RecoverStats.add_stage, in reply order.  The first three
 # are the stages PR 21 had and keep their 3-decimal reply; the decode_*
 # ones split the decode stage: queue = a request waiting in the batcher
@@ -67,7 +92,10 @@ class RecoverStats:
     per stage (fetch = survivor reads, decode = GF math, serve = span
     assembly/cache bookkeeping around them; decode_* see _DECODE_STAGES)
     plus cache and coalescing counters; mirrored into the Prometheus
-    vectors on every update."""
+    vectors on every update.  The `*_beside_job` counters are the decode
+    batches, their blocks and their `decode_apply` seconds that ended
+    while an EC bulk job was in flight (`BULK_JOBS`): what a seal or a
+    rebuild on the same device costs a recovery, a batch at a time."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -83,6 +111,9 @@ class RecoverStats:
             self.batches = 0
             self.batched_spans = 0
             self.recovered_bytes = 0
+            self.batches_beside_job = 0
+            self.spans_beside_job = 0
+            self._apply_beside_job = 0.0
 
     def add_stage(self, stage: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -90,6 +121,8 @@ class RecoverStats:
 
         with self._lock:
             val = self._seconds[stage] = self._seconds[stage] + seconds
+            if stage == "decode_apply" and BULK_JOBS.in_flight:
+                self._apply_beside_job += seconds
         stats.EcRecoverStageSeconds.labels(stage).set(round(val, 6))
 
     def cache_event(self, result: str, n: int = 1):
@@ -113,6 +146,9 @@ class RecoverStats:
             if n_spans > 1:
                 self.batched_spans += n_spans
             self.recovered_bytes += nbytes
+            if BULK_JOBS.in_flight:
+                self.batches_beside_job += 1
+                self.spans_beside_job += n_spans
         stats.EcRecoverSpanCounter.labels(
             "batched" if n_spans > 1 else "solo").inc(n_spans)
         stats.EcRecoverDecodeStackCounter.labels(str(n_spans)).inc()
@@ -141,6 +177,10 @@ class RecoverStats:
                 # names the per-stack counter on /metrics sums to)
                 "decode_batches": self.batches,
                 "decode_blocks": self.spans,
+                "decode_batches_beside_job": self.batches_beside_job,
+                "decode_blocks_beside_job": self.spans_beside_job,
+                "decode_apply_seconds_beside_job":
+                    round(self._apply_beside_job, 6),
             })
         lookups = out["cache_hits"] + out["cache_misses"]
         out["cache_hit_ratio"] = (
@@ -182,7 +222,9 @@ class RecoveredBlockCache:
     single-flight miss coalescing.  Keys are (shard_id, offset, length);
     entries are the recovered bytes — immutable content (EC shard files
     never change after encode), so there is no invalidation story beyond
-    eviction."""
+    eviction.  `lookups` counts this cache's own (hit, miss or coalesced:
+    `stats` is process-wide, a cache is one volume's), under the lock a
+    lookup takes anyway."""
 
     def __init__(self, stats: RecoverStats = STATS):
         self._data: "OrderedDict[tuple, bytes]" = OrderedDict()
@@ -190,6 +232,7 @@ class RecoveredBlockCache:
         self._lock = threading.Lock()
         self._flights: dict[tuple, _Flight] = {}
         self.stats = stats
+        self.lookups = 0
 
     @property
     def size_bytes(self) -> int:
@@ -203,9 +246,12 @@ class RecoveredBlockCache:
             self._data.clear()
             self._bytes = 0
 
-    def _get(self, key: tuple) -> Optional[bytes]:
+    def _get(self, key: tuple, capacity: int) -> Optional[bytes]:
+        """One lookup, counted: the block, where the LRU is on and holds
+        it."""
         with self._lock:
-            data = self._data.get(key)
+            self.lookups += 1
+            data = self._data.get(key) if capacity > 0 else None
             if data is not None:
                 self._data.move_to_end(key)
             return data
@@ -230,11 +276,10 @@ class RecoveredBlockCache:
         dead block cost ONE survivor fan-out and ONE decode; the 15
         followers block on the leader's flight.  A leader failure wakes
         the followers with the error and caches nothing."""
-        if capacity > 0:
-            data = self._get(key)
-            if data is not None:
-                self.stats.cache_event("hit")
-                return data
+        data = self._get(key, capacity)
+        if data is not None:
+            self.stats.cache_event("hit")
+            return data
         if not coalesce:
             self.stats.cache_event("miss")
             data = recover()

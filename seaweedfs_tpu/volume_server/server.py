@@ -46,6 +46,7 @@ from ..storage.erasure_coding.encoder import load_volume_info
 from ..storage.erasure_coding.ec_volume import (READ_STATS, EcDeletedError,
                                                 EcNotFoundError,
                                                 rebuild_ecx_file)
+from ..storage.erasure_coding.recover import BULK_JOBS
 from ..storage import volume_backup
 from ..storage.needle import Needle
 from ..storage.store import Store
@@ -612,6 +613,7 @@ class VolumeServer:
         request counters and the sealed read's per-GET counters folded
         in at scrape time."""
         READ_STATS.export()
+        stats.EcBulkJobsInFlight.set(BULK_JOBS.in_flight)
         if getattr(self, "_native_owner", False):
             from ..storage import native_engine
 
@@ -1722,17 +1724,27 @@ class VolumeServer:
     def _h_ec_generate(self, req: Request):
         p = req.json()
         stage_stats: dict = {}
-        self.store.ec_generate(
-            int(p["volume"]), code_family=p.get("code_family") or None,
-            stage_stats=stage_stats)
+        with BULK_JOBS.job():
+            self.store.ec_generate(
+                int(p["volume"]), code_family=p.get("code_family") or None,
+                stage_stats=stage_stats)
         return self._ec_where(stage_stats)
 
     def _h_ec_rebuild(self, req: Request):
         p = req.json()
         vid = int(p["volume"])
         stage_stats: dict = {}
-        rebuilt = self.store.ec_rebuild(vid, p.get("collection", ""),
-                                        stage_stats=stage_stats)
+        # one rebuild of a volume at a time: a second caller (the
+        # maintenance script beside the curator) waits, then finds
+        # nothing missing instead of uploading the survivors again
+        with self._vid_copy_lock(vid), BULK_JOBS.job():
+            served0 = READ_STATS.needles
+            rebuilt = self.store.ec_rebuild(vid, p.get("collection", ""),
+                                            stage_stats=stage_stats)
+            if stage_stats:
+                # the sealed needles this server served meanwhile
+                stage_stats["foreground_reads"] = \
+                    READ_STATS.needles - served0
         self.read_cache.invalidate_volume(vid, reason="rebuild")
         return {"rebuilt_shard_ids": rebuilt,
                 **self._ec_where(stage_stats)}
@@ -1874,6 +1886,7 @@ class VolumeServer:
                 volumes[str(vid)] = {
                     "cache_blocks": len(ev._recover_cache),
                     "cache_bytes": ev._recover_cache.size_bytes,
+                    "lookups": ev._recover_cache.lookups,
                 }
         out["volumes"] = volumes
         # the device a degraded read would dispatch to, as JAX reports
@@ -1915,9 +1928,10 @@ class VolumeServer:
         offset = int(req.param("offset", "0"))
         size = int(req.param("size", "0"))
         ev = self.store.find_ec_volume(vid)
-        if ev is None or shard_id not in ev.shards:
+        shard = ev.shards.get(shard_id) if ev is not None else None
+        if shard is None:
             raise RpcError(f"shard {vid}.{shard_id} not found", 404)
-        return ev.shards[shard_id].read_at(size, offset)
+        return shard.read_at(size, offset)
 
     def _h_ec_codes(self, req: Request):
         """Coding-tier introspection: registered families (geometry,

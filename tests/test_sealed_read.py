@@ -42,7 +42,7 @@ LOOKUPS = ("cache_hits", "cache_misses", "coalesced")
 READ_SPANS = ("ec.read.locate", "ec.read.shard", "ec.read.assemble")
 COUNTERS = ("needles", "timed_needles", "intervals", "intervals_plain",
             "intervals_recovered", "bytes_plain", "bytes_recovered",
-            "index_preads")
+            "index_preads", "needles_beside_job", "local_fallbacks")
 SECONDS = ("locate_seconds", "shard_seconds", "assemble_seconds")
 
 
@@ -302,7 +302,8 @@ def test_a_get_nobody_samples_is_counted_and_not_timed(sealed, monkeypatch,
                  "bytes_plain": sum(n for _, _, n in plan["intervals"])
                  - plan["recovered"][0][2],
                  "bytes_recovered": plan["recovered"][0][2],
-                 "index_preads": 0}
+                 "index_preads": 0, "needles_beside_job": 0,
+                 "local_fallbacks": 0}
 
 
 def test_sampled_stage_is_a_stage_when_sampled_or_profiled(monkeypatch):
@@ -497,7 +498,8 @@ def test_read_stats_loses_no_update_under_many_threads():
         "assemble_seconds": 0.0, "needles": total, "timed_needles": total,
         "intervals": 5 * total, "intervals_plain": 4 * total,
         "intervals_recovered": total, "bytes_plain": 900 * total,
-        "bytes_recovered": 100 * total, "index_preads": 12 * total}
+        "bytes_recovered": 100 * total, "index_preads": 12 * total,
+        "needles_beside_job": 0, "local_fallbacks": 0}
 
 
 def _family(text, family):
