@@ -220,11 +220,14 @@ def reconstruct_span(survivors, inputs: np.ndarray, target: int,
     free.  Dispatch: fused JAX/Pallas kernel for large spans on a TPU,
     native/NumPy host kernel for small ones.
 
-    slab_key: opaque content identity of `inputs` (the caller hashes the
-    survivor stack).  When set, the device upload routes through the EC
-    device slab pool (ops/device_pool.py) keyed by (survivors, content):
-    consecutive decodes against the same survivor spans — a different
-    missing target, or a block re-recovered after LRU eviction — hit the
+    slab_key: any hashable that stands for the content of `inputs`: the
+    caller's name for these bytes (a mounted sealed volume's token and
+    the spans' offsets and lengths: ec_volume.py), never a pass over
+    them.  Two stacks of different bytes must never share one.  When
+    set, the device upload routes through the EC device slab pool
+    (ops/device_pool.py) keyed by (survivors, slab_key): consecutive
+    decodes against the same survivor spans — a different missing
+    target, or a block re-recovered after LRU eviction — hit the
     HBM-resident slab instead of re-uploading over the link.
 
     family: an erasure_coding.codes CodeFamily.  None (or the RS default)

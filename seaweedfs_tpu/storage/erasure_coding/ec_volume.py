@@ -15,7 +15,7 @@ Parity with ec_volume.go / ec_shard.go / ec_volume_delete.go / store_ec.go:
 
 from __future__ import annotations
 
-import hashlib
+import itertools
 import mmap
 import os
 import struct
@@ -292,6 +292,14 @@ ShardReader = Callable[[int, int, int], Optional[bytes]]
 # double count
 _tls = threading.local()
 
+# What a mounted EcVolume's survivor spans are named by on the device
+# (`_recover_block`): one number a mounted volume and a new one whenever
+# its set of shards changes, so a volume closed and mounted again,
+# restored, replaced by another of the same id, or given a rebuilt shard
+# never meets a slab of other bytes.  A counter, never `id(self)`:
+# addresses are reused.
+_SLAB_TOKENS = itertools.count(1)
+
 _KEY = struct.Struct(">Q").unpack_from
 
 
@@ -356,6 +364,7 @@ class EcVolume:
         # + the same-survivor-set span-decode batcher
         self._recover_cache = RecoveredBlockCache()
         self._recover_batcher = SpanDecodeBatcher(self._decode_span)
+        self._slab_token = next(_SLAB_TOKENS)
         self._ecx_lock = threading.Lock()
         self._ecj_lock = threading.Lock()
         base = self.base_file_name()
@@ -384,9 +393,11 @@ class EcVolume:
         if shard.shard_id in self.shards:
             return False
         self.shards[shard.shard_id] = shard
+        self._slab_token = next(_SLAB_TOKENS)
         return True
 
     def delete_shard(self, shard_id: int) -> Optional[EcVolumeShard]:
+        self._slab_token = next(_SLAB_TOKENS)
         return self.shards.pop(shard_id, None)
 
     def shard_bits(self) -> ShardBits:
@@ -617,13 +628,23 @@ class EcVolume:
         the target row through the decode-plan cache and the span-decode
         batcher."""
         blk0 = time.perf_counter()
+        token = self._slab_token
         try:
             with tracing.stage("ec.recover.fetch", RECOVER_STATS.add_stage,
                                "fetch", target_shard, size):
                 survivors, inputs = self._fetch_survivors(
                     target_shard, offset, size)
+            # what names the survivor spans stands for their bytes: a
+            # sealed volume's shard files are never rewritten in place,
+            # and a shard mounted or unmounted beside the fetch changed
+            # the token.  An inline volume's shard bytes can still change
+            # (the same offset reads zeros now and data later): then, as
+            # after a changed token, no identity and a plain upload.
+            ident = ((token, offset, size)
+                     if self.tail_reader is None
+                     and token == self._slab_token else None)
             out = self._recover_batcher.decode(
-                survivors, target_shard, inputs)
+                survivors, target_shard, inputs, ident)
             return np.ascontiguousarray(out).tobytes()
         finally:
             _tls.busy = (getattr(_tls, "busy", 0.0)
@@ -723,13 +744,19 @@ class EcVolume:
         return survivors, np.stack([shards[sid] for sid in survivors])
 
     def _decode_span(self, survivors: tuple, target: int,
-                     inputs: np.ndarray) -> np.ndarray:
+                     inputs: np.ndarray,
+                     idents: Optional[tuple]) -> np.ndarray:
         """The batcher's decode hook: one cached decode row applied to
         the (possibly multi-span) survivor stack.  An explicitly-pinned
         encoder backend decodes through reconstruct_one on that backend
         (RS volumes only — pinned backends speak the RS layout); the
         default rides the size-dispatched reconstruct_span with this
-        volume's code family."""
+        volume's code family.  `idents`, the stack's members as
+        `_recover_block` named them, is the device slab pool's resident
+        key: consecutive decodes of the same survivor spans (another
+        missing shard, or a block re-recovered after cache eviction)
+        reuse the HBM-resident upload instead of re-crossing the link.
+        None: a plain upload."""
         if self._encoder is not None \
                 and self.family.name == "rs_vandermonde":
             shard_list: list[Optional[np.ndarray]] = \
@@ -737,19 +764,10 @@ class EcVolume:
             for i, sid in enumerate(survivors):
                 shard_list[sid] = inputs[i]
             return self._encoder.reconstruct_one(shard_list, target)
-        slab_key = None
-        if (inputs.nbytes >= codec_mod.recover_device_min_bytes()
-                and codec_mod.recover_device_enabled()):
-            # content identity for the device slab pool: consecutive
-            # decodes of the same survivor spans (another missing shard,
-            # or a block re-recovered after cache eviction) reuse the
-            # HBM-resident upload instead of re-crossing the link
-            slab_key = hashlib.blake2b(
-                np.ascontiguousarray(inputs), digest_size=16).digest()
         return codec_mod.reconstruct_span(
             survivors, inputs, target,
             self.family.data_shards, TOTAL_SHARDS_COUNT,
-            slab_key=slab_key, family=self.family,
+            slab_key=idents, family=self.family,
             add_stage=RECOVER_STATS.add_stage)
 
     # -- delete (ec_volume_delete.go) -----------------------------------------

@@ -202,6 +202,11 @@ class RecoverStats:
             k: snap[k] for k in ("resident_slabs", "resident_hits",
                                  "resident_misses", "bytes",
                                  "evictions")}
+        # ... and beside the batches they are a share of: a decode batch
+        # on the device either found its survivor stack resident or
+        # uploaded it
+        out["decode_slab_hits"] = snap["resident_hits"]
+        out["decode_slab_uploads"] = snap["resident_misses"]
         return out
 
 
@@ -330,10 +335,11 @@ class RecoveredBlockCache:
 
 
 class _DecodeReq:
-    __slots__ = ("inputs", "started", "event", "out", "error")
+    __slots__ = ("inputs", "ident", "started", "event", "out", "error")
 
-    def __init__(self, inputs: np.ndarray):
+    def __init__(self, inputs: np.ndarray, ident=None):
         self.inputs = inputs
+        self.ident = ident
         self.started = threading.Event()  # its batch began to decode
         self.event = threading.Event()    # ... and ended
         self.out: Optional[np.ndarray] = None
@@ -347,10 +353,16 @@ class SpanDecodeBatcher:
     drains everything queued for its key, decodes the stacked (d, ΣL)
     input in one call, then splits the output back per request.
     Requests arriving while a decode is in flight queue for the next
-    round (the leader loops until its key's queue is empty)."""
+    round (the leader loops until its key's queue is empty).
 
-    def __init__(self, decode_fn: Callable[[tuple, int, np.ndarray],
-                                           np.ndarray],
+    A request may carry its stack's identity (any hashable that names
+    the bytes: the read knows which volume, offset and length they came
+    from).  The decode hook gets the members' identities in stack order,
+    or None if any member has none: a key for the stacked bytes that
+    nobody has to hash them for."""
+
+    def __init__(self, decode_fn: Callable[
+            [tuple, int, np.ndarray, Optional[tuple]], np.ndarray],
                  stats: RecoverStats = STATS):
         self._decode_fn = decode_fn
         self._lock = threading.Lock()
@@ -359,11 +371,11 @@ class SpanDecodeBatcher:
         self.stats = stats
 
     def decode(self, survivors: tuple, target: int,
-               inputs: np.ndarray) -> np.ndarray:
+               inputs: np.ndarray, ident=None) -> np.ndarray:
         """inputs: (d, L) survivor stack in `survivors` order -> (L,)
-        recovered bytes of `target`."""
+        recovered bytes of `target`; `ident`: the stack's identity."""
         key = (survivors, target)
-        req = _DecodeReq(inputs)
+        req = _DecodeReq(inputs, ident)
         with self._lock:
             self._queues.setdefault(key, []).append(req)
             leader = key not in self._busy
@@ -414,11 +426,15 @@ class SpanDecodeBatcher:
                     else:
                         stacked = np.concatenate(
                             [r.inputs for r in batch], axis=1)
+                idents = tuple(r.ident for r in batch)
+                if None in idents:
+                    idents = None
                 # foreground device lane: while this decode runs, queued
                 # background batches (scrub re-encode, bulk encode) yield
                 # at their next checkpoint
                 with LANES.foreground():
-                    out = self._decode_fn(survivors, target, stacked)
+                    out = self._decode_fn(survivors, target, stacked,
+                                          idents)
                 outs = []
                 col = 0
                 for r in batch:

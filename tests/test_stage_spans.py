@@ -401,7 +401,7 @@ def _queued_decode():
     batcher = None
     entered = threading.Event()
 
-    def slow_decode(survivors, target, stacked):
+    def slow_decode(survivors, target, stacked, idents):
         entered.set()
         deadline = time.monotonic() + 5
         while not batcher._queues and time.monotonic() < deadline:
