@@ -39,6 +39,7 @@ import os
 import sys
 import threading
 import time
+import zlib
 from collections import deque
 from typing import Optional
 
@@ -46,6 +47,12 @@ from . import tracing
 from .stats import metrics as _stats
 
 _TRUNCATED = "(truncated)"
+# What the always-on sampler's GIL gauge checksums each tick: zlib gives
+# the GIL up for anything over 5 KiB, and 256 KiB keep it away for some
+# 70 us, in which a thread that waits for the GIL takes it.  Probes of
+# 1 and 4 MiB read the same within a third in three cells on the TPU
+# hosts and cost four and sixteen times the CPU (PERF.md §6, PR 40).
+_GIL_PROBE = bytes(256 << 10)
 _MAX_DEPTH = 64
 
 
@@ -109,7 +116,21 @@ class StackSampler:
     flamegraph.pl collapsed form.  ``publish=True`` (the always-on
     instance) mirrors per-route sample counts into the Prometheus
     registry.  The sampler measures its own busy time, so its duty
-    cycle (``overhead_ratio``) is observable, not guessed."""
+    cycle (``overhead_ratio``) is observable, not guessed.
+
+    The always-on instance is also the process's GIL gauge: every tick
+    it checksums 256 KiB in C (``zlib.crc32``), which gives the GIL up
+    as a handler's ``pread``, ``send`` or device wait does, for long
+    enough that a thread waiting for the GIL takes it, and records how
+    much longer that took than the least it ever has (``gil_wait`` over
+    its ``floor``): the wait to get the GIL back, which every thread of
+    the process pays after every blocking call.  No timer is in the
+    reading: a timed wait comes back a whole distribution late on an
+    idle host (0.04 to 2.5 ms on the TPU hosts, PERF.md §6, PR 40), a
+    checksum's time has a floor.  One observation a tick of a thread
+    that holds the GIL for microseconds itself; late for the GIL or, on
+    a host short of cores, for a core: a gauge of the process, not a
+    measurement of any one request."""
 
     def __init__(self, hz: Optional[float] = None,
                  publish: bool = False, exclude=()):
@@ -128,6 +149,12 @@ class StackSampler:
         self._lock = threading.Lock()
         self._names: dict[int, str] = {}
         self._ticks = 0
+        # the GIL gauge of the always-on instance: the least a probe
+        # took, and count, sum, max of what each took longer than that
+        self.gil_floor = float("inf")
+        self.gil_count = 0
+        self.gil_sum = 0.0
+        self.gil_max = 0.0
 
     # -- lifecycle ----------------------------------------------------
 
@@ -163,6 +190,18 @@ class StackSampler:
             if self._stop.wait(interval):
                 return
             t0 = time.perf_counter()
+            if self._publish:
+                zlib.crc32(_GIL_PROBE)
+                wait = time.perf_counter() - t0
+                if wait < self.gil_floor:
+                    self.gil_floor = wait
+                wait -= self.gil_floor
+                self.gil_count += 1
+                self.gil_sum += wait
+                if wait > self.gil_max:
+                    self.gil_max = wait
+                _stats.ProfilerGilWaitHistogram.observe(wait)
+                t0 += wait      # waited, not worked: not in `busy`
             try:
                 self._sample_once(me)
             except Exception:
@@ -225,9 +264,17 @@ class StackSampler:
 
     def snapshot(self) -> dict:
         with self._lock:
-            return {"samples": self.total, "stacks": len(self.samples),
-                    "truncated": self.truncated, "errors": self.errors,
-                    "overhead_ratio": round(self.overhead_ratio(), 6)}
+            out = {"samples": self.total, "stacks": len(self.samples),
+                   "truncated": self.truncated, "errors": self.errors,
+                   "overhead_ratio": round(self.overhead_ratio(), 6)}
+        if self._publish:
+            n = self.gil_count
+            out["gil_wait"] = {
+                "floor": round(self.gil_floor, 9) if n else 0.0,
+                "count": n,
+                "mean": round(self.gil_sum / n, 9) if n else 0.0,
+                "max": round(self.gil_max, 9)}
+        return out
 
 
 # -- always-on process profiler ----------------------------------------------
@@ -262,6 +309,11 @@ def overhead_ratio() -> float:
 def stack_count() -> float:
     prof = _PROFILER
     return float(len(prof.samples)) if prof is not None else 0.0
+
+
+def gil_probe_floor() -> float:
+    prof = _PROFILER
+    return prof.gil_floor if prof is not None and prof.gil_count else 0.0
 
 
 def profile_burst(seconds: float, hz: float, exclude=()) -> str:

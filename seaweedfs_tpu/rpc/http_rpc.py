@@ -12,6 +12,7 @@ a threading server.  TPU-side collectives stay inside JAX (parallel/mesh.py)
 from __future__ import annotations
 
 import http.client
+import itertools
 import json
 import os
 import socket
@@ -244,6 +245,187 @@ class _LeanHeaders(dict):
             self._fold(key) is not None
 
 
+# -- a request's stages around its handler -----------------------------------
+
+# Stage keys of RequestStages, in reply order: `request` is the whole of
+# the server's share, read's start -> reply's end.
+_REQUEST_STAGES = ("read", "handle", "reply", "request")
+# Route (and, for a request line nobody could parse, method) label of a
+# request that no route saw.
+UNROUTED = "-"
+_METHODS = frozenset(("GET", "HEAD", "POST", "PUT", "DELETE"))
+
+
+class _StageRow:
+    """The counters of one (service, route, method)."""
+
+    __slots__ = ("requests", "looks", "timed", "seconds")
+
+    def __init__(self):
+        # next() of a count is one C call under the GIL: handlers count
+        # a request without a lock.  Reading it takes a number too, so
+        # snapshot() keeps how often it looked.
+        self.requests = itertools.count()
+        self.looks = 0
+        self.timed = 0
+        self.seconds = [0.0] * len(_REQUEST_STAGES)
+
+
+class RequestStages:
+    """The process's requests and where their time went outside and
+    inside the handler, by service, matched route prefix and method (all
+    bounded), so that an `/admin/ec/rebuild` of 0.6 s or a `/metrics`
+    scrape never enters an object GET's mean.  Process-global, as
+    `READ_STATS` and `stats.REGISTRY` are: daemons of one service name
+    in one process (tests) count into one row.  `requests` counts every
+    request that was read; the seconds are of the `timed_requests` among
+    them (a sampled request, or a profiler session:
+    `tracing.sampled_stage`'s rule, because this runs on every request
+    of every daemon), so a stage's cost a request is its seconds over
+    `timed_requests`.  An untimed request costs one dict lookup and one
+    `next()`; a timed one takes the lock once.  The Prometheus
+    `rpc_server_*` vectors are brought up to it at scrape (`export`), as
+    `ReadStats`' are."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._rows: dict[tuple[str, str, str], _StageRow] = {}
+
+    def row(self, service: str, route: str, method: str) -> _StageRow:
+        row = self._rows.get((service, route, method))
+        if row is None:
+            with self._lock:
+                row = self._rows.setdefault((service, route, method),
+                                            _StageRow())
+        return row
+
+    def count(self, service: str, route: str, method: str):
+        """One request read and not timed."""
+        next(self.row(service, route, method).requests)
+
+    def add(self, row: _StageRow, read: float, handle: float,
+            reply: float, request: float):
+        """The stages of one timed request (already counted)."""
+        with self._lock:
+            row.timed += 1
+            seconds = row.seconds
+            seconds[0] += read
+            seconds[1] += handle
+            seconds[2] += reply
+            seconds[3] += request
+
+    def snapshot(self) -> dict:
+        """{(service, route, method): {"requests", "timed_requests",
+        "<stage>_seconds" ...}}"""
+        out = {}
+        with self._lock:
+            for key, row in self._rows.items():
+                looked = next(row.requests) - row.looks
+                row.looks += 1
+                out[key] = {
+                    "requests": looked, "timed_requests": row.timed,
+                    **{f"{stage}_seconds": round(seconds, 6)
+                       for stage, seconds in zip(_REQUEST_STAGES,
+                                                 row.seconds)}}
+        return out
+
+    def export(self):
+        """Bring the Prometheus rpc_server_* vectors up to the counters
+        (`stats.metrics_handler` calls this before it exposes the
+        registry)."""
+        for (service, route, method), row in self.snapshot().items():
+            _stats.RpcServerRequestsCounter.labels(
+                service, route, method, "all").set_cumulative(
+                    row["requests"])
+            _stats.RpcServerRequestsCounter.labels(
+                service, route, method, "timed").set_cumulative(
+                    row["timed_requests"])
+            for stage in _REQUEST_STAGES:
+                _stats.RpcServerStageSeconds.labels(
+                    service, route, method, stage).set(
+                        row[stage + "_seconds"])
+
+
+REQUEST_STAGES = RequestStages()
+
+
+class _TimedRequest:
+    """The clock of one timed request.  One `perf_counter` reading a
+    stage boundary is the stage's counter, a child span of a sampled
+    request's server span (`http.read`, `http.handle`, `http.reply`;
+    the handler's own spans hang under `http.handle`) and, under a
+    jax.profiler session, a `TraceAnnotation` on the device planes'
+    clock for `http.read` and `http.reply`.  `http.handle` is never
+    annotated: the profile's idle gaps are named after the host event
+    that covers most of each, and an event around the whole handler
+    would cover every `ec.*` stage inside it and take their labels.
+
+    Built at the request line under a session, where `http.read`'s
+    annotation has to open, else in `_dispatch` once the server span
+    turns out sampled."""
+
+    __slots__ = ("t_line", "t_handle", "t_handled", "t_reply", "row",
+                 "_annotation", "_open", "_span")
+
+    def __init__(self, t_line: float, annotation):
+        self.t_line = t_line
+        self.row = None
+        self._annotation = annotation
+        self._open = None
+        self._span = None
+        self._annotate("http.read")
+
+    def _annotate(self, name: Optional[str]):
+        """Close the open annotation; open `name`'s under a session."""
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        if name is not None and self._annotation is not None:
+            self._open = self._annotation(name)
+            self._open.__enter__()
+
+    def handle_begins(self, row: _StageRow, sp: "tracing.Span"):
+        """`http.read` ends, the route is about to be called: a sampled
+        request's handler runs under `http.handle`."""
+        self.row = row
+        now = self.t_handle = time.perf_counter()
+        self._annotate(None)
+        if sp.sampled:
+            tracing.record_span("http.read", now - self.t_line, parent=sp)
+            self._span = tracing.start("http.handle", parent=sp)
+            tracing.swap(self._span)
+
+    def handle_ends(self, sp: "tracing.Span"):
+        self.t_handled = time.perf_counter()
+        if self._span is not None:
+            tracing.swap(sp)
+            self._span.finish(duration=self.t_handled - self.t_handle)
+            self._span = None
+
+    def reply_begins(self, sp: "tracing.Span"):
+        self._annotate("http.reply")
+        if sp.sampled:
+            self._span = tracing.start("http.reply", parent=sp)
+        self.t_reply = time.perf_counter()
+
+    def flushed(self):
+        """The reply is on the socket: `http.reply` and the request
+        end, and the four counters get their seconds."""
+        now = time.perf_counter()
+        if self._span is not None:
+            self._span.finish(duration=now - self.t_reply)
+            self._span = None
+        self._annotate(None)
+        REQUEST_STAGES.add(
+            self.row, self.t_handle - self.t_line,
+            self.t_handled - self.t_handle, now - self.t_reply,
+            now - self.t_line)
+
+    def close(self):
+        """However the request ended: no annotation stays open."""
+        self._annotate(None)
+
+
 Route = Callable[[Request], object]
 
 
@@ -376,7 +558,58 @@ class RpcServer:
                         return False
                 return True
 
-            def _dispatch(self, method: str):
+            def handle_one_request(self):
+                """The stdlib's loop body, with the request's life around
+                its handler timed where it happens: `http.read` begins
+                here, with the request line in hand (the keep-alive wait
+                for it is the client's time, not the server's), and
+                `http.reply` ends here, behind the flush that puts a
+                reply under the write buffer's size on the socket: a
+                `send` that `rpc_hop_seconds`, the server span and a
+                route's own timers all end before."""
+                timed = None
+                try:
+                    self.raw_requestline = self.rfile.readline(65537)
+                    t_line = time.perf_counter()
+                    if not self.raw_requestline:
+                        self.close_connection = True
+                        return
+                    annotation = tracing.session_annotation()
+                    if annotation is not None:
+                        timed = _TimedRequest(t_line, annotation)
+                    if len(self.raw_requestline) > 65536:
+                        self.requestline = self.request_version = \
+                            self.command = ""
+                        self.send_error(HTTPStatus.REQUEST_URI_TOO_LONG)
+                    elif not self.parse_request():
+                        pass  # an error code has been sent
+                    elif self.command not in _METHODS:
+                        self.send_error(
+                            HTTPStatus.NOT_IMPLEMENTED,
+                            "Unsupported method (%r)" % self.command)
+                    else:
+                        done = self._dispatch(self.command, t_line, timed)
+                        self.wfile.flush()
+                        if done is not None:
+                            done.flushed()
+                        return
+                    REQUEST_STAGES.count(outer.service_name, UNROUTED,
+                                         UNROUTED)
+                except TimeoutError as e:
+                    # a read or a write timed out: discard the connection
+                    self.log_error("Request timed out: %r", e)
+                    self.close_connection = True
+                finally:
+                    if timed is not None:
+                        timed.close()
+
+            def _dispatch(self, method: str, t_line: float,
+                          timed: Optional[_TimedRequest]
+                          ) -> Optional[_TimedRequest]:
+                """Read the body, route, call the handler, write the
+                reply.  Returns the request's clock when its stages were
+                timed (`timed`, or one made here for a sampled span):
+                the caller ends `http.reply` behind the flush."""
                 raw_path = self.path
                 if "?" in raw_path:
                     parsed = urllib.parse.urlsplit(raw_path)
@@ -419,8 +652,10 @@ class RpcServer:
                             json.dumps({"error": str(e)}).encode(),
                             e.status, "application/json",
                             headers=dict(e.headers))
+                    REQUEST_STAGES.count(outer.service_name, UNROUTED,
+                                         method)
                     self._reply(resp)
-                    return
+                    return None
                 route, prefix = outer._match(method, path)
                 # route label for the span name / hop vector: the matched
                 # prefix ("*" = default route), never the raw path — label
@@ -451,6 +686,12 @@ class RpcServer:
                     except ValueError:
                         deadline = None
                 prev_dl = set_deadline(deadline)
+                row = REQUEST_STAGES.row(service, label, method)
+                next(row.requests)
+                if timed is None and sp.sampled:
+                    timed = _TimedRequest(t_line, None)
+                if timed is not None:
+                    timed.handle_begins(row, sp)
                 try:
                     try:
                         if deadline is not None and \
@@ -483,6 +724,8 @@ class RpcServer:
                         resp = Response(
                             json.dumps({"error": f"{type(e).__name__}: {e}"}
                                        ).encode(), 500, "application/json")
+                    if timed is not None:
+                        timed.handle_ends(sp)
                     if pf is not None and \
                             _prefork.FWD_HEADER not in self.headers:
                         if resp.status == 404 and _prefork.is_worker() \
@@ -509,7 +752,10 @@ class RpcServer:
                         # span tree from /debug/traces/<id>
                         resp.headers.setdefault(tracing.TRACE_HEADER,
                                                 sp.trace_id)
+                    if timed is not None:
+                        timed.reply_begins(sp)
                     self._reply(resp)
+                    return timed
                 finally:
                     _qos.set_qos(*prev_qos)
                     set_deadline(prev_dl)
@@ -664,21 +910,6 @@ class RpcServer:
                     # (Content-Length short / missing terminal chunk)
                     # tells the client the transfer is truncated
                     self.close_connection = True
-
-            def do_GET(self):
-                self._dispatch("GET")
-
-            def do_HEAD(self):
-                self._dispatch("HEAD")
-
-            def do_POST(self):
-                self._dispatch("POST")
-
-            def do_PUT(self):
-                self._dispatch("PUT")
-
-            def do_DELETE(self):
-                self._dispatch("DELETE")
 
         class Server(ThreadingHTTPServer):
             # the stdlib default backlog of 5 causes 1s+ SYN-retransmit

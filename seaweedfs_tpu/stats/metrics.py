@@ -494,8 +494,29 @@ S3RequestHistogram = REGISTRY.histogram(
 # route = the matched route prefix — bounded label sets, no addresses)
 RpcHopHistogram = REGISTRY.histogram(
     "SeaweedFS_rpc_hop_seconds",
-    "cross-daemon request hop latency by source/destination/route",
+    "cross-daemon request hop latency by source/destination/route: "
+    "route matched -> the reply written, which ends before the reply's "
+    "flush for bodies under the write buffer (64 KiB); "
+    "rpc_server_stage_seconds{stage=\"reply\"} holds that send",
     ("src", "dst", "route"))
+# a request's life in RpcServer around its handler (rpc/http_rpc.py
+# RequestStages): counted for every request, timed for the sampled ones
+# and under a profiler session, brought up to the counters at scrape
+RpcServerRequestsCounter = REGISTRY.counter(
+    "SeaweedFS_rpc_server_requests_total",
+    "requests a daemon's HTTP server read, by matched route prefix (* = "
+    "the default route, - = never routed: a failed parse, a prefork "
+    "worker's forward) and method: all, and those whose stages were "
+    "timed (a sampled request, or a profiler session): the requests "
+    "rpc_server_stage_seconds is the sum over",
+    ("service", "route", "method", "requests"))
+RpcServerStageSeconds = REGISTRY.gauge(
+    "SeaweedFS_rpc_server_stage_seconds",
+    "busy seconds per stage of a request, summed over the timed "
+    "requests (read = request line in hand -> route about to be called: "
+    "headers, body off the socket, routing; handle = the route; reply = "
+    "the reply begun -> flushed to the socket; request = read's start "
+    "-> reply's end)", ("service", "route", "method", "stage"))
 RpcInflightGauge = REGISTRY.gauge(
     "SeaweedFS_rpc_inflight_requests",
     "requests currently inside a daemon's dispatch", ("service",))
@@ -537,8 +558,10 @@ VolumeServerHeartbeatFailures = REGISTRY.counter(
 VolumeServerHeartbeatMaxGap = REGISTRY.gauge(
     "SeaweedFS_volumeServer_heartbeat_max_gap_seconds",
     "longest time between two heartbeats of the loop that the master "
-    "acknowledged, since the process started (the master unregisters a "
-    "node after 3 pulses of silence)")
+    "acknowledged, from the first one acknowledged after the server "
+    "listens on; begins again once, when the process has initialised "
+    "its device (startup_seconds{phase=\"device_init\"}); the master "
+    "unregisters a node after 3 pulses of silence")
 VolumeReadonlyDemotions = REGISTRY.counter(
     "SeaweedFS_volume_readonly_demotions_total",
     "volumes auto-demoted to read-only after disk write failures")
@@ -559,6 +582,12 @@ def _profiler_stacks() -> float:
     return profiling.stack_count()
 
 
+def _profiler_gil_probe() -> float:
+    from .. import profiling
+
+    return profiling.gil_probe_floor()
+
+
 ProfilerOverheadGauge = REGISTRY.gauge(
     "SeaweedFS_profiler_overhead_ratio",
     "fraction of wall time the always-on stack sampler spends sampling",
@@ -567,6 +596,21 @@ ProfilerStacksGauge = REGISTRY.gauge(
     "SeaweedFS_profiler_stacks",
     "distinct folded stacks interned by the always-on sampler",
     fn=_profiler_stacks)
+ProfilerGilWaitHistogram = REGISTRY.histogram(
+    "SeaweedFS_profiler_gil_wait_seconds",
+    "how much longer than profiler_gil_probe_seconds the always-on "
+    "sampler's probe took (a checksum of 256 KiB in C, which gives the "
+    "GIL up as a pread or a send does): the wait to get the GIL back "
+    "that every thread of the process pays after a blocking call (one "
+    "observation a tick, WEED_PROF_HZ a second)",
+    buckets=(.00001, .000025, .00005, .0001, .00025, .0005, .001, .0025,
+             .005, .01, .025, .05, .1))
+ProfilerGilProbeGauge = REGISTRY.gauge(
+    "SeaweedFS_profiler_gil_probe_seconds",
+    "the least the always-on sampler's GIL probe has taken since the "
+    "process started: the checksum alone, with the GIL free, which "
+    "profiler_gil_wait_seconds leaves out",
+    fn=_profiler_gil_probe)
 ProfilerRouteSamplesCounter = REGISTRY.counter(
     "SeaweedFS_profiler_route_samples_total",
     "always-on profiler samples attributed to an active RPC route",
@@ -883,9 +927,11 @@ ProcessStartTimeGauge = REGISTRY.gauge(
 
 
 def metrics_handler(req):
-    """RpcServer route serving the registry in text exposition format."""
-    from ..rpc.http_rpc import Response
+    """RpcServer route serving the registry in text exposition format,
+    the per-request counters brought up first."""
+    from ..rpc.http_rpc import REQUEST_STAGES, Response
 
+    REQUEST_STAGES.export()
     return Response(REGISTRY.expose().encode(),
                     content_type="text/plain; version=0.0.4")
 

@@ -25,7 +25,11 @@ slow reply to the hop or kernel stage that caused it.  Here:
     ``perf_counter`` measurement that feeds the stage's counter, a child
     span when the request is sampled, and — while a ``jax.profiler``
     session is on — a host event on the profiler's clock, next to the
-    device planes.
+    device planes;
+  * ``RpcServer`` times a request's life around its handler the same
+    way (``http.read`` / ``http.handle`` / ``http.reply``,
+    rpc/http_rpc.py), for the requests that are sampled or fall under a
+    profiler session.
 
 The daemons share one process in tests (like stats.REGISTRY), so
 the recorder is process-global and spans carry a ``service`` label —
@@ -332,6 +336,17 @@ def _annotation_class():
     return _trace_annotation
 
 
+def session_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler session is on,
+    else None: what a site asks before it puts an event on the trace's
+    host plane (``stage()``; ``RpcServer``'s ``http.read`` /
+    ``http.reply``)."""
+    ann = _trace_annotation or _annotation_class()
+    if ann is not None and ann.is_enabled():
+        return ann
+    return None
+
+
 class stage:
     """``with stage(name, add, key[, n, nbytes]):`` — one pipeline stage
     of a device EC path, timed once.  `name` is one of the fixed
@@ -368,8 +383,8 @@ class stage:
         self.seconds = 0.0
 
     def __enter__(self) -> "stage":
-        ann = _trace_annotation or _annotation_class()
-        if ann is not None and ann.is_enabled():
+        ann = session_annotation()
+        if ann is not None:
             ann = self._ann = ann(self.name)
             ann.__enter__()
         else:

@@ -122,6 +122,12 @@ def device_info() -> dict | None:
     return dict(info) if info else None
 
 
+def device_asked() -> bool:
+    """Whether this process's first `jax.devices()` has returned (with
+    a device or with none): `device_info()` answers from its cache."""
+    return bool(_cache)
+
+
 def _probe() -> tuple[bool, str]:
     """(devices_ready, platform_name) of this process's default JAX
     backend — the one seam tests patch."""

@@ -7,6 +7,7 @@ and `weed.py profile` merges a live cluster into one profile."""
 import contextlib
 import io
 import re
+import sys
 import threading
 import time
 
@@ -14,6 +15,7 @@ import pytest
 
 from seaweedfs_tpu import profiling, tracing
 from seaweedfs_tpu.rpc.http_rpc import call
+from seaweedfs_tpu.stats import metrics as stats
 
 # flamegraph.pl's line shape: anything, space, trailing integer count
 FOLDED_RE = re.compile(r"^(.+) (\d+)$")
@@ -116,6 +118,73 @@ class TestStackSampler:
         assert sampler.total > 0
         assert 0.0 < sampler.overhead_ratio() <= 1.0  # busy lies in wall
 
+    def test_gil_gauge_one_observation_a_tick(self):
+        """The always-on kind probes the GIL every tick (a checksum in C
+        that gives it up) and records what the probe took over the least
+        it ever has: one observation a tick, none below zero, in the
+        histogram and in snapshot()."""
+        hist = stats.ProfilerGilWaitHistogram
+        before = sum(hist._counts.get((), []))
+        sampler = profiling.StackSampler(hz=200, publish=True)
+        sampler.start()
+        time.sleep(0.2)
+        assert sampler.stop()
+        assert sampler.gil_count == sampler._ticks > 0
+        assert 0.0 <= sampler.gil_sum <= sampler.gil_count * \
+            sampler.gil_max
+        # the process's own always-on sampler may add its ticks too
+        assert sum(hist._counts[()]) - before >= sampler.gil_count
+        wait = sampler.snapshot()["gil_wait"]
+        assert wait["count"] == sampler.gil_count
+        assert 0.0 <= wait["mean"] <= wait["max"] == \
+            round(sampler.gil_max, 9)
+        # the floor is the probe alone: some tick sat on it, observed 0
+        assert 0.0 < wait["floor"] == round(sampler.gil_floor, 9) < 0.1
+        assert hist.buckets[0] == 10e-6 and hist.buckets[-1] == 0.1
+
+    def test_burst_sampler_probes_nothing(self):
+        hist = stats.ProfilerGilWaitHistogram
+        running = profiling.profiler()   # 5 ticks a second, if mounted
+        before = sum(hist._counts.get((), []))
+        sampler = profiling.StackSampler(hz=200, publish=False)
+        sampler.start()
+        time.sleep(0.1)
+        assert sampler.stop()
+        assert sampler._ticks > 0 and sampler.gil_count == 0
+        assert "gil_wait" not in sampler.snapshot()
+        added = sum(hist._counts.get((), [])) - before
+        assert added <= (2 if running is not None else 0)
+
+    def test_gil_gauge_holds_the_wait_for_the_gil(self, monkeypatch):
+        """Not a timing of the host: a thread that spins in pure Python
+        takes the GIL while the probe checksums and gives it up only
+        when asked, and a waiter asks after one switch interval.  So a
+        probe beside it takes longer than the floor found alone by most
+        of that interval, whatever else the host runs (a loaded host
+        makes it longer still).  The probe is made long here (32 MiB,
+        milliseconds) so that the spinner is sure to wake inside it:
+        inside the 70 us of 256 KiB that is a race on a small host, as
+        it is for any short blocking call."""
+        monkeypatch.setattr(profiling, "_GIL_PROBE", bytes(32 << 20))
+        sampler = profiling.StackSampler(hz=100, publish=True)
+        sampler.start()
+        # this thread sleeps through both phases: a poll would compete
+        # for the GIL and be the one the probe hands it to
+        time.sleep(0.3)             # alone: the floor is found
+        alone = sampler.gil_count
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(0.1)
+        try:
+            with spinner():
+                time.sleep(1.5)
+        finally:
+            sys.setswitchinterval(interval)
+        assert sampler.stop(timeout=5)
+        assert alone >= 3 and sampler.gil_count >= alone + 3
+        assert sampler.gil_floor < 0.05
+        assert sampler.gil_max >= 0.05
+        assert sampler.overhead_ratio() < 0.5    # the wait is not `busy`
+
     def test_merge_folded_prefixes_and_sums(self):
         merged = parse_folded(profiling.merge_folded({
             "volume 127.0.0.1:8080": "main;read 3\n# comment\n",
@@ -183,6 +252,9 @@ class TestPprofEndpoints:
         assert "/debug/pprof/heap" in str(idx["endpoints"])
         assert idx["hz"] == profiling.prof_hz()
         assert idx["always_on"] is not None  # mount() started it
+        # the process's GIL gauge rides the same reply
+        assert set(idx["always_on"]["gil_wait"]) == {
+            "floor", "count", "mean", "max"}
 
     def test_heap_arms_reports_and_disarms(self, cluster):
         import tracemalloc

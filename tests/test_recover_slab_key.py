@@ -340,7 +340,9 @@ def test_recover_slab_hit_share_reads_the_share_and_nothing_from_a_parent():
               {"decode_batches": 110, "device_pool": {"resident_hits": 19}})
     assert admin_json.read(spec["reader"], {"admin": {path: parent}}) is None
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        entry = json.load(f)["per_layer"][-1]
+        # PR 39's one entry; later PRs append theirs behind it
+        (entry,) = [m for m in json.load(f)["per_layer"]
+                    if m["name"] == "recover_slab_hit_share"]
     assert entry == {"name": "recover_slab_hit_share", "unit": "%",
                      "better": "higher", "source": "program_counter",
                      "layer": "Host-device link", "moves": "op_p50_ms",

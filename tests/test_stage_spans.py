@@ -158,6 +158,26 @@ def _metric_files():
     return out
 
 
+def _one_timed_object_request(method: str):
+    """One sampled request to the object route (label `*`) of a server
+    under the volume server's name, and the scrape's export."""
+    from seaweedfs_tpu.rpc import http_rpc
+
+    srv = http_rpc.RpcServer(service_name="volume")
+    srv.default_route = lambda method, req: b"x"
+    srv.start()
+    try:
+        http_rpc.call(srv.address, "/3,0101020304", method=method,
+                      headers={tracing.TRACE_HEADER: "f" * 16,
+                               tracing.SAMPLED_HEADER: "1"}, parse=False)
+        while not http_rpc.REQUEST_STAGES.snapshot()[
+                ("volume", "*", method)]["timed_requests"]:
+            time.sleep(0.005)
+        http_rpc.REQUEST_STAGES.export()
+    finally:
+        srv.stop()
+
+
 # -- (a) every key a metric file names is there -------------------------------
 
 @pytest.mark.parametrize("spec", _metric_files(), ids=lambda s: s["name"])
@@ -199,10 +219,21 @@ def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch):
 
             monkeypatch.setattr(platform_util, "_cache", {})
             assert platform_util.device_info()["platform"] == "cpu"
-        if reader["family"].endswith("request_seconds"):
-            stats.VolumeServerRequestHistogram.labels("read").observe(0.001)
-        text = stats.REGISTRY.expose()
         labels = reader.get("labels") or {}
+        if reader["family"].endswith("request_seconds"):
+            stats.VolumeServerRequestHistogram.labels(
+                labels["type"]).observe(0.001)
+        if reader["family"].startswith("SeaweedFS_rpc_server_"):
+            _one_timed_object_request(labels["method"])
+        if reader["family"].endswith("profiler_gil_wait_seconds"):
+            from seaweedfs_tpu import profiling
+
+            sampler = profiling.StackSampler(hz=200, publish=True)
+            sampler.start()
+            while not sampler.gil_count:
+                time.sleep(0.005)
+            sampler.stop()
+        text = stats.REGISTRY.expose()
         pat = re.compile(r"^" + re.escape(reader["family"])
                          + r"(_sum|_count)?(\{[^}]*\})? \S+$", re.M)
         samples = [m.group(0) for m in pat.finditer(text)
@@ -838,3 +869,45 @@ def test_small_seal_twice_under_put_get_load(tmp_path, monkeypatch):
         stop.set()
         vs.stop()
         master.stop()
+
+
+def test_heartbeat_max_gap_begins_again_once_the_device_is_up(
+        tmp_path, monkeypatch):
+    """The maximum runs from the first beat acknowledged after the server
+    listens on and begins again once, at the first beat after the
+    process initialised its device: the gap of that stall is not what
+    the gauge holds hours later, a shorter gap after it is."""
+    from seaweedfs_tpu.util import platform as platform_util
+    from seaweedfs_tpu.volume_server.server import VolumeServer
+
+    monkeypatch.setattr(platform_util, "_cache", {})
+    assert not platform_util.device_asked()
+    vs = VolumeServer([str(tmp_path)], "127.0.0.1:1", port=0,
+                      pulse_seconds=0.01)
+    beats = []
+    seen = []
+    waits = {2: 0.25, 4: 0.08}     # the third beat is late for the init
+
+    def beat():
+        n = len(beats)
+        seen.append(stats.VolumeServerHeartbeatMaxGap._values[()])
+        if n in waits:
+            time.sleep(waits[n])
+            if n == 2:
+                platform_util._cache["device"] = None
+        beats.append(n)
+        if n == 6:
+            vs._stop.set()
+
+    monkeypatch.setattr(vs, "heartbeat_once", beat)
+    stats.VolumeServerHeartbeatMaxGap.set(0.0)
+    vs.server.start()          # listening; the loop is run by hand
+    try:
+        vs._heartbeat_loop()
+    finally:
+        vs.stop()
+    assert len(beats) == 7 and platform_util.device_asked()
+    assert seen[2] > 0.0 and seen[3] == 0.0    # counted, then begun again
+    gap = stats.VolumeServerHeartbeatMaxGap._values[()]
+    assert 0.08 <= gap < 0.25
+    assert "device" in stats.VolumeServerHeartbeatMaxGap.help
