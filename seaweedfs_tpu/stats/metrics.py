@@ -301,6 +301,13 @@ VolumeServerReadOnlyVolumeGauge = REGISTRY.gauge(
 VolumeServerProxiedReadCounter = REGISTRY.counter(
     "SeaweedFS_volumeServer_proxied_read_total",
     "non-local reads served per readMode outcome", ("mode",))
+VolumeServerReplicateCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_replicate_total",
+    "what a served write, delete or remote fetch decided about its "
+    "fan-out: single_copy = the local volume's own placement has one "
+    "copy, returned without asking; asked = the master was looked up "
+    "for the volume's locations; fanned_out = every other holder took "
+    "the write", ("decision",))
 VolumeServerThrottleRejects = REGISTRY.counter(
     "SeaweedFS_volumeServer_throttle_rejects_total",
     "requests rejected (429) by the in-flight byte throttles",

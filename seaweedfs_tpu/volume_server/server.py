@@ -326,6 +326,9 @@ class VolumeServer:
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):
+        # a scrape of a window without a write reads 0, not "no sample"
+        for decision in ("single_copy", "asked"):
+            stats.VolumeServerReplicateCounter.labels(decision).inc(0)
         self.server.start()
         if self.enable_tcp:
             self._start_tcp()
@@ -1426,7 +1429,7 @@ class VolumeServer:
         self.read_cache.invalidate(f"{vid},{nid:x}", reason="overwrite")
         if not is_replicate:
             self._replicate(vid, f"{vid},{nid:x}{cookie:08x}", "POST",
-                            req.body, dict(req.headers.items()))
+                            req.body, req.headers)
         return {"name": (n.name or b"").decode(errors="replace"),
                 "size": size, "eTag": n.etag()}
 
@@ -1444,9 +1447,29 @@ class VolumeServer:
         return {"size": size}
 
     def _replicate(self, vid: int, fid: str, method: str,
-                   body: Optional[bytes], headers: dict):
+                   body: Optional[bytes], headers):
         """Fan out to the other replicas (store_replicate.go:24-114);
-        any replica failure fails the request, as in the reference."""
+        any replica failure fails the request, as in the reference.
+
+        Whom to write to is getWritableRemoteReplications' rule
+        (store_replicate.go): a volume that is on the local store and
+        whose own ReplicaPlacement.GetCopyCount() == 1 has nobody, and
+        nothing leaves the process; the master is looked up only when
+        the volume "is not on the local store, or has replications".
+        The placement is read from the superblock at every call:
+        /admin/volume/configure_replication rewrites it on a live volume
+        and the next write follows it.  So a second location the master
+        still lists for a single-copy volume (a volume.copy or
+        volume.move in flight, a placement lowered to 000 while the old
+        replica stands) is not written, as upstream does not.
+        `headers` is the request's own (anything with `items()`): it is
+        copied only once there is somebody to send it to."""
+        v = self.store.find_volume(vid)
+        if v is not None and \
+                v.super_block.replica_placement.copy_count() == 1:
+            stats.VolumeServerReplicateCounter.labels("single_copy").inc()
+            return
+        stats.VolumeServerReplicateCounter.labels("asked").inc()
         try:
             lookup = policy.call_policy(
                 self.master_address, f"/dir/lookup?volumeId={vid}",
@@ -1482,6 +1505,7 @@ class VolumeServer:
                     url, f"/{fid}?type=replicate", method=method,
                     raw=body, headers=headers, timeout=30,
                     idempotent=True)
+        stats.VolumeServerReplicateCounter.labels("fanned_out").inc()
 
     # -- admin ---------------------------------------------------------------
     def _h_assign_volume(self, req: Request):

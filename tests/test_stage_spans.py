@@ -181,7 +181,8 @@ def _one_timed_object_request(method: str):
 # -- (a) every key a metric file names is there -------------------------------
 
 @pytest.mark.parametrize("spec", _metric_files(), ids=lambda s: s["name"])
-def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch):
+def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch,
+                                               tmp_path):
     """A renamed key of `stage_stats`, of /admin/ec/recover_stats or a
     renamed family fails here, on the CPU, and not in a chip run."""
     reader = spec["reader"]
@@ -225,6 +226,13 @@ def test_key_named_by_a_metric_file_is_present(spec, paths, monkeypatch):
                 labels["type"]).observe(0.001)
         if reader["family"].startswith("SeaweedFS_rpc_server_"):
             _one_timed_object_request(labels["method"])
+        if reader["family"].endswith("volumeServer_replicate_total"):
+            # there from a volume server's start, before any write
+            from seaweedfs_tpu.volume_server.server import VolumeServer
+
+            vs = VolumeServer([str(tmp_path)], "127.0.0.1:1", port=0)
+            vs.start()
+            vs.stop()
         if reader["family"].endswith("profiler_gil_wait_seconds"):
             from seaweedfs_tpu import profiling
 
