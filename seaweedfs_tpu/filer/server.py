@@ -48,6 +48,7 @@ from ..cache import TieredReadCache
 DEFAULT_CHUNK_SIZE = 4 * 1024 * 1024  # filer -maxMB default (4MB)
 INLINE_LIMIT = 2048  # small-content inlining threshold
 _DEFAULT_PREFETCH = 4
+_STAGES = stats.FILER_STAGES
 
 
 def prefetch_chunks() -> int:
@@ -533,7 +534,8 @@ class FilerServer:
 
             key = gen_cipher_key()
             payload = encrypt(piece, key)
-        with tracing.span("filer.assign"):
+        with tracing.span("filer.assign", add=_STAGES.add,
+                          key="assign"):
             assign = self._assign_leased(replication=replication,
                                          collection=collection, ttl=ttl)
         try:
@@ -547,7 +549,8 @@ class FilerServer:
                 raise
             stats.FilerFidLeaseCounter.labels("stale_retry").inc()
             self._fid_lease.invalidate(reason=f"upload {e.status}")
-            with tracing.span("filer.assign"):
+            with tracing.span("filer.assign", add=_STAGES.add,
+                              key="assign"):
                 assign = self._assign(replication=replication,
                                       collection=collection, ttl=ttl)
             up = self._upload_assigned(assign, payload)
@@ -565,7 +568,8 @@ class FilerServer:
         cluster (doPutAutoChunk, _write_upload.go); per-path rules from
         /etc/seaweedfs/filer.conf pick collection/replication and enforce
         read-only prefixes."""
-        with tracing.span("filer.save", tags={"bytes": len(body)}):
+        with tracing.span("filer.save", tags={"bytes": len(body)},
+                          add=_STAGES.add, key="save"):
             return self._save_bytes(path, body, mime, extended)
 
     def _save_bytes(self, path: str, body: bytes, mime: str = "",
@@ -620,7 +624,8 @@ class FilerServer:
                     with tracing.span("filer.chunk_upload",
                                       parent=parent_span,
                                       tags={"offset": off,
-                                            "bytes": len(piece)}):
+                                            "bytes": len(piece)},
+                                      add=_STAGES.add, key="chunk_upload"):
                         chunk = self._upload_blob(piece, rule.replication,
                                                   rule.collection, rule_ttl)
                 except Exception:
@@ -662,7 +667,8 @@ class FilerServer:
                 lambda blob: self._upload_blob(blob, rule.replication,
                                                rule.collection, rule_ttl),
                 entry.chunks, self.manifest_batch)
-        with tracing.span("filer.meta_save"):
+        with tracing.span("filer.meta_save", add=_STAGES.add,
+                          key="meta_save"):
             self.filer.create_entry(entry)
         return entry
 
@@ -779,7 +785,8 @@ class FilerServer:
         """Reassemble [start, start+length) of an entry's content."""
         with tracing.span("filer.read",
                           tags={"bytes": length if length is not None
-                                else entry.size() - start}):
+                                else entry.size() - start},
+                          add=_STAGES.add, key="read"):
             return b"".join(self._read_parts(entry, start, length))
 
     def read_view(self, entry: Entry, start: int = 0,
@@ -790,7 +797,8 @@ class FilerServer:
         send with no intermediate `bytes` concatenation."""
         with tracing.span("filer.read",
                           tags={"bytes": length if length is not None
-                                else entry.size() - start}):
+                                else entry.size() - start},
+                          add=_STAGES.add, key="read"):
             parts = self._read_parts(entry, start, length)
         return parts, sum(len(p) for p in parts)
 
@@ -832,7 +840,8 @@ class FilerServer:
                 with qos.qos_scope(qos_cls, qos_tenant), \
                         tracing.span("filer.chunk_fetch",
                                      parent=parent_span,
-                                     tags={"fid": fid}):
+                                     tags={"fid": fid}, add=_STAGES.add,
+                                     key="chunk_fetch"):
                     data = self._fetch_chunk(fid)
                 if keys[fid]:
                     # cache holds what the volume stores (ciphertext);
@@ -954,7 +963,8 @@ class FilerServer:
         def fetch(fid: str) -> bytes:
             with qos.qos_scope(qos_cls, qos_tenant), \
                     tracing.span("filer.chunk_fetch", parent=parent_span,
-                                 tags={"fid": fid}):
+                                 tags={"fid": fid}, add=_STAGES.add,
+                                 key="chunk_fetch"):
                 data = self._fetch_chunk(fid)
             if keys[fid]:
                 from ..util.cipher import decrypt
@@ -1000,7 +1010,8 @@ class FilerServer:
             # reach volume servers (filer_server_handlers_proxy.go)
             return self._proxy_chunk(proxy_chunk, req)
         try:
-            with tracing.span("filer.lookup"):
+            with tracing.span("filer.lookup", add=_STAGES.add,
+                              key="lookup"):
                 entry = self.filer.find_entry(path)
         except NotFoundError:
             raise RpcError(f"{path} not found", 404)

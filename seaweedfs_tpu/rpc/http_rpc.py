@@ -15,6 +15,7 @@ import http.client
 import itertools
 import json
 import os
+import select
 import socket
 import threading
 import time
@@ -452,6 +453,13 @@ class RpcServer:
         self._inflight = _stats.RpcInflightGauge.labels(service_name)
         self._sendfile_bytes = \
             _stats.GatewaySendfileBytesCounter.labels(service_name)
+        self._pread_bytes = \
+            _stats.GatewayPreadBytesCounter.labels(service_name)
+        self._sendfile_waits = \
+            _stats.GatewaySendfileWaitsCounter.labels(service_name)
+        for counter in (self._sendfile_bytes, self._pread_bytes,
+                        self._sendfile_waits):
+            counter.inc(0)  # a sample at 0, not an absent series
         # prefork (WEED_HTTP_WORKERS): only explicitly-bound ports shard
         # into worker processes — port-0 servers are ephemeral (test
         # fixtures, embedded sidecars) and must never fork the host
@@ -840,14 +848,22 @@ class RpcServer:
                         out = self.connection.fileno()
                         try:
                             while sent < fs.length:
-                                n = os.sendfile(out, fs.fd,
-                                                fs.offset + sent,
-                                                fs.length - sent)
+                                try:
+                                    n = os.sendfile(out, fs.fd,
+                                                    fs.offset + sent,
+                                                    fs.length - sent)
+                                except BlockingIOError:
+                                    # the handler's timeout makes its
+                                    # descriptor non-blocking: a full
+                                    # send buffer is a reader to wait
+                                    # for, not a broken transfer
+                                    self._wait_writable(out)
+                                    continue
                                 if n == 0:
                                     break  # source truncated under us
                                 sent += n
-                        except OSError:
-                            if sent:
+                        except OSError as e:
+                            if sent or isinstance(e, TimeoutError):
                                 # mid-transfer failure: the framing is
                                 # already committed, sever the socket
                                 self.close_connection = True
@@ -871,8 +887,22 @@ class RpcServer:
                             break
                         self.wfile.write(chunk)
                         done += len(chunk)
+                    outer._pread_bytes.inc(done)
                 finally:
                     fs.close()
+
+            def _wait_writable(self, fd: int):
+                """Block until the client's socket takes bytes again,
+                for no longer than the handler's timeout (what a
+                `send` on the socket object itself would wait)."""
+                outer._sendfile_waits.inc()
+                timeout = self.connection.gettimeout()
+                poller = select.poll()
+                poller.register(fd, select.POLLOUT)
+                if not poller.poll(None if timeout is None
+                                   else timeout * 1000):
+                    raise TimeoutError("the reader took no byte of a "
+                                       f"FileSlice for {timeout} s")
 
             def _reply_stream(self, resp: Response, chunks):
                 """Stream an iterator body: raw writes under a known

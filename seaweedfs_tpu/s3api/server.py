@@ -39,6 +39,7 @@ from .circuit_breaker import read_config as cb_read_config
 
 BUCKETS_ROOT = "/buckets"
 UPLOADS_DIR = ".uploads"
+_STAGES = stats.S3_STAGES
 
 
 def parse_multipart_form(content_type: str, body: bytes) -> dict:
@@ -103,6 +104,14 @@ def _build(parent, children):
                 _build(node, v)
     else:
         parent.text = "" if children is None else str(children)
+
+
+def _then(chunks, done):
+    """`chunks`, and `done()` once they are exhausted or dropped."""
+    try:
+        yield from chunks
+    finally:
+        done()
 
 
 def _error_xml(code: str, message: str, status: int,
@@ -216,7 +225,7 @@ class S3ApiServer:
         with stats.S3RequestHistogram.labels(action).time():
             try:
                 self._maybe_reload_circuit_breaker()
-                resp = self._route(method, req)
+                resp = self._route(method, req, action)
             except AuthError as e:
                 resp = _error_xml(e.code, str(e), e.status)
             except SlowDown as e:
@@ -232,7 +241,7 @@ class S3ApiServer:
         stats.S3RequestCounter.labels(action, code).inc()
         return resp
 
-    def _route(self, method: str, req: Request):
+    def _route(self, method: str, req: Request, label: str):
         path = urllib.parse.unquote(req.path)
         parts = path.lstrip("/").split("/", 1)
         bucket = parts[0]
@@ -253,8 +262,9 @@ class S3ApiServer:
         action = ACTION_READ if method in ("GET", "HEAD") else ACTION_WRITE
         if method == "GET" and not key:
             action = ACTION_LIST
-        identity, req.body = self.iam.verify_and_decode(
-            method, path, req.query, req.headers, req.body)
+        with tracing.span("s3.auth", add=_STAGES.add, key=(label, "auth")):
+            identity, req.body = self.iam.verify_and_decode(
+                method, path, req.query, req.headers, req.body)
         if identity is not None and not identity.can(action, bucket):
             raise AuthError("AccessDenied",
                             f"{action} not allowed on {bucket}", 403)
@@ -701,7 +711,9 @@ class S3ApiServer:
                 return self._copy_object(bucket, key, req)
             if "tagging" in req.query:
                 return self._put_tagging(bucket, key, req)
-            return self._put_object(bucket, key, req)
+            with tracing.span("s3.put", add=_STAGES.add,
+                              key=("put_object", "put")):
+                return self._put_object(bucket, key, req)
         if method == "POST":
             if "uploads" in req.query:
                 return self._create_multipart(bucket, key, req)
@@ -713,13 +725,19 @@ class S3ApiServer:
                 return self._list_parts(bucket, key, req)
             if "tagging" in req.query:
                 return self._get_tagging(bucket, key)
-            return self._get_object(bucket, key, req, method)
+            if method == "GET":
+                return self._get_object(bucket, key, req, method)
+            with tracing.span("s3.head", add=_STAGES.add,
+                              key=("head_object", "head")):
+                return self._get_object(bucket, key, req, method)
         if method == "DELETE":
             if "uploadId" in req.query:
                 return self._abort_multipart(bucket, key, req)
             if "tagging" in req.query:
                 return self._delete_tagging(bucket, key)
-            return self._delete_object(bucket, key)
+            with tracing.span("s3.delete", add=_STAGES.add,
+                              key=("delete_object", "delete")):
+                return self._delete_object(bucket, key)
         raise RpcError(f"unsupported object op {method}", 405)
 
     def _record_access(self, op: str, bucket: str, key: str, nbytes: int,
@@ -748,7 +766,9 @@ class S3ApiServer:
 
     def _get_object(self, bucket: str, key: str, req: Request, method: str):
         t0 = time.monotonic()
-        entry = self.filer.find_entry(self._object_path(bucket, key))
+        with tracing.span("s3.lookup", add=_STAGES.add,
+                          key=(method.lower() + "_object", "lookup")):
+            entry = self.filer.find_entry(self._object_path(bucket, key))
         if entry.is_directory:
             raise NotFoundError(key)
         size = entry.size()
@@ -784,6 +804,29 @@ class S3ApiServer:
         # record at first-byte time: every reply path below serves
         # exactly `length` payload bytes
         self._record_access("read", bucket, key, length, t0)
+        # s3.get: the entry in hand -> the last body byte handed to the
+        # socket; a streamed body is written after this returns, so the
+        # span ends where its iterator does
+        sp = tracing.start("s3.get", tags={"bytes": length})
+
+        def done(status_: Optional[str] = None):
+            sp.finish(status_)
+            _STAGES.add(("get_object", "get"), sp.duration)
+
+        try:
+            resp = self._read_object(entry, start, length, status,
+                                     content_type, headers)
+        except BaseException:
+            done("error")
+            raise
+        if hasattr(resp.body, "__next__"):
+            resp.body = _then(resp.body, done)
+        else:
+            done()
+        return resp
+
+    def _read_object(self, entry: Entry, start: int, length: int,
+                     status: int, content_type: str, headers: dict):
         # single-chunk objects resident in the disk cache tier go out
         # zero-copy via sendfile, same as the filer read path
         zero = self.filer_server._sendfile_read(

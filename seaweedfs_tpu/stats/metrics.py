@@ -496,6 +496,55 @@ S3RequestCounter = REGISTRY.counter(
     "SeaweedFS_s3_request_total", "s3 requests", ("action", "code"))
 S3RequestHistogram = REGISTRY.histogram(
     "SeaweedFS_s3_request_seconds", "s3 request latency", ("action",))
+
+
+class StageSeconds:
+    """Busy seconds of a daemon's named stages and the blocks they are
+    the sum over, as two counter families with the same labels (the
+    last one `stage`): a stage's cost a block is the one over the other,
+    as with rpc_server_stage_seconds and its timed requests.  `add` is
+    what ``tracing.span(..., add=, key=)`` calls; `key` is the label
+    values, one string or a tuple of them."""
+
+    def __init__(self, seconds: Counter, blocks: Counter):
+        self.seconds = seconds
+        self.blocks = blocks
+
+    def add(self, key, seconds: float):
+        labels = key if isinstance(key, tuple) else (key,)
+        self.seconds.inc(seconds, labels)
+        self.blocks.inc(1.0, labels)
+
+
+# the filer's spans (filer/server.py: assign, save, chunk_upload,
+# meta_save, read, chunk_fetch, lookup) and the gateway's (s3api/
+# server.py: auth, lookup, put, get, head, delete, by the action label
+# of s3_request_total)
+FILER_STAGES = StageSeconds(
+    REGISTRY.counter(
+        "SeaweedFS_filer_stage_seconds_total",
+        "busy seconds inside the filer's filer.<stage> spans, summed "
+        "over filer_stage_blocks_total (chunk_upload and chunk_fetch "
+        "run side by side on pool threads: their sum can pass the "
+        "request's wall time)", ("stage",)),
+    REGISTRY.counter(
+        "SeaweedFS_filer_stage_blocks_total",
+        "filer.<stage> spans timed into filer_stage_seconds_total",
+        ("stage",)))
+S3_STAGES = StageSeconds(
+    REGISTRY.counter(
+        "SeaweedFS_s3_stage_seconds_total",
+        "busy seconds inside the gateway's s3.<stage> spans by S3 "
+        "action: auth (signature and payload hash), lookup (entry by "
+        "key), put, get (entry found -> last body byte handed to the "
+        "socket), head, delete (entry and its chunks), summed over "
+        "s3_stage_blocks_total", ("action", "stage")),
+    REGISTRY.counter(
+        "SeaweedFS_s3_stage_blocks_total",
+        "s3.<stage> spans timed into s3_stage_seconds_total",
+        ("action", "stage")))
+
+
 # cross-hop tracing vectors: observed SERVER-side in RpcServer dispatch
 # (src from the caller's X-Trace-Src header, dst = the serving daemon,
 # route = the matched route prefix — bounded label sets, no addresses)
@@ -748,6 +797,15 @@ GatewaySendfileBytesCounter = REGISTRY.counter(
     "response bytes spliced to client sockets with os.sendfile "
     "(zero-copy writeback), by service",
     ("service",))
+GatewayPreadBytesCounter = REGISTRY.counter(
+    "SeaweedFS_gateway_pread_bytes_total",
+    "FileSlice response bytes copied through user space instead (the "
+    "pread fallback: WEED_SENDFILE=0, or sendfile refused the "
+    "descriptors), by service", ("service",))
+GatewaySendfileWaitsCounter = REGISTRY.counter(
+    "SeaweedFS_gateway_sendfile_waits_total",
+    "os.sendfile calls that found the client socket's send buffer full "
+    "and waited for the reader to drain it, by service", ("service",))
 
 
 # -- cluster elasticity: per-node load telemetry the autoscale

@@ -296,13 +296,35 @@ class _SpanCtx:
         return False
 
 
+class _TimedSpanCtx(_SpanCtx):
+    """A span whose seconds also go to a stage accumulator: the stage's
+    counter and its span are the same measurement, as in ``stage()``."""
+
+    __slots__ = ("add", "key")
+
+    def __init__(self, sp: Span, add, key):
+        self.sp = sp
+        self.add = add
+        self.key = key
+
+    def __exit__(self, exc_type, exc, tb):
+        _SpanCtx.__exit__(self, exc_type, exc, tb)
+        self.add(self.key, self.sp.duration)
+        return False
+
+
 def span(name: str, service: str = "", parent: Optional[Span] = None,
-         tags: Optional[dict] = None) -> _SpanCtx:
+         tags: Optional[dict] = None, add=None, key=None) -> _SpanCtx:
     """Open a child span of the thread's current (or explicit `parent`)
     span for the duration of the block.  Pass `parent` explicitly when
     the work runs on a pool thread that did not inherit the request
-    thread's context (chunk fan-outs)."""
-    return _SpanCtx(start(name, service, parent, tags))
+    thread's context (chunk fan-outs).  With `add`, the block's seconds
+    are also handed to ``add(key, seconds)`` (a ``StageSeconds`` of
+    stats/metrics.py), sampled or not: for blocks of a request that
+    costs milliseconds (a filer chunk, an S3 object), never for one
+    that runs many times a request (``sampled_stage()``)."""
+    sp = start(name, service, parent, tags)
+    return _SpanCtx(sp) if add is None else _TimedSpanCtx(sp, add, key)
 
 
 def record_span(name: str, duration: float, service: str = "",
