@@ -755,25 +755,6 @@ int svn_nm_delete(int64_t handle, uint64_t nid, uint64_t tomb_off) {
     return 0;
 }
 
-// Apply + log the entry only when it is newer than the current mapping
-// (the volume_write.go:160-165 "nv.Offset < offset" guard, evaluated
-// atomically under the map lock so a racing native-port write to the
-// same id cannot be clobbered by a stale Python-side put).
-// Returns 1 applied, 0 superseded, <0 error.
-int svn_nm_put_if_newer(int64_t handle, uint64_t nid, uint64_t off,
-                        int64_t size) {
-    auto v = handle_vol(handle);
-    if (!v) return -1;
-    std::unique_lock<std::shared_mutex> lk(v->nm.mu);
-    uint64_t cur_off;
-    int32_t cur_size;
-    if (v->nm.get(nid, &cur_off, &cur_size) && cur_off >= off) return 0;
-    if (!append_idx_entry(v.get(), nid, off, (int32_t)size))
-        return -(errno ? errno : EIO);
-    v->nm.apply(nid, off, (int32_t)size);
-    return 1;
-}
-
 int svn_nm_set_memory(int64_t handle, uint64_t nid, uint64_t off,
                       int64_t size) {
     auto v = handle_vol(handle);
@@ -838,16 +819,58 @@ int64_t svn_nm_visit(int64_t handle, int64_t* out, int64_t cap_entries) {
     return n;
 }
 
-// Append a pre-built record blob to the .dat; returns the landing offset
-// or -errno.  The append mutex is shared with the native write path, so
-// Python-side writes and native-port writes never interleave.
-int64_t svn_append(int64_t handle, const uint8_t* blob, int64_t len) {
+// Append a record the caller built and stamped to the .dat and point the
+// map at it, in one call.  The append mutex is shared with the native
+// write path, so Python-side writes and native-port writes never
+// interleave.  Under it, at the point where the offset is allocated:
+// the caller decided (dedup, cookie rule, a delete's freed size) against
+// the map entry (`expect_off`, `expect_size`), 0 for no entry; if the map
+// says otherwise now, nothing is written and -EEXIST sends the caller to
+// decide again; a record that would end past `limit` is refused with
+// -EFBIG.  Then, under the map
+// lock: apply + log the entry only when it is newer than the current
+// mapping (the volume_write.go:160-165 "nv.Offset < offset" guard,
+// evaluated atomically so a racing native-port write to the same id
+// cannot be clobbered by a stale Python-side put); for size ==
+// kTombstone, svn_nm_delete's entry.  Returns the landing offset or
+// -errno: an idx append that fails (ENOSPC/EIO) must fail the request
+// before it is acknowledged, not vanish on restart.
+int64_t svn_append_put(int64_t handle, const uint8_t* blob, int64_t len,
+                       uint64_t nid, int64_t size, uint64_t expect_off,
+                       int64_t expect_size, int64_t limit) {
     auto v = handle_vol(handle);
     if (!v) return -1;
-    std::lock_guard<std::mutex> lk(v->wmu);
-    int64_t end = lseek(v->dat_fd, 0, SEEK_END);
-    if (end < 0) return -errno;
-    if (!pwrite_full(v->dat_fd, blob, (size_t)len, end)) return -errno;
+    int64_t end;
+    {
+        std::lock_guard<std::mutex> lk(v->wmu);
+        {
+            std::shared_lock<std::shared_mutex> mlk(v->nm.mu);
+            uint64_t cur_off = 0;
+            int32_t cur_size = 0;
+            if (!v->nm.get(nid, &cur_off, &cur_size)) cur_off = 0;
+            if (cur_off != expect_off ||
+                (cur_off && cur_size != expect_size))
+                return -EEXIST;
+        }
+        end = lseek(v->dat_fd, 0, SEEK_END);
+        if (end < 0) return -errno;
+        if (end + len > limit) return -EFBIG;
+        if (!pwrite_full(v->dat_fd, blob, (size_t)len, end)) return -errno;
+    }
+    std::unique_lock<std::shared_mutex> lk(v->nm.mu);
+    if (size == kTombstone) {
+        if (!append_idx_entry(v.get(), nid, (uint64_t)end, kTombstone))
+            return -(errno ? errno : EIO);
+        v->nm.apply(nid, 0, kTombstone);
+        return end;
+    }
+    uint64_t cur_off;
+    int32_t cur_size;
+    if (v->nm.get(nid, &cur_off, &cur_size) && cur_off >= (uint64_t)end)
+        return end;
+    if (!append_idx_entry(v.get(), nid, (uint64_t)end, (int32_t)size))
+        return -(errno ? errno : EIO);
+    v->nm.apply(nid, (uint64_t)end, (int32_t)size);
     return end;
 }
 

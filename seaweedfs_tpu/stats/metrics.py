@@ -308,6 +308,15 @@ VolumeServerReplicateCounter = REGISTRY.counter(
     "copy, returned without asking; asked = the master was looked up "
     "for the volume's locations; fanned_out = every other holder took "
     "the write", ("decision",))
+VolumeLockSecondsCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_volume_lock_seconds_total",
+    "seconds the served needle methods of a volume (write, read, "
+    "delete) spent at Volume.lock: wait = asking for it until it was "
+    "had, held = had until released", ("op", "phase"))
+VolumeLockCounter = REGISTRY.counter(
+    "SeaweedFS_volumeServer_volume_lock_total",
+    "acquisitions of Volume.lock by the served needle methods",
+    ("op",))
 VolumeServerThrottleRejects = REGISTRY.counter(
     "SeaweedFS_volumeServer_throttle_rejects_total",
     "requests rejected (429) by the in-flight byte throttles",

@@ -9,6 +9,7 @@ same interface later.
 from __future__ import annotations
 
 import os
+import threading
 
 from ..util import faults as _faults
 
@@ -168,17 +169,22 @@ class TieredFile:
         self._name = name
         self._cache: "OrderedDict[int, bytes]" = OrderedDict()
         self._cache_blocks = cache_blocks
+        # a volume's reads run outside Volume.lock, so the LRU guards
+        # itself; a fetch is made under it, one at a time as before
+        self._lock = threading.Lock()
 
     def _block(self, index: int) -> bytes:
-        if index in self._cache:
-            self._cache.move_to_end(index)
-            return self._cache[index]
-        offset = index * self.BLOCK
-        data = self._fetch(offset, min(self.BLOCK, self._size - offset))
-        self._cache[index] = data
-        if len(self._cache) > self._cache_blocks:
-            self._cache.popitem(last=False)
-        return data
+        with self._lock:
+            if index in self._cache:
+                self._cache.move_to_end(index)
+                return self._cache[index]
+            offset = index * self.BLOCK
+            data = self._fetch(offset,
+                               min(self.BLOCK, self._size - offset))
+            self._cache[index] = data
+            if len(self._cache) > self._cache_blocks:
+                self._cache.popitem(last=False)
+            return data
 
     def read_at(self, size: int, offset: int) -> bytes:
         if offset >= self._size:

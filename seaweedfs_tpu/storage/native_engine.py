@@ -18,6 +18,7 @@ Set SW_NATIVE=0 to disable the engine even when the library builds.
 from __future__ import annotations
 
 import ctypes
+import errno
 import functools
 import os
 import threading
@@ -56,7 +57,6 @@ def lib() -> Optional[ctypes.CDLL]:
     cdll.svn_set_flags.argtypes = [_i64, ctypes.c_int, ctypes.c_int]
     cdll.svn_serve.argtypes = [_u32, _i64]
     cdll.svn_nm_put.argtypes = [_i64, _u64, _u64, _i64]
-    cdll.svn_nm_put_if_newer.argtypes = [_i64, _u64, _u64, _i64]
     cdll.svn_nm_delete.argtypes = [_i64, _u64, _u64]
     cdll.svn_nm_set_memory.argtypes = [_i64, _u64, _u64, _i64]
     cdll.svn_nm_get.argtypes = [_i64, _u64, ctypes.POINTER(_u64),
@@ -64,8 +64,9 @@ def lib() -> Optional[ctypes.CDLL]:
     cdll.svn_nm_stats.argtypes = [_i64, ctypes.POINTER(_i64)]
     cdll.svn_nm_visit.restype = _i64
     cdll.svn_nm_visit.argtypes = [_i64, ctypes.POINTER(_i64), _i64]
-    cdll.svn_append.restype = _i64
-    cdll.svn_append.argtypes = [_i64, ctypes.c_char_p, _i64]
+    cdll.svn_append_put.restype = _i64
+    cdll.svn_append_put.argtypes = [_i64, ctypes.c_void_p, _i64, _u64, _i64,
+                                    _u64, _i64, _i64]
     cdll.svn_size.restype = _i64
     cdll.svn_size.argtypes = [_i64]
     cdll.svn_sync.argtypes = [_i64]
@@ -152,17 +153,6 @@ class NativeNeedleMap:
     def put(self, nid: int, offset: int, size: int):
         self._lib.svn_nm_put(self.handle, nid, offset, size)
 
-    def put_if_newer(self, nid: int, offset: int, size: int) -> bool:
-        """Atomic form of the write path's "newer offset wins" guard
-        (volume_write.go:160-165): evaluated under the engine's map lock
-        so a racing native-port write cannot be clobbered.  Raises
-        OSError when the .idx append failed (ENOSPC/EIO) — the write
-        must fail before it is acknowledged, not vanish on restart."""
-        rc = self._lib.svn_nm_put_if_newer(self.handle, nid, offset, size)
-        if rc < 0:
-            raise OSError(-rc, "idx append failed")
-        return rc == 1
-
     def delete(self, nid: int, offset: int):
         rc = self._lib.svn_nm_delete(self.handle, nid, offset)
         if rc < 0:
@@ -236,12 +226,34 @@ class NativeNeedleMap:
             fn(nid, nv)
 
     # -- append path ---------------------------------------------------------
-    def append_dat(self, blob: bytes) -> int:
+    def append_put(self, blob: "bytes | bytearray", nid: int, size: int,
+                   expect: Optional[NeedleValue],
+                   limit: int) -> Optional[int]:
         """Append a record to the .dat under the engine's shared write
-        mutex; returns the landing offset."""
-        off = self._lib.svn_append(self.handle, blob, len(blob))
+        mutex and point the map at it, in one call (one hand-back of
+        the GIL).  Under that mutex, where the offset is allocated: the
+        map's entry for `nid` must still be `expect` (None: no entry),
+        the one the caller decided against, else nothing is written and
+        None sends the caller to decide again; a record that would end
+        past `limit` is refused (OSError EFBIG).  Then for a record of
+        `size` the write path's "newer offset wins" guard
+        (volume_write.go:160-165), evaluated under the engine's map
+        lock so a racing native-port write cannot be clobbered; for
+        TOMBSTONE_FILE_SIZE delete()'s entry.  Returns the landing
+        offset.  A bytearray (the write path's stamped record) is
+        handed over in place, not copied.  Raises OSError when the
+        append or the .idx append failed (ENOSPC/EIO) — the write must
+        fail before it is acknowledged, not vanish on restart."""
+        buf = blob if isinstance(blob, bytes) else \
+            ctypes.byref(ctypes.c_char.from_buffer(blob))
+        off = self._lib.svn_append_put(
+            self.handle, buf, len(blob), nid, size,
+            expect.offset if expect else 0, expect.size if expect else 0,
+            limit)
+        if off == -errno.EEXIST:
+            return None
         if off < 0:
-            raise OSError(-off, "native append failed")
+            raise OSError(-off, "native append or idx append failed")
         return off
 
     def touch(self, append_ns: int, modified_ts: int):

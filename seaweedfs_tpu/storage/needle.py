@@ -182,6 +182,12 @@ class Needle:
 
     def to_bytes(self, version: int = CURRENT_VERSION) -> bytes:
         """Full on-disk record (header..padding); sets self.size."""
+        return bytes(self.to_record(version))
+
+    def to_record(self, version: int = CURRENT_VERSION) -> bytearray:
+        """The record of to_bytes, built once into a buffer that
+        stamp_record may still write appendAtNs into: the write path
+        builds outside the volume's lock and stamps under it."""
         self.size = self._computed_size(version)
         out = bytearray()
         out += t.cookie_to_bytes(self.cookie)
@@ -210,7 +216,18 @@ class Needle:
         if version == VERSION3:
             out += struct.pack(">Q", self.append_at_ns)
         out += b"\x00" * padding_length(self.size, version)
-        return bytes(out)
+        return out
+
+    def stamp_record(self, record: bytearray, append_at_ns: int,
+                     version: int = CURRENT_VERSION):
+        """Set appendAtNs on the needle and, in place, in its built
+        record (v3: the 8 bytes between the CRC and the padding)."""
+        self.append_at_ns = append_at_ns
+        if version == VERSION3:
+            struct.pack_into(
+                ">Q", record,
+                t.NEEDLE_HEADER_SIZE + self.size + t.NEEDLE_CHECKSUM_SIZE,
+                append_at_ns)
 
     # -- parsing --------------------------------------------------------------
     def parse_header(self, b: bytes):

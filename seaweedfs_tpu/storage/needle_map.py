@@ -29,6 +29,7 @@ from __future__ import annotations
 import io
 import os
 import sqlite3
+import threading
 from typing import Callable, Iterator, Optional
 
 import numpy as np
@@ -252,9 +253,12 @@ class CompactNeedleMap(BaseNeedleMap):
     _MERGE_MIN = 4096
 
     def __init__(self, index_path: Optional[str] = None):
-        self._keys = np.empty(0, dtype=np.uint64)
-        self._offs = np.empty(0, dtype=np.uint32)   # stored form (÷8)
-        self._sizes = np.empty(0, dtype=np.int32)
+        # (keys, stored offsets (÷8), sizes): one attribute, so that a
+        # merge publishes its three arrays at once to a lookup that runs
+        # beside it (the write path's, outside Volume.lock)
+        self._sorted = (np.empty(0, dtype=np.uint64),
+                        np.empty(0, dtype=np.uint32),
+                        np.empty(0, dtype=np.int32))
         self._overflow: dict[int, tuple[int, int]] = {}  # nid -> (stored, sz)
         super().__init__(index_path)
 
@@ -310,25 +314,32 @@ class CompactNeedleMap(BaseNeedleMap):
             + last_sizes[trailing].sum())
         self.max_key = max(self.max_key, int(keys.max()))
 
-        self._keys = uniq[valid]
-        self._offs = final_off
-        self._sizes = final_size
+        self._sorted = (uniq[valid], final_off, final_size)
+
+    _keys = property(lambda self: self._sorted[0])
+    _offs = property(lambda self: self._sorted[1])
+    _sizes = property(lambda self: self._sorted[2])
 
     # -- storage hooks ------------------------------------------------------
-    def _find_sorted(self, nid: int) -> int:
-        i = int(np.searchsorted(self._keys, np.uint64(nid)))
-        if i < self._keys.size and int(self._keys[i]) == nid:
+    @staticmethod
+    def _find(keys: np.ndarray, nid: int) -> int:
+        i = int(np.searchsorted(keys, np.uint64(nid)))
+        if i < keys.size and int(keys[i]) == nid:
             return i
         return -1
+
+    def _find_sorted(self, nid: int) -> int:
+        return self._find(self._keys, nid)
 
     def _get(self, nid):
         got = self._overflow.get(nid)
         if got is not None:
             return t.from_stored_offset(got[0]), got[1]
-        i = self._find_sorted(nid)
+        keys, offs, sizes = self._sorted
+        i = self._find(keys, nid)
         if i < 0:
             return None
-        return t.from_stored_offset(int(self._offs[i])), int(self._sizes[i])
+        return t.from_stored_offset(int(offs[i])), int(sizes[i])
 
     def _set(self, nid, offset, size):
         stored = t.to_stored_offset(offset)
@@ -367,9 +378,9 @@ class CompactNeedleMap(BaseNeedleMap):
         offs = np.concatenate([self._offs, ov_offs])
         sizes = np.concatenate([self._sizes, ov_sizes])
         order = np.argsort(keys, kind="stable")
-        self._keys = keys[order]
-        self._offs = offs[order]
-        self._sizes = sizes[order]
+        # published before the overflow is emptied: a lookup beside the
+        # merge finds a key in one of the two
+        self._sorted = (keys[order], offs[order], sizes[order])
         self._overflow.clear()
 
     def _visit_ascending(self):
@@ -405,8 +416,11 @@ class SqliteNeedleMap(BaseNeedleMap):
                  db_path: Optional[str] = None):
         if db_path is None:
             db_path = (index_path + ".sqlite") if index_path else ":memory:"
-        # volume-server handlers run on per-connection threads; access is
-        # serialised by Volume.lock, so cross-thread use is safe
+        # volume-server handlers run on per-connection threads, and a
+        # lookup may run outside Volume.lock beside a put under it: the
+        # connection is one at a time's (a commit resets every statement
+        # in flight on it), so the map guards it itself
+        self._mu = threading.Lock()
         self._db = sqlite3.connect(db_path, check_same_thread=False)
         self._db.execute("PRAGMA journal_mode=WAL")
         self._db.execute("PRAGMA synchronous=NORMAL")
@@ -429,7 +443,11 @@ class SqliteNeedleMap(BaseNeedleMap):
 
     def _load_from_idx(self, path: str):
         idx_size = os.path.getsize(path)
-        if self._meta("idx_size") == idx_size:
+        # the size alone does not say it is the same log: a vacuum's
+        # commit puts another file of the same length in its place when
+        # nothing was deleted since the last one
+        if (self._meta("idx_size") == idx_size
+                and self._meta("idx_ino") == os.stat(path).st_ino):
             # DB is current: restore metrics, skip the replay
             for attr in ("file_count", "deleted_count", "deleted_bytes",
                          "content_bytes", "max_key"):
@@ -447,11 +465,15 @@ class SqliteNeedleMap(BaseNeedleMap):
                 self._index_file.flush()
             idx_size = (os.path.getsize(self.index_path)
                         if os.path.exists(self.index_path) else 0)
-        self._set_meta("idx_size", idx_size or 0)
-        for attr in ("file_count", "deleted_count", "deleted_bytes",
-                     "content_bytes", "max_key"):
-            self._set_meta(attr, getattr(self, attr))
-        self._db.commit()
+        with self._mu:
+            self._set_meta("idx_size", idx_size or 0)
+            self._set_meta("idx_ino", os.stat(self.index_path).st_ino
+                           if self.index_path
+                           and os.path.exists(self.index_path) else 0)
+            for attr in ("file_count", "deleted_count", "deleted_bytes",
+                         "content_bytes", "max_key"):
+                self._set_meta(attr, getattr(self, attr))
+            self._db.commit()
 
     @staticmethod
     def _sql_key(nid: int) -> int:
@@ -463,25 +485,28 @@ class SqliteNeedleMap(BaseNeedleMap):
         return k + (1 << 64) if k < 0 else k
 
     def _get(self, nid):
-        row = self._db.execute(
-            "SELECT off, size FROM needles WHERE key=?",
-            (self._sql_key(nid),)).fetchone()
+        with self._mu:
+            row = self._db.execute(
+                "SELECT off, size FROM needles WHERE key=?",
+                (self._sql_key(nid),)).fetchone()
         if row is None:
             return None
         return t.from_stored_offset(int(row[0])), int(row[1])
 
     def _set(self, nid, offset, size):
-        self._db.execute(
-            "INSERT INTO needles(key, off, size) VALUES(?, ?, ?) "
-            "ON CONFLICT(key) DO UPDATE SET off=excluded.off, "
-            "size=excluded.size",
-            (self._sql_key(nid), t.to_stored_offset(offset), size))
-        self._bump()
+        with self._mu:
+            self._db.execute(
+                "INSERT INTO needles(key, off, size) VALUES(?, ?, ?) "
+                "ON CONFLICT(key) DO UPDATE SET off=excluded.off, "
+                "size=excluded.size",
+                (self._sql_key(nid), t.to_stored_offset(offset), size))
+            self._bump()
 
     def _mark_deleted(self, nid):
-        self._db.execute("UPDATE needles SET size=-size WHERE key=?",
-                         (self._sql_key(nid),))
-        self._bump()
+        with self._mu:
+            self._db.execute("UPDATE needles SET size=-size WHERE key=?",
+                             (self._sql_key(nid),))
+            self._bump()
 
     def _bump(self):
         self._pending += 1
@@ -501,8 +526,9 @@ class SqliteNeedleMap(BaseNeedleMap):
                        t.from_stored_offset(int(off)), int(size))
 
     def __len__(self):
-        return int(self._db.execute(
-            "SELECT COUNT(*) FROM needles").fetchone()[0])
+        with self._mu:
+            return int(self._db.execute(
+                "SELECT COUNT(*) FROM needles").fetchone()[0])
 
     def flush(self):
         super().flush()
@@ -513,7 +539,8 @@ class SqliteNeedleMap(BaseNeedleMap):
         self._persist_meta(
             os.path.getsize(self.index_path)
             if self.index_path and os.path.exists(self.index_path) else 0)
-        self._db.close()
+        with self._mu:
+            self._db.close()
 
 
 _KINDS = {

@@ -114,7 +114,7 @@ def tier_upload(volume, backend_id: str, bucket: str,
             files=[remote])
         save_volume_info(volume.file_name(".vif"), vif)
         if not keep_local:
-            volume.data.close()
+            volume.close_data()
             volume.data = TieredFile(
                 lambda off, sz: client.read_range(loc, off, sz),
                 size, name=f"{backend_id}:{key}")
@@ -153,7 +153,7 @@ def tier_download(volume) -> int:
     # else: keep_local cache IS current (volume was sealed readonly)
     with volume.lock:
         if isinstance(volume.data, _TieredFile):
-            volume.data.close()
+            volume.close_data()
             volume.data = DiskFile(dat_path)
         volume.read_only = False
         vif.files = []

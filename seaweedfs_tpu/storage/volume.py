@@ -16,6 +16,7 @@ Semantics parity with the reference's weed/storage/volume*.go:
 
 from __future__ import annotations
 
+import errno
 import os
 import threading
 import time
@@ -31,6 +32,7 @@ from .needle_map import NeedleMap, new_needle_map
 from .super_block import SUPER_BLOCK_SIZE, ReplicaPlacement, SuperBlock
 from .ttl import EMPTY_TTL, TTL
 from .. import tracing
+from ..stats import metrics as stats
 
 
 class VolumeError(Exception):
@@ -111,7 +113,59 @@ class _FsyncBatcher:
         self._thread.join(timeout=5)
 
 
+_LOCK_LABELS = {op: ((op,), (op, "wait"), (op, "held"))
+                for op in ("write", "read", "delete")}
+
+
+def _entry(nv):
+    """A map entry as a value to compare; None where there is none."""
+    return (nv.offset, nv.size) if nv is not None and nv.offset else None
+
+
+class _LockSection:
+    """`with _LockSection(volume.lock, op):` is `with volume.lock:` for
+    the served needle methods, with its wait and its hold counted
+    (volumeServer_volume_lock_seconds_total{op,phase},
+    volumeServer_volume_lock_total{op}); the counters are brought up
+    after the release."""
+
+    __slots__ = ("_lock", "_labels", "_asked", "_had")
+
+    def __init__(self, lock, op: str):
+        self._lock = lock
+        self._labels = _LOCK_LABELS[op]
+
+    def __enter__(self):
+        self._asked = time.perf_counter()
+        self._lock.acquire()
+        self._had = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        released = time.perf_counter()
+        count, wait, held = self._labels
+        stats.VolumeLockCounter.inc(1.0, count)
+        stats.VolumeLockSecondsCounter.inc(self._had - self._asked, wait)
+        stats.VolumeLockSecondsCounter.inc(released - self._had, held)
+
+
 class Volume:
+    """What `lock` guards, and nothing else (every build, parse, CRC and
+    data read of a served needle runs outside it):
+      * an append: the refusals (read only, size limit) made at its
+        point, the appendAtNs stamp (it never decreases along the file:
+        volume_backup bisects on it), the offset's allocation and the
+        bytes landing at it, the map's put with "the newer offset wins";
+      * that the map entry a write or a delete decided against outside
+        the lock is still the entry, at the point of the append: the
+        record an overwrite's cookie and dedup were read from, a fresh
+        id's absence, the size a delete frees (else it decides again);
+      * a read's map lookup together with its hold on the data file
+        (`_locate`): a vacuum's commit, a tier move and close() take the
+        lock and then wait for those holds before they close the file;
+      * the swaps themselves (commit_compact, tier), sync, file_stat.
+    """
+
     def __init__(self, directory: str, collection: str, vid: int,
                  replica_placement: Optional[ReplicaPlacement] = None,
                  ttl: TTL = EMPTY_TTL, preallocate: int = 0,
@@ -123,6 +177,9 @@ class Volume:
         self.fsync = fsync
         self._batcher: Optional[_FsyncBatcher] = None
         self.lock = threading.RLock()
+        # reads in flight on self.data outside the lock (_locate)
+        self._readers = 0
+        self._readers_cv = threading.Condition(threading.Lock())
         self.data: Optional[DiskFile] = None
         self.nm: Optional[NeedleMap] = None
         self._last_append_at_ns = 0
@@ -187,12 +244,68 @@ class Volume:
     def last_modified_ts(self, value: int):
         self._last_modified_ts = value
 
-    def _append_blob(self, blob: bytes) -> int:
-        """Append one record to the .dat.  In native mode the engine's
-        per-volume mutex serializes this with TCP fast-path writes."""
-        if isinstance(self.nm, native_engine.NativeNeedleMap):
-            return self.nm.append_dat(blob)
-        return self.data.append(blob)
+    def _peek(self, nid: int):
+        """-> (the map, its entry for `nid`), read without the lock: a
+        hint that says what to check outside it.  Whoever appends hands
+        both to _append_stamped, which confirms them under the lock."""
+        nm = self.nm
+        try:
+            return nm, nm.get(nid)
+        except Exception:
+            # a vacuum's commit closed the map under the call: ask
+            # behind it
+            with self.lock:
+                return self.nm, self.nm.get(nid)
+
+    def _append_stamped(self, n: Needle, record: bytearray, size: int,
+                        nm, expect) -> Optional[int]:
+        """Under the lock, at the point of the append: refuse what a
+        seal, a demotion or the size limit has shut out; confirm that
+        `nm` is still the map and `expect` still its entry for the id
+        (None: no entry), which the caller decided against outside the
+        lock, else return None and the caller decides again; stamp the
+        built record, append it and point the map at it (`size`: the
+        needle's, or TOMBSTONE_FILE_SIZE for a delete's tombstone).
+        The limit is held against the file's end where the offset is
+        allocated, whoever wrote the file up to there."""
+        if self.read_only:
+            raise VolumeError(f"volume {self.id} is read only")
+        if self.nm is not nm:
+            return None  # a vacuum's commit swapped the map
+        n.stamp_record(record,
+                       max(time.time_ns(), self._last_append_at_ns),
+                       self.version)
+        limit = t.MAX_POSSIBLE_VOLUME_SIZE
+        try:
+            if isinstance(nm, native_engine.NativeNeedleMap):
+                # one call into the engine: the confirmation, the limit
+                # and the append under its per-volume mutex, which
+                # serializes them with TCP fast-path writes, and "the
+                # newer offset wins" under its map lock, where a
+                # native-port write to the same id may have landed since
+                offset = nm.append_put(record, n.id, size, expect, limit)
+                if offset is None:
+                    return None
+            else:
+                if _entry(nm.get(n.id)) != _entry(expect):
+                    return None
+                # every append of a Python map is made here, under the
+                # lock: this offset is the newest
+                offset = self.data.size()
+                if offset + len(record) > limit:
+                    raise OSError(errno.EFBIG, "past the size limit")
+                self.data.write_at(record, offset)
+                if size == t.TOMBSTONE_FILE_SIZE:
+                    nm.delete(n.id, offset)
+                else:
+                    nm.put(n.id, offset, size)
+        except OSError as e:
+            if e.errno != errno.EFBIG:
+                raise
+            raise VolumeError(
+                f"volume size limit {limit} exceeded") from None
+        self._last_append_at_ns = n.append_at_ns
+        return offset
 
     def _native_writable(self) -> bool:
         """Whether the native fast path may write this volume directly.
@@ -346,15 +459,12 @@ class Volume:
         return append_at_ns
 
     # -- write ---------------------------------------------------------------
-    def _is_file_unchanged(self, n: Needle) -> bool:
-        if self.ttl:
-            return False
-        nv = self.nm.get(n.id)
-        if nv is None or nv.offset == 0 or not t.size_is_valid(nv.size):
+    def _is_file_unchanged(self, n: Needle, nv, data) -> bool:
+        if self.ttl or nv.offset == 0 or not t.size_is_valid(nv.size):
             return False
         old = Needle()
         try:
-            blob = self.data.read_at(
+            blob = data.read_at(
                 get_actual_size(nv.size, self.version), nv.offset)
             old.read_bytes(blob, nv.offset, nv.size, self.version)
         except (NeedleError, Exception):
@@ -362,43 +472,56 @@ class Volume:
         return (old.cookie == n.cookie and old.checksum == n.checksum
                 and old.data == n.data)
 
+    def _check_against_existing(self, n: Needle, check_cookie: bool):
+        """An id the map knows: the dedup and the cookie rule, read from
+        the record outside the lock.  Returns the entry they were read
+        for (the append confirms it under the lock) and whether the
+        needle is a byte-identical re-write; None if the entry went
+        away meanwhile."""
+        try:
+            nv, data = self._locate(n.id, "write", deleted_ok=True)
+        except NotFoundError:
+            return None, False
+        try:
+            if self._is_file_unchanged(n, nv, data):
+                return nv, True
+            header = data.read_at(t.NEEDLE_HEADER_SIZE, nv.offset)
+        finally:
+            self._release_data()
+        existing, _ = read_needle_header(header)
+        if n.cookie == 0 and not check_cookie:
+            n.cookie = existing.cookie
+        if existing.cookie != n.cookie:
+            raise CookieMismatchError(f"mismatching cookie {n.cookie:x}")
+        return nv, False
+
     def write_needle(self, n: Needle, check_cookie: bool = True
                      ) -> tuple[int, int, bool]:
         """Append a needle; returns (offset, size, is_unchanged)."""
-        with self.lock:
+        if not n.has_ttl and self.ttl:
+            n.ttl = self.ttl
+            n._set_flag(0x10)
+        while True:
             if self.read_only:
                 raise VolumeError(f"volume {self.id} is read only")
-            actual = get_actual_size(len(n.data), self.version)
-            if self.nm.content_size() + actual > t.MAX_POSSIBLE_VOLUME_SIZE:
-                raise VolumeError(
-                    f"volume size limit {t.MAX_POSSIBLE_VOLUME_SIZE} exceeded")
-            if not n.has_ttl and self.ttl:
-                n.ttl = self.ttl
-                n._set_flag(0x10)
-            if self._is_file_unchanged(n):
-                return 0, len(n.data), True
-            nv = self.nm.get(n.id)
+            # one lookup serves the dedup and the cookie rule; a fresh
+            # id finds nothing and goes straight to its build
+            nm, nv = self._peek(n.id)
             if nv is not None:
-                header = self.data.read_at(t.NEEDLE_HEADER_SIZE, nv.offset)
-                existing, _ = read_needle_header(header)
-                if n.cookie == 0 and not check_cookie:
-                    n.cookie = existing.cookie
-                if existing.cookie != n.cookie:
-                    raise CookieMismatchError(
-                        f"mismatching cookie {n.cookie:x}")
-            n.append_at_ns = time.time_ns()
-            blob = n.to_bytes(self.version)
-            offset = self._append_blob(blob)
-            self.last_append_at_ns = n.append_at_ns
-            if isinstance(self.nm, native_engine.NativeNeedleMap):
-                # the "newer offset wins" check must read the map under
-                # its own lock: a native-port write to the same id may
-                # have landed after our pre-append lookup
-                self.nm.put_if_newer(n.id, offset, n.size)
-            elif nv is None or nv.offset < offset:
-                self.nm.put(n.id, offset, n.size)
-            if n.last_modified > self.last_modified_ts:
-                self.last_modified_ts = n.last_modified
+                nv, unchanged = self._check_against_existing(
+                    n, check_cookie)
+                if unchanged:
+                    return 0, len(n.data), True
+            record = n.to_record(self.version)
+            with _LockSection(self.lock, "write"):
+                offset = self._append_stamped(n, record, n.size, nm, nv)
+                if offset is None:
+                    # the entry (or its absence) that was decided
+                    # against is no longer the map's: decide again
+                    continue
+                if n.last_modified > self._last_modified_ts:
+                    self._last_modified_ts = n.last_modified
+            break
         if self.fsync:
             # outside the lock: other writers append while this one waits
             # for the shared group-commit fsync
@@ -408,44 +531,77 @@ class Volume:
 
     def delete_needle(self, n: Needle) -> int:
         """Tombstone-append; returns the freed size (0 if absent)."""
-        with self.lock:
+        n.data = b""
+        record = n.to_record(self.version)
+        while True:
             if self.read_only:
                 raise VolumeError(f"volume {self.id} is read only")
-            nv = self.nm.get(n.id)
+            nm, nv = self._peek(n.id)
             if nv is None or not t.size_is_valid(nv.size):
                 return 0
-            size = nv.size
-            n.data = b""
-            n.append_at_ns = time.time_ns()
-            blob = n.to_bytes(self.version)
-            offset = self._append_blob(blob)
-            self.last_append_at_ns = n.append_at_ns
-            self.nm.delete(n.id, offset)
+            with _LockSection(self.lock, "delete"):
+                if self._append_stamped(n, record, t.TOMBSTONE_FILE_SIZE,
+                                        nm, nv) is not None:
+                    break
         if self.fsync:
             with tracing.span("fsync.group_commit", tags={"vid": self.id}):
                 self._fsync_batcher().wait_durable()
-        return size
+        return nv.size
 
     # -- read ----------------------------------------------------------------
-    def read_needle(self, nid: int, cookie: Optional[int] = None) -> Needle:
-        with self.lock:
+    def _locate(self, nid: int, op: str = "read",
+                deleted_ok: bool = False):
+        """The one visit a read pays to the lock: the map's entry and
+        the data file it points into, held open until _release_data()
+        (whoever closes the file waits for that, under the lock)."""
+        with _LockSection(self.lock, op):
             nv = self.nm.get(nid)
             if nv is None or nv.offset == 0:
                 raise NotFoundError(f"needle {nid:x} not found")
-            if t.size_is_deleted(nv.size):
+            if t.size_is_deleted(nv.size) and not deleted_ok:
                 raise DeletedError(f"needle {nid:x} already deleted")
-            blob = self.data.read_at(
+            with self._readers_cv:
+                self._readers += 1
+            return nv, self.data
+
+    def _release_data(self):
+        with self._readers_cv:
+            self._readers -= 1
+            if not self._readers:
+                self._readers_cv.notify_all()
+
+    def close_data(self):
+        """Close the data file once the reads in flight on it are done.
+        The caller holds the lock, so no read can begin meanwhile."""
+        with self._readers_cv:
+            while self._readers:
+                self._readers_cv.wait()
+        self.data.close()
+
+    def _check_cookie_and_expiry(self, n: Needle, cookie: Optional[int]):
+        if cookie is not None and n.cookie != cookie:
+            raise CookieMismatchError(
+                f"cookie mismatch for needle {n.id:x}")
+        if n.has_ttl and self.ttl and n.last_modified:
+            expiry = n.last_modified + self.ttl.minutes() * 60
+            if time.time() >= expiry:
+                raise NotFoundError(f"needle {n.id:x} expired")
+
+    def read_needle(self, nid: int, cookie: Optional[int] = None,
+                    with_entry: bool = False):
+        """-> the needle; `with_entry`: (the needle, the map entry it
+        was read at), which is what a cache pins the needle to without
+        asking the map again."""
+        nv, data = self._locate(nid)
+        try:
+            blob = data.read_at(
                 get_actual_size(nv.size, self.version), nv.offset)
-            n = Needle()
-            n.read_bytes(blob, nv.offset, nv.size, self.version)
-            if cookie is not None and n.cookie != cookie:
-                raise CookieMismatchError(
-                    f"cookie mismatch for needle {nid:x}")
-            if n.has_ttl and self.ttl and n.last_modified:
-                expiry = n.last_modified + self.ttl.minutes() * 60
-                if time.time() >= expiry:
-                    raise NotFoundError(f"needle {nid:x} expired")
-            return n
+        finally:
+            self._release_data()
+        n = Needle()
+        n.read_bytes(blob, nv.offset, nv.size, self.version)
+        self._check_cookie_and_expiry(n, cookie)
+        return (n, nv) if with_entry else n
 
     def read_needle_blob(self, offset: int, size: int) -> bytes:
         return self.data.read_at(get_actual_size(size, self.version), offset)
@@ -464,21 +620,17 @@ class Volume:
         swaps the .dat cannot invalidate an in-flight transfer."""
         from .needle import VERSION1, VERSION3
 
-        with self.lock:
-            if self.version == VERSION1:
-                return None
-            fileno = getattr(self.data, "fileno", None)
+        if self.version == VERSION1:
+            return None
+        nv, data = self._locate(nid)
+        try:
+            fileno = getattr(data, "fileno", None)
             raw_fd = fileno() if fileno is not None else None
             if raw_fd is None:
                 return None  # remote tier (or closed handle)
-            nv = self.nm.get(nid)
-            if nv is None or nv.offset == 0:
-                raise NotFoundError(f"needle {nid:x} not found")
-            if t.size_is_deleted(nv.size):
-                raise DeletedError(f"needle {nid:x} already deleted")
             if nv.size <= 0:
                 return None  # empty payload: nothing to sendfile
-            head = self.data.read_at(t.NEEDLE_HEADER_SIZE + 4, nv.offset)
+            head = data.read_at(t.NEEDLE_HEADER_SIZE + 4, nv.offset)
             if len(head) < t.NEEDLE_HEADER_SIZE + 4:
                 raise NotFoundError(f"needle {nid:x}: truncated record")
             n = Needle()
@@ -495,7 +647,7 @@ class Volume:
             if self.version == VERSION3:
                 tail_len += t.TIMESTAMP_SIZE
             tail_off = nv.offset + t.NEEDLE_HEADER_SIZE + 4 + data_size
-            tail = self.data.read_at(tail_len, tail_off)
+            tail = data.read_at(tail_len, tail_off)
             if len(tail) < tail_len:
                 raise NotFoundError(f"needle {nid:x}: truncated record")
             # a synthetic zero-length dataSize prefix parses just the
@@ -507,16 +659,12 @@ class Volume:
             n.checksum = int.from_bytes(tail[meta_len:meta_len + 4], "big")
             if self.version == VERSION3:
                 n.append_at_ns = int.from_bytes(tail[meta_len + 4:], "big")
-            if cookie is not None and n.cookie != cookie:
-                raise CookieMismatchError(
-                    f"cookie mismatch for needle {nid:x}")
+            self._check_cookie_and_expiry(n, cookie)
             if n.is_compressed or n.is_chunk_manifest:
                 return None  # the response path needs these in memory
-            if n.has_ttl and self.ttl and n.last_modified:
-                expiry = n.last_modified + self.ttl.minutes() * 60
-                if time.time() >= expiry:
-                    raise NotFoundError(f"needle {nid:x} expired")
             fd = os.dup(raw_fd)
+        finally:
+            self._release_data()
         return n, nv.offset + t.NEEDLE_HEADER_SIZE + 4, data_size, fd
 
     # -- scan (export/fsck support; volume_read.go:213-232) ------------------
@@ -641,7 +789,7 @@ class Volume:
                     self.nm.set_flags(writable=self._native_writable())
                 raise
             self.nm.close()
-            self.data.close()
+            self.close_data()
             os.replace(self.file_name(".cpd"), self.file_name(".dat"))
             os.replace(self.file_name(".cpx"), self.file_name(".idx"))
             self._load(create_if_missing=False)
@@ -718,7 +866,7 @@ class Volume:
             if self.nm is not None:
                 self.nm.close()
             if self.data is not None:
-                self.data.close()
+                self.close_data()
 
     def destroy(self):
         with self.lock:

@@ -329,6 +329,10 @@ class VolumeServer:
         # a scrape of a window without a write reads 0, not "no sample"
         for decision in ("single_copy", "asked"):
             stats.VolumeServerReplicateCounter.labels(decision).inc(0)
+        for op in ("write", "read", "delete"):
+            stats.VolumeLockCounter.labels(op).inc(0)
+            for phase in ("wait", "held"):
+                stats.VolumeLockSecondsCounter.labels(op, phase).inc(0)
         self.server.start()
         if self.enable_tcp:
             self._start_tcp()
@@ -1114,15 +1118,21 @@ class VolumeServer:
             # (volume_server_handlers_read.go:30-70)
             return self._read_nonlocal(vid, method, req, fid)
         self._refresh_worker_view(v)
-        n = self._cached_needle(v, vid, nid, cookie)
+        # one probe of the map serves both that want it: a cached
+        # needle's validation and the zero-copy path's size rule
+        nv = v.nm.get(nid) if v is not None else None
+        n = self._cached_needle(v, vid, nid, cookie, nv)
         if n is None:
-            resp = self._sendfile_read(v, vid, nid, cookie, method, req)
+            resp = self._sendfile_read(v, nv, nid, cookie, method, req)
             if resp is not None:
                 return resp
-        if n is None:
-            nv_before = v.nm.get(nid) if v is not None else None
+            nv_read = None
             try:
-                n = self.store.read_needle(vid, nid, cookie=cookie)
+                if v is not None:
+                    n, nv_read = v.read_needle(nid, cookie=cookie,
+                                               with_entry=True)
+                else:
+                    n = self.store.read_needle(vid, nid, cookie=cookie)
             except (NotFoundError, EcNotFoundError):
                 n = self._retry_after_idx_refresh(v, vid, nid, cookie)
                 if n is None:
@@ -1131,7 +1141,7 @@ class VolumeServer:
                 raise RpcError("already deleted", 404)
             except (CookieMismatchError,) as e:
                 raise RpcError(str(e), 404)
-            self._fill_needle_cache(v, vid, nid, n, nv_before)
+            self._fill_needle_cache(v, vid, nid, n, nv_read)
         if not self.download_gate.acquire(len(n.data)):
             stats.VolumeServerThrottleRejects.labels("download").inc()
             raise RpcError("too many requests: download limit", 429)
@@ -1180,22 +1190,26 @@ class VolumeServer:
         except (VolumeError, EcNotFoundError, EcDeletedError):
             return None
 
-    def _sendfile_read(self, v, vid: int, nid: int, cookie: int,
+    def _sendfile_read(self, v, nv, nid: int, cookie: int,
                        method: str, req: Request):
         """Zero-copy GET: a big uncompressed needle goes straight from
         the .dat to the client socket via sendfile — the payload never
         enters Python.  Small needles (below WEED_SENDFILE_MIN) keep the
         buffered path so they still populate the RAM needle cache, which
-        is faster for them than a syscall round trip.  Returns None to
-        fall back to the buffered path (which also owns all error
-        reporting: any storage error here falls through to it)."""
-        if v is None or not sendfile_enabled():
+        is faster for them than a syscall round trip: `nv`, the map's
+        entry as the caller just read it, says so before the volume is
+        visited at all (a record's size bounds its data's).  Returns
+        None to fall back to the buffered path (which also owns all
+        error reporting: any storage error here falls through to it)."""
+        if v is None or nv is None or not sendfile_enabled():
             return None
         try:
             min_size = int(
                 os.environ.get("WEED_SENDFILE_MIN", "") or 65536)
         except ValueError:
             min_size = 65536
+        if nv.size < min_size:
+            return None
         try:
             sliced = v.read_needle_slice(nid, cookie, min_size=min_size)
         except VolumeError:
@@ -1259,13 +1273,14 @@ class VolumeServer:
                 os.close(fd)
             raise
 
-    def _cached_needle(self, v, vid: int, nid: int, cookie: int):
+    def _cached_needle(self, v, vid: int, nid: int, cookie: int, nv):
         """Serve a needle read out of the unified read cache when the
-        live needle map still agrees with the cached (offset, size) —
-        overwrites, deletes and vacuum offset shifts all change the
-        map, so a stale entry self-invalidates even for writes that
-        arrive on the native TCP path (defense in depth on top of the
-        explicit invalidation hooks)."""
+        live needle map (`nv`, its entry as just read) still agrees
+        with the cached (offset, size) — overwrites, deletes and vacuum
+        offset shifts all change the map, so a stale entry
+        self-invalidates even for writes that arrive on the native TCP
+        path (defense in depth on top of the explicit invalidation
+        hooks)."""
         if v is None or v.ttl:  # EC reads and TTL expiry go to the store
             return None
         key = f"{vid},{nid:x}"
@@ -1273,7 +1288,6 @@ class VolumeServer:
         if cached is None:
             return None
         n, off, size = cached
-        nv = v.nm.get(nid)
         if nv is None or nv.offset != off or nv.size != size:
             self.read_cache.invalidate(key, reason="stale")
             return None
@@ -1281,16 +1295,11 @@ class VolumeServer:
             raise RpcError(f"cookie mismatch for needle {nid:x}", 404)
         return n
 
-    def _fill_needle_cache(self, v, vid: int, nid: int, n: Needle,
-                           nv_before):
-        """Admit a freshly-read needle, pinned to the (offset, size) it
-        was read at; a concurrent overwrite between the read and this
-        fill shows up as a map probe mismatch and skips the fill."""
-        if v is None or v.ttl:
-            return
-        nv = v.nm.get(nid)
-        if nv is None or nv_before is None or \
-                nv.offset != nv_before.offset or nv.size != nv_before.size:
+    def _fill_needle_cache(self, v, vid: int, nid: int, n: Needle, nv):
+        """Admit a freshly-read needle, pinned to the map entry
+        (offset, size) the volume read it at; an overwrite since then
+        shows up as a map probe mismatch at the next hit."""
+        if v is None or v.ttl or nv is None:
             return
         self.read_cache.put(f"{vid},{nid:x}", (n, nv.offset, nv.size),
                             nbytes=len(n.data))
