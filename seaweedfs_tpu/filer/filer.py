@@ -18,6 +18,8 @@ import time
 import uuid
 from typing import Callable, Optional
 
+from .. import tracing
+from ..stats import metrics as stats
 from ..util.log_buffer import LogBuffer
 from .entry import Attr, Entry, FileChunk, new_directory_entry
 from .filer_store import FilerStore, MemoryStore, NotFoundError
@@ -25,6 +27,38 @@ from .filer_store import FilerStore, MemoryStore, NotFoundError
 LOG_BUFFER_CAPACITY = 10000
 SYSTEM_LOG_DIR = "/topics/.system/log"  # filer_notify.go SystemLogDir
 HARDLINK_DIR = "/etc/.hardlinks"  # hardlink indirection records
+_STAGES = stats.FILER_STAGES
+
+
+class _LockSection:
+    """`with _LockSection(filer.lock):` is `with filer.lock:` for the
+    three mutations (create_entry, update_entry, delete_entry), with its
+    wait and its hold handed to filer_stage_seconds_total{stage=
+    "lock_wait"|"lock_held"} after the release.  The lock is re-entrant:
+    a mutation that calls another (a hardlink's, the change log's flush)
+    is counted again for the inner visit."""
+
+    __slots__ = ("_lock", "_asked", "_had")
+
+    def __init__(self, lock):
+        self._lock = lock
+
+    def __enter__(self):
+        self._asked = time.perf_counter()
+        self._lock.acquire()
+        self._had = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self._lock.release()
+        released = time.perf_counter()
+        _STAGES.add("lock_wait", self._had - self._asked)
+        _STAGES.add("lock_held", released - self._had)
+
+
+def _store_write():
+    """The store's statement with its commit, as a stage."""
+    return tracing.span("filer.store_write", add=_STAGES.add,
+                        key="store_write")
 
 
 class MetaEvent:
@@ -73,29 +107,31 @@ class Filer:
                 new_entry: Optional[Entry]):
         if (directory + "/").startswith(SYSTEM_LOG_DIR + "/"):
             return  # never log the log (filer_notify.go:21 guard)
-        # strictly-monotonic event timestamps so since_ns cursors never skip
-        ts = time.time_ns()
-        if ts <= self._last_event_ns:
-            ts = self._last_event_ns + 1
-        self._last_event_ns = ts
-        event = MetaEvent(
-            directory,
-            old_entry.to_dict() if old_entry else None,
-            new_entry.to_dict() if new_entry else None, ts_ns=ts)
-        record = event.to_dict()
-        sigs = getattr(self._sig_local, "value", None)
-        if sigs:
-            record["signatures"] = list(sigs)
-        self._log_buffer.add(ts, record)
-        if self.notification_queue is not None:
-            key = ((new_entry or old_entry).full_path
-                   if (new_entry or old_entry) else directory)
-            try:
-                self.notification_queue.send(key, record)
-            except Exception as e:  # a broken sink must not fail writes
-                from ..util import glog
+        with tracing.span("filer.notify", add=_STAGES.add, key="notify"):
+            # strictly-monotonic event timestamps so since_ns cursors
+            # never skip
+            ts = time.time_ns()
+            if ts <= self._last_event_ns:
+                ts = self._last_event_ns + 1
+            self._last_event_ns = ts
+            event = MetaEvent(
+                directory,
+                old_entry.to_dict() if old_entry else None,
+                new_entry.to_dict() if new_entry else None, ts_ns=ts)
+            record = event.to_dict()
+            sigs = getattr(self._sig_local, "value", None)
+            if sigs:
+                record["signatures"] = list(sigs)
+            self._log_buffer.add(ts, record)
+            if self.notification_queue is not None:
+                key = ((new_entry or old_entry).full_path
+                       if (new_entry or old_entry) else directory)
+                try:
+                    self.notification_queue.send(key, record)
+                except Exception as e:  # a broken sink must not fail writes
+                    from ..util import glog
 
-                glog.errorf("notification send %s: %s", key, e)
+                    glog.errorf("notification send %s: %s", key, e)
 
     def enable_meta_log(self, background: bool = True):
         """Turn on persistence of the change log into date-partitioned
@@ -264,16 +300,19 @@ class Filer:
     # -- CRUD ----------------------------------------------------------------
     def create_entry(self, entry: Entry):
         pending: list[FileChunk] = []
-        with self.lock:
+        with _LockSection(self.lock):
             self._ensure_parents(entry.parent)
             old = self._find_or_none(entry.full_path)
             if old is not None and old.is_directory and not entry.is_directory:
                 raise ValueError(
                     f"{entry.full_path} is a directory")
-            self.store.insert_entry(entry)
+            with _store_write():
+                self.store.insert_entry(entry)
             self._notify(entry.parent, old, entry)
             if old is None:
                 return
+            if not old.is_directory:
+                stats.FilerOverwriteCounter.inc()
             if old.hard_link_id:
                 # overwrote a hardlink pointer: drop its reference (even
                 # when both point at the same record — the new entry holds
@@ -342,7 +381,7 @@ class Filer:
             return None
 
     def update_entry(self, entry: Entry):
-        with self.lock:
+        with _LockSection(self.lock):
             old = self._find_or_none(entry.full_path)
             if old is not None and old.hard_link_id:
                 # write-through to the shared record so every link sees it
@@ -353,7 +392,8 @@ class Filer:
                 entry = Entry(full_path=entry.full_path, attr=entry.attr,
                               extended=entry.extended,
                               hard_link_id=old.hard_link_id)
-            self.store.update_entry(entry)
+            with _store_write():
+                self.store.update_entry(entry)
             self._notify(entry.parent, old, entry)
 
     def delete_entry(self, path: str, recursive: bool = False,
@@ -364,7 +404,7 @@ class Filer:
         out (the HTTP skipChunkDelete param, used by metadata-only
         restores).  Chunk-delete RPCs are issued after the filer lock is
         released — a slow volume server must not stall metadata ops."""
-        with self.lock:
+        with _LockSection(self.lock):
             pending = self._delete_entry_locked(path, recursive,
                                                 delete_chunks)
         self._reclaim(pending)
@@ -384,7 +424,8 @@ class Filer:
             self._delete_recursive(path, delete_chunks, pending)
             self.store.delete_entry(path)
         else:
-            self.store.delete_entry(path)
+            with _store_write():
+                self.store.delete_entry(path)
             if delete_chunks:
                 self._release_file(entry, pending)
         self._notify(entry.parent, entry, None)
@@ -394,7 +435,12 @@ class Filer:
         """Fire the chunk-delete callback (volume-server RPCs) — call
         with the filer lock RELEASED."""
         if chunks and self.on_delete_chunks:
-            self.on_delete_chunks(chunks)
+            stats.FilerReclaimedChunksCounter.inc(len(chunks))
+            stats.FilerReclaimedBytesCounter.inc(
+                sum(c.size for c in chunks))
+            with tracing.span("filer.reclaim", add=_STAGES.add,
+                              key="reclaim"):
+                self.on_delete_chunks(chunks)
 
     def _release_file(self, entry: Entry, pending: list[FileChunk]):
         """Collect a deleted file's reclaimable chunks into `pending`,

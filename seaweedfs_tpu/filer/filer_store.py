@@ -10,6 +10,7 @@ the reference's per-store test harness (filer/store_test/)."""
 
 from __future__ import annotations
 
+import contextlib
 import json
 import sqlite3
 import threading
@@ -140,6 +141,19 @@ class SqliteStore(FilerStore):
 
     def forget_connections(self):
         self._local = threading.local()
+
+    @contextlib.contextmanager
+    def transaction(self):
+        """This thread's statements inside the block under one commit (a
+        bulk load); outside one, every statement commits by itself."""
+        conn = self._conn()
+        conn.execute("BEGIN")
+        try:
+            yield
+        except BaseException:
+            conn.execute("ROLLBACK")
+            raise
+        conn.execute("COMMIT")
 
     def insert_entry(self, entry: Entry):
         self._conn().execute(

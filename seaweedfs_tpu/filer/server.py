@@ -46,8 +46,9 @@ from .meta_aggregator import MetaAggregator
 from ..cache import TieredReadCache
 
 DEFAULT_CHUNK_SIZE = 4 * 1024 * 1024  # filer -maxMB default (4MB)
-INLINE_LIMIT = 2048  # small-content inlining threshold
+INLINE_LIMIT = 2048  # -saveToFilerLimit where none is given
 _DEFAULT_PREFETCH = 4
+_READ_ATTEMPTS = 4  # a GET whose chunks are reclaimed under it reads again
 _STAGES = stats.FILER_STAGES
 
 
@@ -74,7 +75,8 @@ class FilerServer:
                  manifest_batch: int = MANIFEST_BATCH,
                  cipher: bool = False,
                  cache_dir: str = "",
-                 cache_disk_bytes: int = 1 << 30):
+                 cache_disk_bytes: int = 1 << 30,
+                 save_to_filer_limit: int = INLINE_LIMIT):
         # -master may name the whole raft trio ("a,b,c"): every
         # master call then fails over through the MasterClient (leader
         # hints, per-master breakers) instead of pinning one address
@@ -83,6 +85,10 @@ class FilerServer:
         self.master_address = self.masters[0]
         self._master_client = MasterClient(self.masters, name="filer")
         self.chunk_size = chunk_size
+        # a body of at most this many bytes is stored inside its entry
+        # (weed filer -saveToFilerLimit; upstream's default is 0: every
+        # body becomes a chunk on a volume server)
+        self.save_to_filer_limit = save_to_filer_limit
         self.replication = replication
         self.collection = collection
         # encrypt-at-rest: every uploaded chunk gets a fresh AES-256-GCM
@@ -325,7 +331,9 @@ class FilerServer:
         path = req.path or "/"
         if method in ("GET", "HEAD"):
             stats.FilerRequestCounter.labels("read").inc()
-            with stats.FilerRequestHistogram.labels("read").time():
+            with stats.FilerRequestHistogram.labels("read").time(), \
+                    tracing.span("filer.http_read", add=_STAGES.add,
+                                 key="http_read"):
                 return self._h_read(path, req, method)
         # mutations: stamp the caller's replication signature (if any) onto
         # the resulting metadata events so sync loops can break cycles
@@ -339,7 +347,9 @@ class FilerServer:
         try:
             if method in ("POST", "PUT"):
                 stats.FilerRequestCounter.labels("write").inc()
-                with stats.FilerRequestHistogram.labels("write").time():
+                with stats.FilerRequestHistogram.labels("write").time(), \
+                        tracing.span("filer.http_write", add=_STAGES.add,
+                                     key="http_write"):
                     return self._h_write(path, req)
             if method == "DELETE":
                 stats.FilerRequestCounter.labels("delete").inc()
@@ -605,7 +615,7 @@ class FilerServer:
             attr=Attr(mtime=now, crtime=now, mime=mime, md5=md5,
                       file_size=len(body), ttl_sec=ttl_sec),
             extended=extended or {})
-        if len(body) <= INLINE_LIMIT:
+        if len(body) <= self.save_to_filer_limit:
             entry.content = body
         else:
             offsets = list(range(0, len(body), self.chunk_size))
@@ -696,12 +706,11 @@ class FilerServer:
 
             def fetch(url):
                 def attempt():
-                    got = call(url, f"/{fid}", headers=headers,
-                               timeout=60)
-                    if isinstance(got, dict):
-                        raise RpcError(f"chunk {fid} fetch failed", 500,
-                                       addr=url, route=f"/{fid}")
-                    return bytes(got)
+                    # parse=False: a chunk is stored content, and a
+                    # needle whose mime is application/json must come
+                    # back as its bytes, not as a parsed object
+                    return bytes(call(url, f"/{fid}", headers=headers,
+                                      timeout=60, parse=False))
                 return attempt
 
             # hedged replica read: when the volume is replicated, a slow
@@ -1024,7 +1033,27 @@ class FilerServer:
             if "text/html" in (req.headers.get("Accept") or ""):
                 return self._render_ui(entry)  # browser surface
             return self._list_directory(entry, req)
+        for attempt in range(_READ_ATTEMPTS):
+            try:
+                return self._read_entry(entry, req, method)
+            except RpcError as e:
+                if e.status != 404 or attempt == _READ_ATTEMPTS - 1:
+                    raise
+                # a chunk is gone: an overwrite replaced the entry after
+                # the lookup and reclaimed what this read still held.
+                # The entry as it stands now is what a read that began
+                # before that write was acknowledged may answer with
+                try:
+                    fresh = self.filer.find_entry(path)
+                except NotFoundError:
+                    raise RpcError(f"{path} not found", 404)
+                if [c.fid for c in fresh.chunks] == \
+                        [c.fid for c in entry.chunks]:
+                    raise
+                stats.FilerReadRetryCounter.inc()
+                entry = fresh
 
+    def _read_entry(self, entry: Entry, req: Request, method: str):
         size = entry.size()
         start, length = 0, size
         status = 200
@@ -1292,7 +1321,8 @@ class FilerServer:
             # only ever see ciphertext, which the volume server cannot
             # produce from the plaintext remote object
             mapped = rs.mapped_location(self.filer, entry.full_path) \
-                if size > INLINE_LIMIT and not self.cipher else None
+                if size > self.save_to_filer_limit and not self.cipher \
+                else None
             if mapped is not None:
                 _, loc = mapped
                 conf = rs.load_remote_conf(self.filer, loc.name)
@@ -1334,7 +1364,7 @@ class FilerServer:
             data = rs.read_through(self.filer, entry)
             entry.attr.file_size = len(data)
             entry.attr.md5 = hashlib.md5(data).hexdigest()
-            if len(data) <= INLINE_LIMIT:
+            if len(data) <= self.save_to_filer_limit:
                 entry.content = data
             else:
                 offset = 0

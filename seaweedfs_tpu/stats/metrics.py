@@ -526,7 +526,9 @@ class StageSeconds:
 
 
 # the filer's spans (filer/server.py: assign, save, chunk_upload,
-# meta_save, read, chunk_fetch, lookup) and the gateway's (s3api/
+# meta_save, read, chunk_fetch, lookup, and its own routes' http_read,
+# http_write; filer/filer.py, a mutation's inside: lock_wait, lock_held,
+# store_write, notify, reclaim) and the gateway's (s3api/
 # server.py: auth, lookup, put, get, head, delete, by the action label
 # of s3_request_total)
 FILER_STAGES = StageSeconds(
@@ -540,6 +542,20 @@ FILER_STAGES = StageSeconds(
         "SeaweedFS_filer_stage_blocks_total",
         "filer.<stage> spans timed into filer_stage_seconds_total",
         ("stage",)))
+FilerOverwriteCounter = REGISTRY.counter(
+    "SeaweedFS_filer_overwrites_total",
+    "create_entry calls that found a file at the path and replaced it")
+FilerReclaimedChunksCounter = REGISTRY.counter(
+    "SeaweedFS_filer_reclaimed_chunks_total",
+    "chunks handed to the chunk-delete callback after the filer lock "
+    "was released (an overwrite's superseded chunks, a delete's)")
+FilerReclaimedBytesCounter = REGISTRY.counter(
+    "SeaweedFS_filer_reclaimed_bytes_total",
+    "logical bytes of the chunks in filer_reclaimed_chunks_total")
+FilerReadRetryCounter = REGISTRY.counter(
+    "SeaweedFS_filer_read_retries_total",
+    "GETs that found a chunk reclaimed under them (an overwrite replaced "
+    "the entry after the lookup) and read the entry as it then stood")
 S3_STAGES = StageSeconds(
     REGISTRY.counter(
         "SeaweedFS_s3_stage_seconds_total",

@@ -226,7 +226,8 @@ def cmd_filer(args):
                     persist_meta_log=args.metaLog,
                     cipher=args.encryptVolumeData,
                     cache_dir=args.cacheDir,
-                    cache_disk_bytes=args.cacheCapacityMB << 20)
+                    cache_disk_bytes=args.cacheCapacityMB << 20,
+                    save_to_filer_limit=args.saveToFilerLimit)
     _wire_notification(f)
     f.start()
     stoppables = [f]
@@ -1369,6 +1370,10 @@ def main(argv=None):
     p.add_argument("-ip", default="127.0.0.1")
     p.add_argument("-port", type=int, default=8888)
     p.add_argument("-maxMB", type=int, default=4)
+    p.add_argument("-saveToFilerLimit", type=int, default=2048,
+                   help="bodies of at most this many bytes are stored "
+                        "inside the entry; 0 (upstream's default) sends "
+                        "every body to a volume server")
     p.add_argument("-db", default="", help="sqlite path (default: memory)")
     p.add_argument("-store", default="sqlite",
                    help="store kind: sqlite | sharded | perbucket | "
