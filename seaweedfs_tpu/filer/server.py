@@ -146,6 +146,10 @@ class FilerServer:
         # opened before the fork; new serve threads reopen lazily
         self.server.on_worker_start(
             lambda wid: self.filer.store.forget_connections())
+        # a worker inherits no thread either: each follows the master's
+        # location feed itself
+        self.server.on_worker_start(
+            lambda wid: self._master_client.start())
         # observability mounts shadow the matching user paths, like the
         # /metadata/, /remote/ and /kv/ prefixes below
         self.server.add("GET", "/metrics", stats.metrics_handler)
@@ -192,7 +196,12 @@ class FilerServer:
                 healthz.gate_check(self.qos_gate)]
 
     def start(self):
+        for result in ("hit", "miss", "stale"):
+            stats.FilerVolumeLookupCounter.labels(result).inc(0)
         self.server.start()
+        # the master's /dir/watch feed keeps the volume locations that
+        # _locate reads fresh (upstream's filer: KeepConnected -> vidMap)
+        self._master_client.start()
         if self.meta_aggregator is not None:
             self.meta_aggregator.start()
         self._register_thread = threading.Thread(
@@ -201,6 +210,7 @@ class FilerServer:
 
     def stop(self):
         self._stop_event.set()
+        self._master_client.stop()
         if self.meta_aggregator is not None:
             self.meta_aggregator.stop()
         self.server.stop()
@@ -261,16 +271,66 @@ class FilerServer:
         return self._master_client.call(f"/dir/assign?{query}",
                                         timeout=30)
 
+    def _locate(self, fid: str,
+                fresh: bool = False) -> tuple[list[str], bool]:
+        """All replica holders of a fid's volume, and whether the master
+        client's map named them (True) or the master was asked just now
+        (False: a `filer.volume_lookup` span, through the policy layer).
+        The map is kept by the watch loop `start()` runs: the master's
+        add / remove deltas edit an entry, a resync or a leader change
+        clears the map, and `fresh` drops the entry first (`_at_holders`,
+        after its holders failed a call).  An answer without holders is
+        not cached."""
+        try:
+            vid = int(fid.split(",")[0])
+        except ValueError:
+            raise RpcError(f"malformed file id {fid!r}", 400) from None
+        if fresh:
+            self._master_client.invalidate(vid)
+        locations = self._master_client.vid_map.get(vid)
+        cached = bool(locations)
+        stats.FilerVolumeLookupCounter.labels(
+            "hit" if cached else "miss").inc()
+        if not cached:
+            with tracing.span("filer.volume_lookup", add=_STAGES.add,
+                              key="volume_lookup"):
+                locations = self._master_client.lookup(vid, timeout=10)
+        if not locations:
+            raise RpcError(f"volume {vid} has no locations", 404)
+        return [l["url"] for l in locations], cached
+
     def _lookup_urls(self, fid: str) -> list[str]:
-        """All replica holders of a fid's volume, via the policy layer
-        (lookup GETs retry with jittered backoff on a flaky master)."""
-        vid = fid.split(",")[0]
-        found = self._master_client.call(
-            f"/dir/lookup?volumeId={vid}", timeout=10)
-        return [l["url"] for l in found["locations"]]
+        """The holders of a fid's volume from the master client's map,
+        which the watch loop of `start()` keeps and `_at_holders` repairs
+        (see `_locate`); the master is asked only when the map has none."""
+        return self._locate(fid)[0]
 
     def _lookup_url(self, fid: str) -> str:
         return self._lookup_urls(fid)[0]
+
+    def _at_holders(self, fid: str, attempt):
+        """`attempt(urls)` at the holders of a fid's volume.  The backstop
+        for what the watch feed has not said yet (or never says: EC shards
+        that moved): when the attempt fails at holders the map named — a
+        transport error, or a 404, which a holder answers for a volume it
+        no longer has as for a needle that is gone — the entry is dropped
+        and the master asked once; if it names other holders the attempt
+        is made once more, there.  If it names the same, the error is the
+        holders' own (the needle is gone, the server is down) and stands."""
+        urls, cached = self._locate(fid)
+        try:
+            return attempt(urls)
+        except RpcError as e:
+            if not cached or not (e.transport or e.status == 404):
+                raise
+            try:
+                fresh = self._locate(fid, fresh=True)[0]
+            except RpcError:
+                raise e from None
+            if fresh == urls:
+                raise
+        stats.FilerVolumeLookupCounter.labels("stale").inc()
+        return attempt(fresh)
 
     def _delete_chunks(self, chunks: list[FileChunk],
                        exclude_fids: Optional[set] = None):
@@ -297,8 +357,9 @@ class FilerServer:
                 headers["Authorization"] = "BEARER " + gen_write_jwt(
                     self.guard.signing, chunk.fid)
             try:
-                call(self._lookup_url(chunk.fid), f"/{chunk.fid}",
-                     method="DELETE", headers=headers, timeout=10)
+                self._at_holders(chunk.fid, lambda urls: call(
+                    urls[0], f"/{chunk.fid}", method="DELETE",
+                    headers=headers, timeout=10))
             except RpcError:
                 pass  # chunk may already be gone; vacuum reclaims the rest
 
@@ -464,9 +525,10 @@ class FilerServer:
         requests fetch the whole chunk and slice locally so the reply
         carries a correct 206 + Content-Range (forwarding the Range and
         rewrapping as 200 would mislabel a partial body as complete)."""
-        url = self._lookup_url(file_id)
         try:
-            data = call(url, f"/{file_id}", timeout=30)
+            data = self._at_holders(
+                file_id, lambda urls: call(urls[0], f"/{file_id}",
+                                           timeout=30))
         except RpcError as e:
             raise RpcError(f"proxy chunk {file_id}: {e}", e.status or 502)
         if not isinstance(data, (bytes, bytearray)):
@@ -695,32 +757,36 @@ class FilerServer:
                                time.monotonic() - t0, "ram")
             return cached
         FilerChunkCacheCounter.inc(labels=("miss",))
-        urls = self._lookup_urls(fid)
-        if not urls:
-            raise RpcError(f"chunk {fid} has no locations", 404)
         jwt = (gen_read_jwt(self.guard.read_signing, fid)
                if self.guard.read_signing else "")
-        data = self._fetch_chunk_tcp(urls[0], fid, jwt) if urls else None
-        if data is None:
-            headers = {"Authorization": "BEARER " + jwt} if jwt else {}
-
-            def fetch(url):
-                def attempt():
-                    # parse=False: a chunk is stored content, and a
-                    # needle whose mime is application/json must come
-                    # back as its bytes, not as a parsed object
-                    return bytes(call(url, f"/{fid}", headers=headers,
-                                      timeout=60, parse=False))
-                return attempt
-
-            # hedged replica read: when the volume is replicated, a slow
-            # holder is raced by the next replica after the adaptive p95
-            # delay; on single-copy volumes this degenerates to one call
-            data = policy.hedged(
-                "/chunk_fetch", [fetch(u) for u in urls])
+        data = self._at_holders(
+            fid, lambda urls: self._fetch_chunk_from(urls, fid, jwt))
         self.chunk_cache.put(fid, data)
         self._record_chunk(fid, len(data), time.monotonic() - t0, "miss")
         return data
+
+    def _fetch_chunk_from(self, urls: list[str], fid: str,
+                          jwt: str) -> bytes:
+        """One chunk from its volume's holders: the first one's TCP fast
+        path, else HTTP."""
+        data = self._fetch_chunk_tcp(urls[0], fid, jwt)
+        if data is not None:
+            return data
+        headers = {"Authorization": "BEARER " + jwt} if jwt else {}
+
+        def fetch(url):
+            def attempt():
+                # parse=False: a chunk is stored content, and a
+                # needle whose mime is application/json must come
+                # back as its bytes, not as a parsed object
+                return bytes(call(url, f"/{fid}", headers=headers,
+                                  timeout=60, parse=False))
+            return attempt
+
+        # hedged replica read: when the volume is replicated, a slow
+        # holder is raced by the next replica after the adaptive p95
+        # delay; on single-copy volumes this degenerates to one call
+        return policy.hedged("/chunk_fetch", [fetch(u) for u in urls])
 
     def _record_chunk(self, fid: str, nbytes: int, latency_s: float,
                       tier: str):

@@ -500,6 +500,12 @@ class MasterServer:
             deltas = [{"seq": s, **d} for s, d in self._changes if s > since]
             seq = self._change_seq
             oldest = self._changes[0][0] if self._changes else 0
+        # the wait for a change is not work: the request's span ends here
+        # with no time on it, or every idle poll of every filer would be
+        # kept as a slow trace (WEED_TRACE_SLOW_MS)
+        span = tracing.current()
+        if span is not None:
+            span.finish(duration=0.0)
         return {"seq": seq, "deltas": deltas,
                 "feed_id": self._feed_id,
                 "leader": self.raft.leader or self.address,

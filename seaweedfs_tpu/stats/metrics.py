@@ -526,7 +526,8 @@ class StageSeconds:
 
 
 # the filer's spans (filer/server.py: assign, save, chunk_upload,
-# meta_save, read, chunk_fetch, lookup, and its own routes' http_read,
+# meta_save, read, chunk_fetch, lookup, volume_lookup (a question to the
+# master for a volume's holders), and its own routes' http_read,
 # http_write; filer/filer.py, a mutation's inside: lock_wait, lock_held,
 # store_write, notify, reclaim) and the gateway's (s3api/
 # server.py: auth, lookup, put, get, head, delete, by the action label
@@ -556,6 +557,12 @@ FilerReadRetryCounter = REGISTRY.counter(
     "SeaweedFS_filer_read_retries_total",
     "GETs that found a chunk reclaimed under them (an overwrite replaced "
     "the entry after the lookup) and read the entry as it then stood")
+FilerVolumeLookupCounter = REGISTRY.counter(
+    "SeaweedFS_filer_volume_lookup_total",
+    "a volume's holders resolved for a chunk fetch, delete or proxy: hit "
+    "(from the master client's map), miss (the master was asked: a "
+    "filer.volume_lookup span), stale (the map's holders failed the call "
+    "and the master then named others)", ("result",))
 S3_STAGES = StageSeconds(
     REGISTRY.counter(
         "SeaweedFS_s3_stage_seconds_total",

@@ -4,6 +4,21 @@ Parity with weed/wdclient: MasterClient holds a vidMap refreshed by the
 KeepConnected stream's VolumeLocation deltas (masterclient.go:20-120); here
 the stream is the master's /dir/watch long-poll.  Lookup misses fall back
 to /dir/lookup and populate the cache (vid_map.go:38-120).
+
+Who starts the watch loop: `FilerServer.start()` (the stand-alone filer
+and the one embedded in the S3 gateway) and `MasterFollower.start()`;
+each stops it in its own `stop()`.  A client nobody started still caches
+what it looked up, with nothing to tell it of a change.  The loop's long
+polls are waits: they carry a trace that is never sampled, and the master
+ends their handler span with no time on it.
+
+What drops a cached entry: an `add` / `remove` delta of the feed edits it
+(a volume's last location removed deletes it); a `resync` reply (the
+cursor fell off the master's retained deltas) and a change of `feed_id`
+(another master answers: a leader change) clear the whole map; and
+`invalidate(vid)`, which a caller uses when a holder the map named failed
+it — the feed says nothing of EC shards that moved, and may lag a volume
+that did — so that its next `lookup` asks the master.
 """
 
 from __future__ import annotations
@@ -13,6 +28,7 @@ import threading
 from typing import Optional
 
 from . import fid_lease
+from .. import tracing
 from ..rpc import policy
 from ..rpc.http_rpc import RpcError
 from ..util import glog
@@ -48,6 +64,10 @@ class VidMap:
             if not self._map[vid]:
                 del self._map[vid]
 
+    def discard(self, vid: int):
+        with self._lock:
+            self._map.pop(vid, None)
+
     def clear(self):
         with self._lock:
             self._map.clear()
@@ -70,15 +90,20 @@ class MasterClient:
         self._thread: Optional[threading.Thread] = None
 
     # -- lookup (vid_map.go LookupVolumeServerUrl) ---------------------------
-    def lookup(self, vid: int) -> list[dict]:
+    def lookup(self, vid: int, timeout: float = 30) -> list[dict]:
         cached = self.vid_map.get(vid)
         if cached:
             return cached
-        found = self._call_any(f"/dir/lookup?volumeId={vid}")
+        found = self._call_any(f"/dir/lookup?volumeId={vid}",
+                               timeout=timeout)
         locations = found.get("locations", [])
         if locations:
             self.vid_map.set(vid, locations)
         return locations
+
+    def invalidate(self, vid: int):
+        """Forget a volume's cached locations: the next lookup asks."""
+        self.vid_map.discard(vid)
 
     def lookup_file_id(self, fid: str) -> list[str]:
         vid = int(fid.split(",")[0])
@@ -140,6 +165,12 @@ class MasterClient:
         self._stop.set()
 
     def _watch_loop(self):
+        # a poll is a wait, not work: this thread's calls carry a trace
+        # that is never sampled, so the master's handler span is not
+        # sampled either (it finishes with the time it worked, not waited)
+        unsampled = tracing.start("master_client.watch", service=self.name)
+        unsampled.sampled = False
+        tracing.swap(unsampled)
         while not self._stop.is_set():
             try:
                 r = policy.call_policy(
