@@ -11,10 +11,11 @@ a threading server.  TPU-side collectives stay inside JAX (parallel/mesh.py)
 
 from __future__ import annotations
 
-import http.client
+import io
 import itertools
 import json
 import os
+import re
 import select
 import socket
 import threading
@@ -244,6 +245,28 @@ class _LeanHeaders(dict):
     def __contains__(self, key):
         return dict.__contains__(self, key) or \
             self._fold(key) is not None
+
+
+def _lean_headers(lines) -> _LeanHeaders:
+    """A head's header lines (str, with or without their line break) as
+    _LeanHeaders, for a request and for a reply alike: the first of a
+    repeated name wins (no case-folding scan), an obs-fold continuation
+    joins the line before it, a line without a name is skipped."""
+    headers = _LeanHeaders()
+    setdefault = dict.setdefault
+    last = None
+    for line in lines:
+        if line[:1] in (" ", "\t"):  # obs-fold continuation
+            if last is not None:
+                headers[last] = (dict.__getitem__(headers, last) + " "
+                                 + line.strip())
+            continue
+        key, colon, value = line.partition(":")
+        if not colon or not key:
+            continue
+        setdefault(headers, key, value.strip())
+        last = key
+    return headers
 
 
 # -- a request's stages around its handler -----------------------------------
@@ -508,6 +531,14 @@ class RpcServer:
                 Handler._date_cache = (now, rendered)
                 return rendered
 
+            def handle_expect_100(self):
+                # the 100 tells the client to send its body: held in
+                # the write buffer (wbufsize) it would leave with the
+                # reply, after a client that waits for it gave up
+                go_on = super().handle_expect_100()
+                self.wfile.flush()
+                return go_on
+
             def parse_request(self):
                 # Lean fast path for plain HTTP/1.0-1.1 requests: the
                 # stdlib routes every request's headers through
@@ -524,11 +555,8 @@ class RpcServer:
                 self.requestline = requestline
                 self.command, self.path, self.request_version = words
                 self.close_connection = words[2] == "HTTP/1.0"
-                headers = _LeanHeaders()
-                setdefault = dict.setdefault  # no case-folding scans
                 rl = self.rfile.readline
-                last = None
-                count = 0
+                lines = []
                 while True:
                     line = rl(65537)
                     if len(line) > 65536:
@@ -536,24 +564,11 @@ class RpcServer:
                         return False
                     if line in (b"\r\n", b"\n", b""):
                         break
-                    count += 1
-                    if count > 100:
+                    if len(lines) == 100:
                         self.send_error(431, "Too many headers")
                         return False
-                    if line[0] in (32, 9):  # obs-fold continuation
-                        if last is not None:
-                            headers[last] = (
-                                dict.__getitem__(headers, last) + " " +
-                                line.strip().decode("iso-8859-1"))
-                        continue
-                    idx = line.find(b":")
-                    if idx < 1:
-                        continue
-                    key = line[:idx].decode("iso-8859-1")
-                    setdefault(headers, key,
-                               line[idx + 1:].strip().decode("iso-8859-1"))
-                    last = key
-                self.headers = headers
+                    lines.append(str(line, "iso-8859-1"))
+                self.headers = headers = _lean_headers(lines)
                 conntype = (headers.get("Connection") or "").lower()
                 if conntype == "close":
                     self.close_connection = True
@@ -1145,14 +1160,252 @@ class RpcServer:
 # -- client helpers ----------------------------------------------------------
 
 
-class _NoDelayConnection(http.client.HTTPConnection):
-    """HTTPConnection with TCP_NODELAY: headers and body go out as
-    separate send()s, and Nagle would hold the second for the peer's
-    delayed ACK (~40 ms) on every pooled reuse."""
+class _HttpError(Exception):
+    """A message this client will not send, or a reply it cannot read."""
+
+
+class _PeerClosed(ConnectionResetError):
+    """EOF where a reply's first byte should be: the peer closed the
+    connection, as a server does with a keep-alive it reaped."""
+
+
+# a request target is printable ASCII without a space: anything else
+# could end the request line early and start a message of its own
+_BAD_TARGET = re.compile(r"[^\x21-\x7e]").search
+_BODYLESS_WITH_LENGTH = frozenset(("POST", "PUT", "PATCH"))
+# a chunk's size: hex digits and nothing else (int(x, 16) alone would
+# take a sign, an underscore or a 0x as well)
+_HEX = re.compile(rb"[0-9A-Fa-f]+\Z").match
+# head and body leave in one send() under this size; above it the body
+# goes as it is, uncopied, behind its head
+_ONE_SEND_MAX = 64 * 1024
+_MAX_HEAD = 256 * 1024
+
+
+class _BodySource(io.RawIOBase):
+    """What came with the reply's head, then the socket: the raw end of
+    the io.BufferedReader that reads a body.  Its read(n) fills one
+    bytes object of n in place, so a 4 MiB chunk is copied out of the
+    kernel once and never again.  `fed` counts the bytes handed over,
+    which tells the caller whether the peer sent more than the body."""
+
+    def __init__(self, rest: bytes, sock: socket.socket):
+        self._rest = rest
+        self._sock = sock
+        self.fed = 0
+
+    def readable(self):
+        return True
+
+    def readinto(self, b):
+        rest = self._rest
+        if rest:
+            n = min(len(b), len(rest))
+            b[:n] = rest[:n]
+            self._rest = rest[n:]
+        else:
+            n = self._sock.recv_into(b)
+        self.fed += n
+        return n
+
+
+class _Reply:
+    """A reply read whole: what call() and prefork.proxy use of one."""
+
+    __slots__ = ("status", "headers", "will_close", "_body")
+
+    def __init__(self, status: int, headers: _LeanHeaders,
+                 will_close: bool, body: bytes):
+        self.status = status
+        self.headers = headers
+        self.will_close = will_close
+        self._body = body
+
+    def read(self) -> bytes:
+        return self._body
+
+    def getheaders(self) -> list:
+        return list(self.headers.items())
+
+
+class _Connection:
+    """One HTTP/1.1 client connection of the pool: a request is one
+    preformatted message and one send(), a reply's head is split by
+    bytes into the server side's _LeanHeaders (http.client builds a
+    request line by line and parses every reply's head with
+    email.parser: three quarters of a call()'s GIL time, PERF.md §6,
+    PR 49).  TCP_NODELAY: a body over _ONE_SEND_MAX follows its head in
+    a send() of its own, and Nagle would hold it for the peer's delayed
+    ACK (~40 ms) on every pooled reuse.  Reads a reply whole; not for
+    streams (call_stream)."""
+
+    __slots__ = ("addr", "timeout", "sock", "_method")
+
+    def __init__(self, addr: str, timeout: float):
+        self.addr = addr
+        self.timeout = timeout
+        self.sock: Optional[socket.socket] = None
+        self._method = ""
 
     def connect(self):
-        super().connect()
+        host, _, port = self.addr.partition(":")
+        self.sock = socket.create_connection(
+            (host, int(port) if port else 80), self.timeout)
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+
+    def close(self):
+        sock, self.sock = self.sock, None
+        if sock is not None:
+            sock.close()
+
+    def request(self, method: str, path: str,
+                body: Optional[bytes] = None,
+                headers: Optional[dict] = None):
+        """Send one request: the lines http.client would send (Host,
+        Accept-Encoding: identity, a Content-Length for a body and for
+        a body-less POST / PUT / PATCH) unless the caller's headers
+        name them, then the caller's headers."""
+        if _BAD_TARGET(path) or _BAD_TARGET(method):
+            raise _HttpError(f"request line cannot carry {method!r} "
+                             f"{path!r}")
+        lines = [f"{method} {path} HTTP/1.1"]
+        given = {k.lower() for k in headers} if headers else ()
+        if "host" not in given:
+            lines.append("Host: " + self.addr.removesuffix(":80"))
+        if "accept-encoding" not in given:
+            lines.append("Accept-Encoding: identity")
+        if "content-length" not in given:
+            if body is not None:
+                lines.append(f"Content-Length: {len(body)}")
+            elif method in _BODYLESS_WITH_LENGTH:
+                lines.append("Content-Length: 0")
+        if headers:
+            lines.extend(f"{k}: {v}" for k, v in headers.items())
+        lines += ("", "")
+        try:
+            head = "\r\n".join(lines).encode("iso-8859-1")
+        except UnicodeEncodeError as e:
+            raise _HttpError(f"header cannot be sent: {e}") from None
+        # one line break behind each line and none inside any: a CR or
+        # LF in a header's name or value would start a header, or a
+        # request, of the value's own
+        breaks = len(lines) - 1
+        if head.count(b"\r") != breaks or head.count(b"\n") != breaks:
+            raise _HttpError("a header carries a line break")
+        if self.sock is None:
+            self.connect()
+        self._method = method
+        if body is None:
+            self.sock.sendall(head)
+        elif len(head) + len(body) < _ONE_SEND_MAX:
+            self.sock.sendall(b"".join((head, body)))
+        else:
+            self.sock.sendall(head)
+            self.sock.sendall(body)
+
+    def _read_head(self, buf: bytes) -> tuple[list, bytes]:
+        """Read to a head's blank line, beginning with what `buf` holds
+        of it: the head's lines, and the bytes that came behind it."""
+        recv = self.sock.recv
+        while True:
+            end = buf.find(b"\r\n\r\n")
+            if end >= 0:
+                return (buf[:end].decode("iso-8859-1").split("\r\n"),
+                        buf[end + 4:])
+            if len(buf) > _MAX_HEAD:
+                raise _HttpError("reply's head is too long")
+            more = recv(65536)
+            if not more:
+                raise _HttpError("reply's head cut short")
+            buf += more
+
+    def getresponse(self) -> _Reply:
+        """Read one reply whole.  _PeerClosed: EOF before its first
+        byte; _HttpError: not a reply, or one cut short."""
+        rest = self.sock.recv(65536)
+        if not rest:
+            raise _PeerClosed("the peer closed the connection before "
+                              "a reply")
+        while True:
+            lines, rest = self._read_head(rest)
+            version, _, status_text = lines[0].partition(" ")
+            status_text = status_text[:3]
+            if version not in ("HTTP/1.1", "HTTP/1.0") or \
+                    len(status_text) != 3 or \
+                    not (status_text.isascii() and status_text.isdigit()):
+                raise _HttpError(f"not an HTTP/1.x status line: "
+                                 f"{lines[0][:80]!r}")
+            status = int(status_text)
+            if status != 100:
+                break
+            # `100 Continue` answers an `Expect` (RpcServer sends it, and
+            # prefork.proxy may relay a client's) and is no reply: the
+            # reply comes behind it, as http.client reads it too
+        headers = _lean_headers(lines[1:])
+        # any other 1xx is handed back as it came, and what follows it
+        # on this connection is not ours to guess: never pooled again
+        will_close = version == "HTTP/1.0" or status < 200 or \
+            (headers.get("Connection") or "").lower() == "close"
+        # `extra`: bytes beyond the body belong to no request of ours,
+        # and the connection is not to be used again
+        if self._method == "HEAD" or status < 200 or \
+                status in (204, 304):
+            body, extra = b"", bool(rest)
+        elif "chunked" in (headers.get("Transfer-Encoding")
+                           or "").lower():
+            body, extra = self._read_chunked(rest)
+        else:
+            length = headers.get("Content-Length")
+            if length is None:
+                # neither counted nor chunked: the body ends with the
+                # connection
+                recv = self.sock.recv
+                parts = [rest]
+                while more := recv(65536):
+                    parts.append(more)
+                body, extra, will_close = b"".join(parts), False, True
+            elif not (length.isascii() and length.isdigit()):
+                raise _HttpError(f"not a Content-Length: {length[:40]!r}")
+            else:
+                n = int(length)
+                if len(rest) >= n:
+                    body, extra = rest[:n], len(rest) > n
+                else:
+                    source = _BodySource(rest, self.sock)
+                    body = io.BufferedReader(source).read(n)
+                    if len(body) != n:
+                        raise _HttpError(f"reply's body cut short: "
+                                         f"{len(body)} of {n} bytes")
+                    extra = source.fed > n
+        return _Reply(status, headers, will_close or extra, body)
+
+    def _read_chunked(self, rest: bytes) -> tuple[bytes, bool]:
+        """Decode a chunked body that begins in `rest`; returns it, and
+        whether the peer sent bytes beyond its last line."""
+        source = _BodySource(rest, self.sock)
+        reader = io.BufferedReader(source)
+        parts = []
+        used = 0
+        while True:
+            line = reader.readline(_MAX_HEAD)
+            used += len(line)
+            size_text = line.split(b";", 1)[0].strip()
+            if not line.endswith(b"\n") or not _HEX(size_text):
+                raise _HttpError(f"not a chunk's size line: {line[:40]!r}")
+            size = int(size_text, 16)
+            if size == 0:
+                break
+            data = reader.read(size + 2)  # the chunk and its CRLF
+            used += len(data)
+            if len(data) != size + 2:
+                raise _HttpError("chunked body cut short")
+            parts.append(data[:size])
+        while line not in (b"\r\n", b"\n"):  # trailers, to the blank line
+            line = reader.readline(_MAX_HEAD)
+            used += len(line)
+            if not line.endswith(b"\n"):
+                raise _HttpError("chunked body cut short")
+        return b"".join(parts), source.fed > used
 
 
 class _ConnPool:
@@ -1268,9 +1521,7 @@ class _ConnPool:
                 idle = self._idle.get(addr)
                 item = idle.pop() if idle else None
             if item is None:
-                host, _, port = addr.partition(":")
-                return _NoDelayConnection(
-                    host, int(port) if port else 80, timeout=timeout)
+                return _Connection(addr, timeout)
             conn, stored_at = item
             if now - stored_at > self.idle_ttl or self._dropped(conn):
                 conn.close()
@@ -1297,6 +1548,15 @@ class _ConnPool:
 
 
 _POOL = _ConnPool()
+
+# how a call() came by its connection, counted once a call: the share
+# that found a keep-alive connection is what the lean client's saving
+# rests on.  Keyed by (attempt, the connection was new)
+_CLIENT_CALLS = _stats.RpcClientCallsCounter
+_CONN_LABELS = {(0, False): ("reused",), (0, True): ("new",),
+                (1, True): ("retried",)}
+for _labels in _CONN_LABELS.values():
+    _CLIENT_CALLS.inc(0, _labels)  # a sample at 0, not an absent series
 
 # pick up a WEED_FAULTS spec set before process start; daemons/tests
 # that set it later reconfigure via faults.REGISTRY or /debug/faults
@@ -1350,80 +1610,59 @@ def call(addr: str, path: str, payload: Optional[dict] = None,
     # disconnect before any response.  Timeouts and errors on fresh
     # connections never retry — re-sending a non-idempotent RPC that may
     # already be executing would double-apply the mutation
-    stale_errors = (http.client.RemoteDisconnected,
-                    http.client.BadStatusLine,
-                    ConnectionResetError, BrokenPipeError)
     for attempt in (0, 1):
-        if attempt == 0:
-            conn = _POOL.get(addr, timeout)
-        else:  # bypass the pool: it may hold MORE stale sockets
-            host, _, port = addr.partition(":")
-            conn = _NoDelayConnection(host, int(port) if port else 80,
-                                      timeout=timeout)
+        # the retry bypasses the pool: it may hold MORE stale sockets
+        conn = _POOL.get(addr, timeout) if attempt == 0 \
+            else _Connection(addr, timeout)
         fresh = conn.sock is None
+        sent = False
         try:
-            # SEND phase: a reuse failure here means the server closed
-            # the idle socket before receiving the request — safe to
-            # retry any method, it was never fully delivered
             conn.request(method, path, body=data, headers=req_headers)
-        except stale_errors as e:
-            conn.close()
-            if attempt == 0 and not fresh:
-                continue
-            raise RpcError(f"cannot reach {addr}: {e}", 503,
-                           addr=addr, route=path,
-                           transport=True) from None
-        except (http.client.HTTPException, ConnectionError,
-                socket.timeout, TimeoutError, OSError) as e:
-            conn.close()
-            raise RpcError(f"cannot reach {addr}: {e}", 503,
-                           addr=addr, route=path,
-                           transport=True) from None
-        try:
-            # RECEIVE phase: the request reached the server and may have
-            # EXECUTED even though the response was lost — only
-            # idempotent methods may retry here
+            sent = True
             resp = conn.getresponse()
-            body = resp.read()
-            status = resp.status
-            ctype = resp.headers.get("Content-Type", "")
-            keep = not resp.will_close
-        except stale_errors as e:
+        except (_HttpError, OSError) as e:
             conn.close()
-            if attempt == 0 and not fresh and method in ("GET", "HEAD"):
+            # SEND phase: a reuse failure means the server closed the
+            # idle socket before receiving the request — safe to retry
+            # any method, it was never fully delivered.  RECEIVE phase:
+            # the request reached the server and may have EXECUTED even
+            # though the response was lost — only idempotent methods
+            # may retry
+            if isinstance(e, (ConnectionResetError, BrokenPipeError)) \
+                    and attempt == 0 and not fresh \
+                    and (not sent or method in ("GET", "HEAD")):
                 continue
+            _CLIENT_CALLS.inc(1.0, _CONN_LABELS[attempt, fresh])
             raise RpcError(f"cannot reach {addr}: {e}", 503,
                            addr=addr, route=path,
                            transport=True) from None
-        except (http.client.HTTPException, ConnectionError,
-                socket.timeout, TimeoutError, OSError) as e:
-            conn.close()
-            raise RpcError(f"cannot reach {addr}: {e}", 503,
-                           addr=addr, route=path,
-                           transport=True) from None
-        if keep:
-            _POOL.put(addr, conn)
-        else:
-            conn.close()
-        if status >= 400:
-            try:
-                message = json.loads(body).get("error", body.decode())
-            except Exception:
-                message = body.decode(errors="replace")
-            err_headers = {}
-            retry_after = resp.headers.get("Retry-After")
-            if retry_after:
-                err_headers["Retry-After"] = retry_after
-            # raft leader hint on not-leader rejections: clients retry
-            # against the hinted address before the next failover round
-            leader_hint = resp.headers.get("X-Raft-Leader")
-            if leader_hint:
-                err_headers["X-Raft-Leader"] = leader_hint
-            raise RpcError(message, status, addr=addr, route=path,
-                           headers=err_headers or None)
-        if parse and "application/json" in ctype:
-            return json.loads(body) if body else {}
-        return body
+        _CLIENT_CALLS.inc(1.0, _CONN_LABELS[attempt, fresh])
+        break
+    body = resp.read()
+    status = resp.status
+    if resp.will_close:
+        conn.close()
+    else:
+        _POOL.put(addr, conn)
+    if status >= 400:
+        try:
+            message = json.loads(body).get("error", body.decode())
+        except Exception:
+            message = body.decode(errors="replace")
+        err_headers = {}
+        retry_after = resp.headers.get("Retry-After")
+        if retry_after:
+            err_headers["Retry-After"] = retry_after
+        # raft leader hint on not-leader rejections: clients retry
+        # against the hinted address before the next failover round
+        leader_hint = resp.headers.get("X-Raft-Leader")
+        if leader_hint:
+            err_headers["X-Raft-Leader"] = leader_hint
+        raise RpcError(message, status, addr=addr, route=path,
+                       headers=err_headers or None)
+    if parse and "application/json" in resp.headers.get("Content-Type", ""):
+        return json.loads(body) if body else {}
+    return body
 
 
 def call_stream(addr: str, path: str, payload: Optional[dict] = None,

@@ -390,8 +390,11 @@ class PreforkGroup:
         content type and body bytes (call() would re-encode error
         bodies, mangling e.g. S3 XML error documents)."""
         from .http_rpc import RpcError, Response, _POOL
+        # `expect`: this worker has answered the client's 100-continue
+        # and holds the whole body, so the next hop is asked for nothing
         hop = {"connection", "keep-alive", "transfer-encoding", "te",
-               "upgrade", "proxy-connection", "host", "content-length"}
+               "upgrade", "proxy-connection", "host", "content-length",
+               "expect"}
         fwd = {k: v for k, v in headers.items() if k.lower() not in hop}
         fwd[FWD_HEADER] = "1"
         conn = _POOL.get(addr, 60.0)

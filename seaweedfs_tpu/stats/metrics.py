@@ -618,6 +618,13 @@ RpcRetryCounter = REGISTRY.counter(
     "SeaweedFS_rpc_retries_total",
     "outbound retry decisions by route and reason "
     "(retry / budget_dry / deadline)", ("route", "reason"))
+RpcClientCallsCounter = REGISTRY.counter(
+    "SeaweedFS_rpc_client_calls_total",
+    "unary calls a daemon made (rpc/http_rpc.py call()), by how each came "
+    "by its connection: reused (a keep-alive connection from the pool), "
+    "new (the pool had none for the address: a TCP connect), retried (the "
+    "pooled connection had been closed by the peer and the call went "
+    "again on a new one)", ("conn",))
 RpcHedgeCounter = REGISTRY.counter(
     "SeaweedFS_rpc_hedges_total",
     "hedged idempotent reads by route (fired / win)",
