@@ -144,9 +144,9 @@ def _make_units(plans: list[_VolumePlan], chunk: int) -> list[_Unit]:
 # the device pipeline's drain side, and the rebuild path.
 
 _IOV_MAX = 1024       # kernel cap on iovecs per pwritev
-_SFR_WAIT_BEFORE = 1  # SYNC_FILE_RANGE_WAIT_BEFORE
 _SFR_WRITE = 2        # SYNC_FILE_RANGE_WRITE
-_SFR_WAIT_AFTER = 4   # SYNC_FILE_RANGE_WAIT_AFTER
+# bytes written to an fd before the pacer kicks writeback of the window
+WRITE_FLUSH_BYTES = 32 << 20
 
 _sfr_fn = None
 _sfr_probed = False
@@ -170,30 +170,6 @@ def _sync_file_range():
     return _sfr_fn
 
 
-def _write_knobs() -> tuple[bool, int, int, bool]:
-    """The WEED_EC_WRITE_* knob set, read per encode (daemons and tests
-    flip them without a reimport): (write_behind, writers, flush_bytes,
-    drop_cache).
-
-      WEED_EC_WRITE_BEHIND     0 disables the decoupled writer stage
-                               (compute workers write synchronously)
-      WEED_EC_WRITERS          writer-pool size (0 = auto: workers/2,
-                               capped at 4)
-      WEED_EC_WRITE_FLUSH_MB   writeback pacing window in MiB
-                               (0 disables pacing; default 32)
-      WEED_EC_WRITE_DROP_CACHE 1 = drop synced windows from the page
-                               cache (posix_fadvise DONTNEED)
-    """
-    behind = os.environ.get("WEED_EC_WRITE_BEHIND", "1").lower() \
-        not in ("0", "false", "no")
-    writers = int(os.environ.get("WEED_EC_WRITERS", "0") or 0)
-    mb = os.environ.get("WEED_EC_WRITE_FLUSH_MB", "")
-    flush_bytes = int(float(mb) * (1 << 20)) if mb else (32 << 20)
-    drop = os.environ.get("WEED_EC_WRITE_DROP_CACHE", "0").lower() \
-        not in ("", "0", "false", "no")
-    return behind, writers, flush_bytes, drop
-
-
 def _preadv_full(fd: int, view: memoryview, offset: int):
     """Positional read that fills `view` or raises OSError: a short read
     is continued from where the kernel stopped, and end of file before
@@ -210,7 +186,7 @@ def _preadv_full(fd: int, view: memoryview, offset: int):
         got += n
 
 
-def _pwritev_full(fd: int, bufs, offset: int) -> int:
+def pwritev_full(fd: int, bufs, offset: int) -> int:
     """pwritev that writes every byte or raises OSError.  A short kernel
     write must fail the encode, not silently truncate a shard whose CRC
     was already computed from memory: partial progress is retried from
@@ -235,23 +211,21 @@ def _pwritev_full(fd: int, bufs, offset: int) -> int:
     return total
 
 
-class _WritebackPacer:
+class WritebackPacer:
     """Paces dirty-page writeback for the shard writer stage: after
-    every `flush_bytes` written to an fd, kick the kernel's async
+    every `WRITE_FLUSH_BYTES` written to an fd, kick the kernel's async
     writeback for the newly-written window (sync_file_range(WRITE)) so
     dirty pages drain continuously instead of accumulating until
     vm.dirty_ratio stalls every writer at once — the failure mode of the
-    8.79 GiB scale run, whose write stage was 93.5% of wall time.  With
-    drop_cache the window is synced and evicted (posix_fadvise DONTNEED):
-    shard bytes are write-once and never re-read by this process.
+    8.79 GiB scale run, whose write stage was 93.5% of wall time.
 
     Time spent flushing is accumulated in `flush_seconds` so callers can
     attribute it separately from the pwritev busy time."""
 
-    def __init__(self, flush_bytes: int, drop_cache: bool):
-        self.flush_bytes = flush_bytes
-        self.drop_cache = drop_cache
-        self._sfr = _sync_file_range() if flush_bytes > 0 else None
+    def __init__(self):
+        self._sfr = _sync_file_range()
+        # 0 where the libc has no sync_file_range: nothing to pace with
+        self.flush_bytes = WRITE_FLUSH_BYTES if self._sfr is not None else 0
         self._lock = threading.Lock()
         self._state: dict[int, list[int]] = {}  # fd -> [acc, cursor, hi]
         self.flush_seconds = 0.0
@@ -271,23 +245,10 @@ class _WritebackPacer:
             st[0] = 0
             lo, hi = st[1], st[2]
             st[1] = hi
-        self._flush_window(fd, lo, hi)
-
-    def _flush_window(self, fd: int, lo: int, hi: int):
         if hi <= lo:
             return
         t0 = time.perf_counter()
-        try:
-            if self._sfr is not None:
-                self._sfr(fd, lo, hi - lo, _SFR_WRITE)
-            if self.drop_cache:
-                if self._sfr is not None:
-                    self._sfr(fd, lo, hi - lo,
-                              _SFR_WAIT_BEFORE | _SFR_WRITE | _SFR_WAIT_AFTER)
-                os.posix_fadvise(fd, lo, hi - lo, os.POSIX_FADV_DONTNEED)
-        except OSError:
-            self.flush_bytes = 0  # fs doesn't support pacing; stop trying
-            return
+        self._sfr(fd, lo, hi - lo, _SFR_WRITE)
         with self._lock:
             self.flush_seconds += time.perf_counter() - t0
             self.flushes += 1
@@ -300,38 +261,45 @@ class _WritebackPacer:
 
 
 class _ShardFileSet:
-    """One volume's 14 shard files as raw O_WRONLY fds (no BufferedWriter
-    copy, no seek-flush churn — profiling showed buffered seek+write was
-    the #1 cost of the old host stage) with rolling per-file CRC32C.
-    pwritev is positional and thread-safe, so reader, writer-pool and
-    drain threads can all write concurrently.  Files are ftruncate()d to
-    their final size up front: extending i_size a megabyte at a time
-    measurably slows tmpfs/ext4 writes (~3x on the profiled box).  Every
-    write goes through the checked pwritev (full length or OSError) and
-    reports to the writeback pacer."""
+    """The shard files one job writes for a volume (all 14 for a seal,
+    the missing ones for a rebuild) as raw O_WRONLY fds (no
+    BufferedWriter copy, no seek-flush churn — profiling showed buffered
+    seek+write was the #1 cost of the old host stage) with rolling
+    per-file CRC32C.  pwritev is positional and thread-safe, so reader,
+    writer-pool and drain threads can all write concurrently.  Files are
+    ftruncate()d to their final size up front: extending i_size a
+    megabyte at a time measurably slows tmpfs/ext4 writes (~3x on the
+    profiled box).  Every write goes through the checked pwritev (full
+    length or OSError) and reports to the writeback pacer."""
 
     def __init__(self, base: str, to_ext, shard_size: int = 0,
-                 pacer: Optional[_WritebackPacer] = None):
-        self.fds = [os.open(base + to_ext(i),
-                            os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o644)
-                    for i in range(TOTAL_SHARDS)]
-        if shard_size:
-            for fd in self.fds:
-                os.ftruncate(fd, shard_size)
+                 pacer: Optional[WritebackPacer] = None,
+                 shards=range(TOTAL_SHARDS)):
+        self.fds: dict[int, int] = {}
         self.crcs = [0] * TOTAL_SHARDS
         self.pacer = pacer
+        try:
+            for i in shards:
+                self.fds[i] = os.open(
+                    base + to_ext(i),
+                    os.O_CREAT | os.O_TRUNC | os.O_WRONLY, 0o644)
+                if shard_size:
+                    os.ftruncate(self.fds[i], shard_size)
+        except BaseException:
+            self.close()
+            raise
 
     def write(self, shard: int, bufs, offset: int) -> int:
         fd = self.fds[shard]
-        n = _pwritev_full(fd, bufs, offset)
+        n = pwritev_full(fd, bufs, offset)
         if self.pacer is not None:
             self.pacer.wrote(fd, offset, n)
         return n
 
     def close(self):
         if self.pacer is not None:
-            self.pacer.forget(self.fds)
-        for fd in self.fds:
+            self.pacer.forget(self.fds.values())
+        for fd in self.fds.values():
             os.close(fd)
 
 
@@ -386,8 +354,7 @@ def encode_volumes(bases: list[str], large_block: Optional[int] = None,
         if host_codec:
             return _encode_units_host(plans, units, chunk, host_codec,
                                       stage_stats)
-        _, _, flush_bytes, drop_cache = _write_knobs()
-        pacer = _WritebackPacer(flush_bytes, drop_cache)
+        pacer = WritebackPacer()
         writers = {vi: _ShardFileSet(
                        p.base, to_ext,
                        (p.rows[-1][1] + p.rows[-1][2]) if p.rows else 0,
@@ -401,6 +368,28 @@ def encode_volumes(bases: list[str], large_block: Optional[int] = None,
     finally:
         tracing.restore(prev)
         root.finish()
+
+
+def _put(stop: threading.Event, q: "queue.Queue", item) -> bool:
+    """`q.put(item)` that gives up once the job has stopped: False then,
+    and the item was not queued."""
+    while not stop.is_set():
+        try:
+            q.put(item, timeout=0.5)
+            return True
+        except queue.Full:
+            continue
+    return False
+
+
+def _get(stop: threading.Event, q: "queue.Queue"):
+    """`q.get()` that gives up once the job has stopped: None then."""
+    while not stop.is_set():
+        try:
+            return q.get(timeout=0.5)
+        except queue.Empty:
+            continue
+    return None
 
 
 class _ReadStage:
@@ -461,21 +450,10 @@ class _ReadStage:
             target=self._coordinate, daemon=True, name=threads + "-reader")
 
     def put(self, q, item) -> bool:
-        while not self.stop.is_set():
-            try:
-                q.put(item, timeout=0.5)
-                return True
-            except queue.Full:
-                continue
-        return False
+        return _put(self.stop, q, item)
 
     def get(self, q):
-        while not self.stop.is_set():
-            try:
-                return q.get(timeout=0.5)
-            except queue.Empty:
-                continue
-        return None
+        return _get(self.stop, q)
 
     def _run_steps(self, buf: np.ndarray, steps: deque):
         """One I/O worker's share of a batch."""
@@ -535,13 +513,97 @@ class _ReadStage:
         self._workers.shutdown(wait=True)   # they saw the stop
 
 
+class _WriteStage:
+    """The write-behind stage of every pipeline here (the seal's, the
+    rebuild's, the host pipeline's): a bounded queue and `writers`
+    threads that run the client's `write(item)` on what `put` hands
+    them, in the order it was put (one writer) or each its own share in
+    that order (a pool), so that the thread that computes never waits
+    for a checked pwritev unless the queue is full.
+
+    It shares the job's one `errors` list and one `stop` event with the
+    read stage and the pipeline's own threads: a write that fails is in
+    `errors`, `stop` is set, readers, dispatch and completion end, and
+    `put` returns False from then on.
+
+    Seconds, through `add_time`: `write` = a writer inside `write(item)`
+    (the `<span>.write` stage, one block an item, with `put`'s `nbytes`);
+    what the pipeline's thread loses to a full queue is the client's to
+    time around `put` and `close` (the rebuild's `write_wait`)."""
+
+    def __init__(self, span: str, write, add_time, errors: list,
+                 stop: threading.Event, depth: int, writers: int = 1):
+        self.write, self.add_time = write, add_time
+        self.errors, self.stop = errors, stop
+        self._span = span + ".write"
+        self.root = tracing.current()   # the job's span, as _ReadStage's
+        self.q: "queue.Queue" = queue.Queue(maxsize=depth)
+        name = span.replace(".", "-") + "-writer"   # ec-encode-writer
+        self._threads = [threading.Thread(target=self._run, daemon=True,
+                                          name=name)
+                         for _ in range(writers)]
+
+    def put(self, item, nbytes: int = -1) -> bool:
+        return _put(self.stop, self.q, (item, nbytes))
+
+    def take_if(self, joins):
+        """Without waiting: the next queued item if `joins(item)` holds,
+        else None, and the item stays where it is.  For a client whose
+        `write` can serve the item behind its own in the same call."""
+        q = self.q
+        with q.mutex:
+            head = q.queue[0] if q.queue else None
+            if head is None or not joins(head[0]):
+                return None
+            q.queue.popleft()
+            q.not_full.notify()
+        return head[0]
+
+    def _run(self):
+        tracing.swap(self.root)
+        try:
+            n = 0
+            while True:
+                entry = _get(self.stop, self.q)
+                if entry is None:
+                    return
+                item, nbytes = entry
+                with tracing.stage(self._span, self.add_time, "write", n,
+                                   nbytes):
+                    self.write(item)
+                n += 1
+        except BaseException as e:  # fails the job, stops the others
+            self.errors.append(e)
+            self.stop.set()
+        finally:
+            tracing.restore(None)
+
+    def start(self):
+        for t in self._threads:
+            t.start()
+
+    def close(self):
+        """Let the writers finish what is queued (nothing, once the job
+        has stopped) and join them.  Files are the client's to close,
+        after this."""
+        for _ in self._threads:
+            _put(self.stop, self.q, None)
+        for t in self._threads:
+            if t.is_alive():
+                t.join(timeout=600)
+            if t.is_alive():    # its files are about to be closed
+                self.errors.append(TimeoutError(
+                    f"{t.name} still writing after 600 s"))
+                self.stop.set()
+
+
 class _PipelineIO:
     """Shared reader/writer scaffolding of the streaming encode
     pipeline: pooled staging slots, backpressure queues, the read stage
-    (fills slots and writes data shards), the writer thread (appends
-    parity shards), and the torn-shutdown sequencing.  The device
-    compute stages differ only in what happens between `ready` and
-    `parity_q`.
+    (fills slots and writes data shards), the write stage (one thread
+    that appends parity shards), and the torn-shutdown sequencing.  The
+    device compute stages differ only in what happens between `ready`
+    and `writes.put`.
 
     The read stage is a `_ReadStage`, which the rebuild composes too.
     The seal hands it, a batch, the (k, unit, row) steps of the rows
@@ -604,9 +666,9 @@ class _PipelineIO:
         # one stop and one list of errors for every thread of the seal
         self.errors, self.stop = self.reads.errors, self.reads.stop
         self.put, self.get = self.reads.put, self.reads.get
-        self.parity_q: "queue.Queue" = queue.Queue(maxsize=n_slots)
-        self._wt = threading.Thread(target=self._writer, daemon=True,
-                                    name="ec-encode-writer")
+        self.writes = _WriteStage("ec.encode", self._write_parity,
+                                  self.add_time, self.errors, self.stop,
+                                  depth=n_slots)
 
     def add_time(self, key: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -654,39 +716,24 @@ class _PipelineIO:
                    functools.partial(self._zero_padding, batch, k_max),
                    (batch, k_max))
 
-    def _writer(self):
-        tracing.swap(self.root)
-        try:
-            n = 0
-            while True:
-                item = self.get(self.parity_q)
-                if item is None:
-                    return
-                parity, batch = item
-                with tracing.stage("ec.encode.write", self.add_time,
-                                   "write", n):
-                    for k, u in enumerate(batch):
-                        if u.real_rows == 0:
-                            continue  # parity of all-zero rows is zero:
-                            #           already on disk via ftruncate
-                        w = self.writers[u.vol]
-                        for i in range(PARITY_SHARDS):
-                            w.write(DATA_SHARDS + i, [parity[k, i]],
-                                    u.shard_off)
-                n += 1
-        except BaseException as e:
-            self.errors.append(e)
-            self.stop.set()
-        finally:
-            tracing.restore(None)
+    def _write_parity(self, item):
+        """The write stage's client: a batch's parity rows on to the
+        parity shards."""
+        parity, batch = item
+        for k, u in enumerate(batch):
+            if u.real_rows == 0:
+                continue  # parity of all-zero rows is zero: already on
+                #           disk via ftruncate
+            w = self.writers[u.vol]
+            for i in range(PARITY_SHARDS):
+                w.write(DATA_SHARDS + i, [parity[k, i]], u.shard_off)
 
     def start(self):
         self.reads.start()
-        self._wt.start()
+        self.writes.start()
 
     def finish(self):
-        self.put(self.parity_q, None)
-        self._wt.join(timeout=60)
+        self.writes.close()
         self.reads.close()
         for fd in self.dats:
             os.close(fd)
@@ -954,7 +1001,7 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
             io.free_slots.put(slot)
             if parity is not None:
                 # (4, B, L) -> writer's [k][i] indexing as a free view
-                io.put(io.parity_q, (parity.transpose(1, 0, 2), batch))
+                io.writes.put((parity.transpose(1, 0, 2), batch))
         else:
             parity_dev, crc_dev = out
             with tracing.stage("ec.encode.d2h_wait", add_time, "d2h_wait",
@@ -980,7 +1027,7 @@ def _encode_units_device(plans, units, chunk, writers, mesh,
                         w.crcs[s] = crc_host.crc32c_combine(
                             w.crcs[s], int(crcs[k, s]), chunk)
             add_time("encode_crc", time.perf_counter() - t0)
-            io.put(io.parity_q, (parity, batch))
+            io.writes.put((parity, batch))
 
     def _completion():
         tracing.swap(root)
@@ -1196,10 +1243,11 @@ def _encode_units_host(plans, units, chunk, host_codec,
                 one per *available* core, each releasing the GIL inside
                 the fused native parity+CRC kernel) encodes into pooled
                 parity slots;
-      write   — a dedicated writer pool drains a bounded hand-off queue,
-                coalescing adjacent spans into one pwritev per shard
-                file and pacing dirty-page writeback (_WritebackPacer)
-                so scale runs don't stall on a full dirty-page budget.
+      write   — a dedicated writer pool (`_WriteStage`) drains a bounded
+                hand-off queue, coalescing adjacent spans into one
+                pwritev per shard file and pacing dirty-page writeback
+                (WritebackPacer) so scale runs don't stall on a full
+                dirty-page budget.
 
     Compute workers hand (data, parity, crcs) to the writer stage and
     immediately pull the next item instead of blocking on 14 synchronous
@@ -1211,9 +1259,8 @@ def _encode_units_host(plans, units, chunk, host_codec,
 
     On a single-core host everything runs inline in the calling thread —
     profiling showed reader/worker threads on one core cost ~3x in GIL
-    convoying around every ctypes/syscall boundary.  WEED_EC_WRITE_BEHIND=0
-    degrades to the two-stage form (compute workers write synchronously),
-    byte- and CRC-identical either way.
+    convoying around every ctypes/syscall boundary — byte- and
+    CRC-identical either way.
 
     stage_stats (optional dict) gets per-stage busy seconds + fractions
     (read / encode_crc / write / flush): the pipeline's own answer to
@@ -1239,12 +1286,8 @@ def _encode_units_host(plans, units, chunk, host_codec,
         # workers onto cores it cannot use
         nworkers = max(1, min(16, available_cpu_count()))
 
-    write_behind, nwriters, flush_bytes, drop_cache = _write_knobs()
-    write_behind = write_behind and nworkers > 1
-    if nwriters <= 0:
-        nwriters = max(1, min(4, nworkers // 2))
-    if not write_behind:
-        nwriters = 0
+    write_behind = nworkers > 1
+    nwriters = max(1, min(4, nworkers // 2)) if write_behind else 0
 
     items = _host_work_items(plans)
     slot_bytes = max(i.rows * DATA_SHARDS * i.length for i in items)
@@ -1258,7 +1301,7 @@ def _encode_units_host(plans, units, chunk, host_codec,
 
     stop = threading.Event()
     errors: list[BaseException] = []
-    pacer = _WritebackPacer(flush_bytes, drop_cache)
+    pacer = WritebackPacer()
     dat_fds = [os.open(p.base + ".dat", os.O_RDONLY) for p in plans]
     vols = {vi: _ShardFileSet(
                 p.base, to_ext,
@@ -1309,20 +1352,14 @@ def _encode_units_host(plans, units, chunk, host_codec,
 
     def encode_item(w: _HostWork, data: np.ndarray):
         """Encode stage: parity+CRC into a pooled parity slot.  The slot
-        travels with the item to the writer stage (write-behind) or is
-        released right after the inline write."""
+        travels with the item to the write stage, which releases it."""
         prev = tracing.swap(root)  # a pool thread: hand it the seal's span
         try:
             with tracing.stage("ec.encode.crc", add_time, "encode_crc",
                                w.rows, data.nbytes):
-                while True:  # stop-aware: an error elsewhere must not
-                    #          wedge us
-                    try:
-                        pbuf = parity_free.get(timeout=0.5)
-                        break
-                    except queue.Empty:
-                        if stop.is_set():
-                            raise RuntimeError("encode pipeline stopped")
+                pbuf = _get(stop, parity_free)
+                if pbuf is None:    # an error elsewhere must not wedge us
+                    raise RuntimeError("encode pipeline stopped")
                 need = w.rows * PARITY_SHARDS * w.length
                 parity = pbuf[:need].reshape(w.rows, PARITY_SHARDS,
                                              w.length)
@@ -1341,54 +1378,22 @@ def _encode_units_host(plans, units, chunk, host_codec,
             tracing.restore(prev)
         return pbuf, parity, crcs
 
-    def write_item(w: _HostWork, data: np.ndarray, parity: np.ndarray):
-        """Write stage body: the item's data+parity shard spans."""
-        prev = tracing.swap(root)
-        try:
-            with tracing.stage("ec.encode.write", add_time, "write",
-                               w.rows, data.nbytes + parity.nbytes):
-                v = vols[w.vol]
-                for i in range(DATA_SHARDS):
-                    v.write(i, [data[r, i] for r in range(w.rows)],
-                            w.shard_off)
-                for i in range(PARITY_SHARDS):
-                    v.write(DATA_SHARDS + i,
-                            [parity[r, i] for r in range(w.rows)],
-                            w.shard_off)
-        finally:
-            tracing.restore(prev)
-
-    def encode_write_item(w: _HostWork, data: np.ndarray) -> list[int]:
-        """Two-stage form (WEED_EC_WRITE_BEHIND=0): the compute worker
-        writes synchronously, as the pipeline always did before the
-        writer stage was split out."""
-        pbuf, parity, crcs = encode_item(w, data)
-        write_item(w, data, parity)
-        parity_free.put(pbuf)
-        return crcs
+    def write_spans(group):
+        """The data+parity shard spans of adjacent items (w, data,
+        parity): ONE pwritev per shard file."""
+        v = vols[group[0][0].vol]
+        for s in range(TOTAL_SHARDS):
+            j = s if s < DATA_SHARDS else s - DATA_SHARDS
+            v.write(s, [(data if s < DATA_SHARDS else parity)[r, j]
+                        for (w, data, parity) in group
+                        for r in range(w.rows)],
+                    group[0][0].shard_off)
 
     def combine(w: _HostWork, crcs: list[int]):
         v = vols[w.vol]
         for s in range(TOTAL_SHARDS):
             v.crcs[s] = crc_host.crc32c_combine(
                 v.crcs[s], crcs[s], w.rows * w.length)
-
-    def qput(q, item) -> bool:
-        while not stop.is_set():
-            try:
-                q.put(item, timeout=0.5)
-                return True
-            except queue.Full:
-                continue
-        return False
-
-    def qget(q):
-        while not stop.is_set():
-            try:
-                return q.get(timeout=0.5)
-            except queue.Empty:
-                continue
-        return None
 
     wall0 = _t.perf_counter()
     try:
@@ -1399,7 +1404,9 @@ def _encode_units_host(plans, units, chunk, host_codec,
                                    w.rows):
                     data = read_item(w, flat)
                 pbuf, parity, crcs = encode_item(w, data)
-                write_item(w, data, parity)
+                with tracing.stage("ec.encode.write", add_time, "write",
+                                   w.rows, data.nbytes + parity.nbytes):
+                    write_spans([(w, data, parity)])
                 parity_free.put(pbuf)
                 combine(w, crcs)
         else:
@@ -1408,94 +1415,61 @@ def _encode_units_host(plans, units, chunk, host_codec,
             for _ in range(n_slots):
                 free_slots.put(np.empty(slot_bytes, dtype=np.uint8))
             ready: "queue.Queue" = queue.Queue(maxsize=n_slots)
-            write_q: "queue.Queue" = queue.Queue(maxsize=2 * nwriters + 2)
 
             def reader():
                 tracing.swap(root)
                 try:
                     for w in items:
-                        flat = qget(free_slots)
+                        flat = _get(stop, free_slots)
                         if flat is None:
                             return
                         with tracing.stage("ec.encode.read", add_time,
                                            "read", w.rows):
                             data = read_item(w, flat)
-                        if not qput(ready, (flat, data, w)):
+                        if not _put(stop, ready, (flat, data, w)):
                             return
-                    qput(ready, None)
+                    _put(stop, ready, None)
                 except BaseException as e:
                     errors.append(e)
                     stop.set()
                 finally:
                     tracing.restore(None)
 
-            # the writer pool: items arrive in stripe order (the main
-            # loop combines and enqueues in submission order), so a
-            # writer can coalesce the adjacent spans queued behind its
-            # current item into ONE pwritev per shard file
+            # the write stage's client.  Items arrive in stripe order
+            # (the main loop combines and enqueues in submission order),
+            # so a writer takes the adjacent spans queued behind its
+            # item with it and writes them as one group
             _GROUP_MAX = 8  # spans per coalesced group
 
-            def write_group(group):
-                with tracing.stage("ec.encode.write", add_time, "write",
-                                   len(group)):
-                    v = vols[group[0][0].vol]
-                    base_off = group[0][0].shard_off
-                    for s in range(TOTAL_SHARDS):
-                        iovs = []
-                        for (w, _flat, data, parity, _pbuf) in group:
-                            src = data if s < DATA_SHARDS else parity
-                            j = s if s < DATA_SHARDS else s - DATA_SHARDS
-                            for r in range(w.rows):
-                                iovs.append(src[r, j])
-                        v.write(s, iovs, base_off)
+            def write_item(item):
+                group = [item]
+                rows = item[0].rows
+
+                def joins(nxt) -> bool:
+                    lw, nw = group[-1][0], nxt[0]
+                    return (nw.vol == lw.vol
+                            and nw.shard_off
+                            == lw.shard_off + lw.rows * lw.length
+                            and rows + nw.rows <= _IOV_MAX)
+
+                while len(group) < _GROUP_MAX:
+                    nxt = writes.take_if(joins)
+                    if nxt is None:
+                        break
+                    group.append(nxt)
+                    rows += nxt[0].rows
+                write_spans([(w, data, parity)
+                             for (w, _flat, data, parity, _pbuf) in group])
                 for (_w, flat, _data, _parity, pbuf) in group:
                     free_slots.put(flat)
                     parity_free.put(pbuf)
 
-            def writer_loop():
-                carry = None
-                tracing.swap(root)
-                try:
-                    while True:
-                        if carry is not None:
-                            item, carry = carry, None
-                        else:
-                            item = qget(write_q)
-                        if item is None:
-                            return
-                        group = [item]
-                        rows = item[0].rows
-                        while len(group) < _GROUP_MAX:
-                            try:
-                                nxt = write_q.get_nowait()
-                            except queue.Empty:
-                                break
-                            if nxt is None:
-                                # a sibling's sentinel: hand it back
-                                write_q.put(None)
-                                break
-                            lw, nw = group[-1][0], nxt[0]
-                            if (nw.vol != lw.vol
-                                    or nw.shard_off != lw.shard_off
-                                    + lw.rows * lw.length
-                                    or rows + nw.rows > _IOV_MAX):
-                                carry = nxt
-                                break
-                            group.append(nxt)
-                            rows += nw.rows
-                        write_group(group)
-                except BaseException as e:
-                    errors.append(e)
-                    stop.set()
-                finally:
-                    tracing.restore(None)
-
+            writes = _WriteStage("ec.encode", write_item, add_time, errors,
+                                 stop, depth=2 * nwriters + 2,
+                                 writers=nwriters)
             rt = threading.Thread(target=reader, daemon=True)
             rt.start()
-            wthreads = [threading.Thread(target=writer_loop, daemon=True)
-                        for _ in range(nwriters)]
-            for wt in wthreads:
-                wt.start()
+            writes.start()
             pool = ThreadPoolExecutor(max_workers=nworkers)
             # keep up to nworkers+1 items in flight; combine in order
             # (per-file CRCs chain in stripe order, and in-order hand-off
@@ -1504,33 +1478,21 @@ def _encode_units_host(plans, units, chunk, host_codec,
             try:
                 done = False
                 while not done and not stop.is_set():
-                    try:
-                        item = ready.get(timeout=0.5)
-                    except queue.Empty:
-                        continue
-                    if item is None:
+                    item = _get(stop, ready)
+                    if item is None:    # the end, or stopped
                         done = True
                     else:
                         flat, data, w = item
-                        fn = encode_item if write_behind else \
-                            encode_write_item
                         pending.append(
-                            (w, flat, data, pool.submit(fn, w, data)))
+                            (w, flat, data,
+                             pool.submit(encode_item, w, data)))
                     while pending and (len(pending) > nworkers or done):
                         w, flat, data, fut = pending.pop(0)
-                        if write_behind:
-                            pbuf, parity, crcs = fut.result()
-                            combine(w, crcs)
-                            if not qput(write_q,
-                                        (w, flat, data, parity, pbuf)):
-                                break
-                        else:
-                            combine(w, fut.result())
-                            free_slots.put(flat)
-                for _ in range(nwriters):
-                    qput(write_q, None)
-                for wt in wthreads:
-                    wt.join(timeout=600)
+                        pbuf, parity, crcs = fut.result()
+                        combine(w, crcs)
+                        if not writes.put((w, flat, data, parity, pbuf)):
+                            break
+                writes.close()
                 if errors:
                     raise errors[0]
             except BaseException:
@@ -1542,8 +1504,7 @@ def _encode_units_host(plans, units, chunk, host_codec,
                 stop.set()
                 pool.shutdown(wait=True)
                 rt.join(timeout=30)
-                for wt in wthreads:
-                    wt.join(timeout=5)
+                writes.close()
     finally:
         for fd in dat_fds:
             os.close(fd)
@@ -1634,8 +1595,8 @@ def rebuild_shards(base: str, mesh=None,
     pipeline thread, disjoint: `read_wait` (blocked on a filled slot:
     the read stage sets the pace), `dispatch` (upload + step call) with
     `h2d` inside it, `d2h_wait` (step + copy back), `crc` and
-    `write_wait` (blocked on the write-behind thread: its full queue,
-    and the join at the end).  Overlapping them: the read stage's `read`,
+    `write_wait` (blocked on the write stage, `_WriteStage`: its full
+    queue, and the join at the end).  Overlapping them: the read stage's `read`,
     `read_slot_wait` and `read_worker_busy` over its `read_workers`
     workers (see `_ReadStage`), and on the writer thread `write`.
     """
@@ -1700,15 +1661,8 @@ def rebuild_shards(base: str, mesh=None,
                         b * DATA_SHARDS * chunk, device=dev_label)
              for _ in range(_REBUILD_SLOTS)]
 
-    crcs = {sid: 0 for sid in missing}
-    # write-behind: rebuilt batches are handed to a writer thread so the
-    # next device dispatch isn't serialized behind checked pwritevs; the
-    # pacer keeps large rebuilds from stalling on dirty-page writeback
-    werrs: list[BaseException] = []
-    wq: "queue.Queue" = queue.Queue(maxsize=2)
     timers = dict.fromkeys(_REBUILD_STAGES, 0.0)
     tlock = threading.Lock()
-    root = tracing.current()  # the rebuild request's span, if sampled
 
     def add_time(key: str, seconds: float):
         """The stage accumulator handed to tracing.stage()."""
@@ -1718,7 +1672,6 @@ def rebuild_shards(base: str, mesh=None,
     # raw fds: the read stage's workers read positionally, sharing no
     # file offset
     in_fds: list[int] = []
-    out_fds: dict[int, int] = {}
 
     def survivor_batches():
         for start in range(0, len(offsets), b):
@@ -1737,47 +1690,33 @@ def rebuild_shards(base: str, mesh=None,
         if width < chunk:
             row[width:] = 0
 
-    def wb_writer():
-        tracing.swap(root)
-        try:
-            n = 0
-            while True:
-                item = wq.get()
-                if item is None:
-                    return
-                batch_offs, out = item
-                with tracing.stage("ec.rebuild.write", add_time, "write",
-                                   n, out.nbytes):
-                    for k, off in enumerate(batch_offs):
-                        width = min(chunk, shard_size - off)
-                        for j, sid in enumerate(missing):
-                            fd = out_fds[sid]
-                            _pwritev_full(fd, [out[k, j, :width]], off)
-                            pacer.wrote(fd, off, width)
-                n += 1
-        except BaseException as e:
-            werrs.append(e)
-        finally:
-            tracing.restore(None)
+    def write_rows(item):
+        """The write stage's client: a drained batch's rebuilt rows on
+        to the missing shards' files."""
+        batch_offs, out = item
+        for k, off in enumerate(batch_offs):
+            width = min(chunk, shard_size - off)
+            for j, sid in enumerate(missing):
+                files.write(sid, [out[k, j, :width]], off)
 
-    reads = wt = None
+    reads = _ReadStage("ec.rebuild", survivor_batches(), read_row, slots,
+                       add_time)
+    files = writes = None
     done = False
     try:
         for i in chosen:
             in_fds.append(os.open(base + to_ext(i), os.O_RDONLY))
-        reads = _ReadStage("ec.rebuild", survivor_batches(), read_row,
-                           slots, add_time)
         reads.start()
-        _, _, flush_bytes, drop_cache = _write_knobs()
-        pacer = _WritebackPacer(flush_bytes, drop_cache)
-        for sid in missing:
-            out_fds[sid] = os.open(base + to_ext(sid),
-                                   os.O_CREAT | os.O_TRUNC | os.O_WRONLY,
-                                   0o644)
-            os.ftruncate(out_fds[sid], shard_size)
-        wt = threading.Thread(target=wb_writer, daemon=True,
-                              name="ec-rebuild-writer")
-        wt.start()
+        # write-behind: rebuilt batches are handed to a writer thread so
+        # the next device dispatch isn't serialized behind checked
+        # pwritevs; the pacer keeps large rebuilds from stalling on
+        # dirty-page writeback.  One `errors` and one `stop` with the
+        # read stage: a failed write ends the readers too
+        files = _ShardFileSet(base, to_ext, shard_size, WritebackPacer(),
+                              shards=missing)
+        writes = _WriteStage("ec.rebuild", write_rows, add_time,
+                             reads.errors, reads.stop, depth=2)
+        writes.start()
         inflight: list = []
 
         def drain_one():
@@ -1800,18 +1739,13 @@ def rebuild_shards(base: str, mesh=None,
                         # combine algebra
                         chunk_crc = int(fin[j]) if width == chunk else \
                             crc_host.crc32c(out[k, j, :width].tobytes())
-                        crcs[sid] = crc_host.crc32c_combine(
-                            crcs[sid], chunk_crc, width)
+                        files.crcs[sid] = crc_host.crc32c_combine(
+                            files.crcs[sid], chunk_crc, width)
             with tracing.stage("ec.rebuild.write_wait", add_time,
                                "write_wait", n, out.nbytes):
-                while True:  # `out` is fresh per drain: safe to hand off
-                    if werrs:
-                        raise werrs[0]
-                    try:
-                        wq.put((batch_offs, out), timeout=0.5)
-                        return None
-                    except queue.Full:
-                        continue
+                # `out` is fresh per drain: safe to hand off
+                if not writes.put((batch_offs, out), out.nbytes):
+                    raise reads.errors[0]
 
         for n in range(n_batches):
             with tracing.stage("ec.rebuild.read_wait", add_time,
@@ -1835,27 +1769,27 @@ def rebuild_shards(base: str, mesh=None,
             drain_one()
         done = True
     finally:
-        if wt is not None:
+        if not done:
+            reads.stop.set()    # the writer too: nothing left to write
+        if writes is not None:
             with tracing.stage("ec.rebuild.write_wait", add_time,
                                "write_wait"):
-                try:
-                    wq.put(None, timeout=5)
-                except queue.Full:
-                    pass
-                wt.join(timeout=120)
-        if reads is not None:
-            reads.close()   # before its slots and files go
+                writes.close()
+        reads.close()   # before its slots and files go
         for sl in slots:
             pool.release(sl)
         for fd in in_fds:
             os.close(fd)
-        for fd in out_fds.values():
-            os.close(fd)
-        if not done or werrs:
-            for sid in out_fds:     # they were missing: leave them so
-                os.unlink(base + to_ext(sid))
-    if werrs:
-        raise werrs[0]
+        if files is not None:
+            files.close()
+        if not done or reads.errors:
+            for sid in missing:     # they were missing: leave them so
+                try:
+                    os.unlink(base + to_ext(sid))
+                except FileNotFoundError:
+                    pass
+    if reads.errors:
+        raise reads.errors[0]
     if stage_stats is not None:
         dev0 = mesh.devices.flat[0]
         stage_stats.update({
@@ -1873,4 +1807,4 @@ def rebuild_shards(base: str, mesh=None,
             "h2d_bytes": n_batches * b * DATA_SHARDS * chunk,
             "d2h_bytes": n_batches * b * len(missing) * chunk,
         })
-    return crcs
+    return {sid: files.crcs[sid] for sid in missing}

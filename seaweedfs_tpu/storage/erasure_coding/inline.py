@@ -234,7 +234,7 @@ class InlineEcWriter:
     def __init__(self, base: str, family: Optional[str] = None,
                  unit: Optional[int] = None, create: bool = False,
                  version: int = 3):
-        from ...parallel.batched_encode import _WritebackPacer, _write_knobs
+        from ...parallel.batched_encode import WritebackPacer
 
         self.base = base
         self.version = version
@@ -260,8 +260,7 @@ class InlineEcWriter:
             for ext in (".ecx", ".ecj"):
                 if not os.path.exists(base + ext):
                     open(base + ext, "ab").close()
-        _, _, flush_bytes, drop = _write_knobs()
-        self._pacer = _WritebackPacer(flush_bytes, drop)
+        self._pacer = WritebackPacer()
         # snapshot the log sizes BEFORE O_CREAT: a deleted/lost shard
         # log is recreated empty by the open below, and only this
         # snapshot lets _recover tell "lost device" from "empty log"
@@ -434,12 +433,12 @@ class InlineEcWriter:
             self._pwrite_shard(sid, inner, seg)
 
     def _pwrite_shard(self, shard_id: int, offset: int, buf):
-        from ...parallel.batched_encode import _pwritev_full
+        from ...parallel.batched_encode import pwritev_full
 
         if _faults.ACTIVE:
             _faults.on_disk(self.base + to_ext(shard_id), "write")
         fd = self._fds[shard_id]
-        _pwritev_full(fd, [buf], offset)
+        pwritev_full(fd, [buf], offset)
         self._pacer.wrote(fd, offset, len(buf))
 
     # -- tail reads (partially-filled stripe) --------------------------------
@@ -677,7 +676,7 @@ class InlineEcWriter:
     def _append_record(self, kind: int, row_index: int, logical: int,
                        idx_size: int, row: bytes, parity: np.ndarray):
         from ...ops import crc32c as crc32c_mod
-        from ...parallel.batched_encode import _pwritev_full
+        from ...parallel.batched_encode import pwritev_full
 
         crc = crc32c_mod.crc32c(row)
         crc = crc32c_mod.crc32c(np.ascontiguousarray(parity).tobytes(),
@@ -688,7 +687,7 @@ class InlineEcWriter:
         rec = pack_record(kind, row_index, logical, idx_size, crc, offs)
         if _faults.ACTIVE:
             _faults.on_disk(self._scl_path, "commit")
-        _pwritev_full(self._scl_fd, [rec], self._scl_size)
+        pwritev_full(self._scl_fd, [rec], self._scl_size)
         self._scl_size += SCL_RECORD_SIZE
         self.stripes_committed += 1
 

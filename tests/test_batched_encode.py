@@ -2,12 +2,16 @@
 fused shard-file CRC32Cs, multi-volume batching (parallel/batched_encode.py).
 """
 
+import errno
+import itertools
 import os
+import threading
 
 import numpy as np
 import pytest
 
 from seaweedfs_tpu.ops import crc32c as crc_host
+from seaweedfs_tpu.parallel import batched_encode as be
 from seaweedfs_tpu.parallel.batched_encode import (_chunk_len, _plan_volume,
                                                    encode_volumes)
 from seaweedfs_tpu.storage.erasure_coding import encoder as ec_encoder
@@ -389,20 +393,20 @@ class TestWriteBehindStage:
         base = _make_volume(tmp_path, tag, size, seed)
         for k, v in env.items():
             monkeypatch.setenv(k, v)
+        # a pacing window this small volume fills
+        monkeypatch.setattr(be, "WRITE_FLUSH_BYTES", 1 << 20)
         st: dict = {}
         crcs = encode_volumes([base], large_block=LARGE, small_block=SMALL,
                               host_codec=True, stage_stats=st)[base]
         return base, crcs, st
 
     def test_write_behind_matches_inline(self, tmp_path, monkeypatch):
-        """Async write-behind (4 workers, 3 writers, tiny pacing window)
-        produces shards byte- and CRC-identical to the single-threaded
-        inline path on the same input."""
+        """Async write-behind (4 workers, so 2 writers; tiny pacing
+        window) produces shards byte- and CRC-identical to the
+        single-threaded inline path on the same input."""
         b_async, c_async, st = self._encode(
-            tmp_path, monkeypatch, "wb",
-            WEED_EC_HOST_WORKERS="4", WEED_EC_WRITERS="3",
-            WEED_EC_WRITE_BEHIND="1", WEED_EC_WRITE_FLUSH_MB="1")
-        assert st["write_behind"] is True and st["writers"] == 3
+            tmp_path, monkeypatch, "wb", WEED_EC_HOST_WORKERS="4")
+        assert st["write_behind"] is True and st["writers"] == 2
         b_inline, c_inline, st2 = self._encode(
             tmp_path, monkeypatch, "inl", WEED_EC_HOST_WORKERS="1")
         assert st2["write_behind"] is False and st2["writers"] == 0
@@ -414,36 +418,19 @@ class TestWriteBehindStage:
                 assert got == b.read(), f"shard {i}"
             assert c_async[i] == crc_host.crc32c(got), f"crc {i}"
 
-    def test_sync_mode_knob_matches(self, tmp_path, monkeypatch):
-        """WEED_EC_WRITE_BEHIND=0 degrades to the two-stage form
-        (compute workers write synchronously) with identical output."""
-        b_sync, c_sync, st = self._encode(
-            tmp_path, monkeypatch, "sync",
-            WEED_EC_HOST_WORKERS="4", WEED_EC_WRITE_BEHIND="0")
-        assert st["write_behind"] is False and st["writers"] == 0
-        b_inline, c_inline, _ = self._encode(
-            tmp_path, monkeypatch, "sref", WEED_EC_HOST_WORKERS="1")
-        assert c_sync == c_inline
-        for i in range(14):
-            with open(b_sync + to_ext(i), "rb") as a, \
-                    open(b_inline + to_ext(i), "rb") as b:
-                assert a.read() == b.read(), f"shard {i}"
-
     def test_stage_stats_schema(self, tmp_path, monkeypatch):
         """With the writer stage enabled and >=2 workers, stage stats
         attribute read / encode_crc / write / flush separately, plus the
         pipeline-shape fields."""
         _, _, st = self._encode(
-            tmp_path, monkeypatch, "ss",
-            WEED_EC_HOST_WORKERS="2", WEED_EC_WRITE_BEHIND="1",
-            WEED_EC_WRITERS="0", WEED_EC_WRITE_FLUSH_MB="1")
+            tmp_path, monkeypatch, "ss", WEED_EC_HOST_WORKERS="2")
         for k in ("read", "encode_crc", "write", "flush", "wall"):
             assert isinstance(st[k], float), k
             assert st[k] >= 0.0, k
         for k in ("read", "encode_crc", "write", "flush"):
             assert isinstance(st[f"{k}_frac"], float), k
         assert st["workers"] == 2
-        assert st["writers"] >= 1          # auto: workers//2, min 1
+        assert st["writers"] == 1          # workers // 2, at least 1
         assert st["write_behind"] is True
         assert isinstance(st["flushes"], int)
         assert st["items"] >= 1
@@ -491,21 +478,220 @@ class TestWriteBehindStage:
                 assert got == b.read(), f"shard {i}"
             assert crcs[i] == crc_host.crc32c(got), f"crc {i}"
 
-    def test_pwritev_full_unit(self, tmp_path):
-        """_pwritev_full unit coverage: multi-iovec writes land fully at
+    def testpwritev_full_unit(self, tmp_path):
+        """pwritev_full unit coverage: multi-iovec writes land fully at
         the right offset; zero progress raises."""
-        from seaweedfs_tpu.parallel.batched_encode import _pwritev_full
+        from seaweedfs_tpu.parallel.batched_encode import pwritev_full
 
         path = str(tmp_path / "f")
         fd = os.open(path, os.O_CREAT | os.O_WRONLY, 0o644)
         try:
             bufs = [b"aa", b"bbb", b"cccc"]
-            n = _pwritev_full(fd, bufs, 3)
+            n = pwritev_full(fd, bufs, 3)
             assert n == 9
         finally:
             os.close(fd)
         with open(path, "rb") as f:
             assert f.read() == b"\0\0\0aabbbcccc"
+
+
+JOBS = ("seal", "rebuild", "host")
+LOST = (0, 3, 11, 13)
+
+
+def _writer_thread() -> bool:
+    return threading.current_thread().name in ("ec-encode-writer",
+                                               "ec-rebuild-writer")
+
+
+def _pipeline_threads() -> list:
+    return [t.name for t in threading.enumerate()
+            if t.name.startswith(("ec-encode", "ec-rebuild"))]
+
+
+class TestWriteStage:
+    """The write-behind stage the seal, the rebuild and the host
+    pipeline compose (`_WriteStage`): what a failed write does to each
+    job, the order of its writes, and `put` on a stopped job."""
+
+    def _job(self, job, tmp_path, monkeypatch, before=lambda: None):
+        """(base, the shard ids the job writes, run): `run()` makes
+        several batches (or spans) of a small volume and returns
+        {shard id: CRC32C}.  `before` runs once the rebuild's volume is
+        sealed and its shards are lost."""
+        monkeypatch.setenv("WEED_EC_DEVICE_SHARD", "1")
+        base = _make_volume(tmp_path, job, LARGE * 10 * 2 + 12345, 31)
+        kw = dict(large_block=LARGE, small_block=SMALL)
+        if job == "seal":
+            before()
+            return base, range(14), lambda: dict(enumerate(
+                encode_volumes([base], batch_units=40, **kw)[base]))
+        if job == "host":
+            monkeypatch.setenv("WEED_EC_HOST_WORKERS", "4")
+            # a row of large blocks a span, four rows of small ones
+            monkeypatch.setattr(be, "_HOST_SPAN_BYTES", SMALL * 10 * 4)
+            before()
+            return base, range(14), lambda: dict(enumerate(
+                encode_volumes([base], host_codec=True, **kw)[base]))
+        encode_volumes([base], **kw)
+        for sid in LOST:
+            os.remove(base + to_ext(sid))
+        monkeypatch.setattr(be, "MAX_CHUNK_BYTES", 4096)
+        before()
+        return base, LOST, lambda: be.rebuild_shards(base, batch_units=1)
+
+    @pytest.mark.parametrize("job", JOBS)
+    def test_a_failed_write_fails_the_job_and_frees_everything(
+            self, job, tmp_path, monkeypatch):
+        """The third pwritev of the writer thread fails: the job raises
+        that OSError, every thread of it is joined, every lease of the
+        slab pool is back, and a rebuild leaves none of the files it
+        created."""
+        from seaweedfs_tpu.ops.device_pool import get_pool
+
+        real = os.pwritev
+        calls = itertools.count(1)
+
+        def pwritev(fd, bufs, offset):
+            if _writer_thread() and next(calls) == 3:
+                raise OSError(errno.EIO, "injected into the writer")
+            return real(fd, bufs, offset)
+
+        base, _, run = self._job(
+            job, tmp_path, monkeypatch,
+            before=lambda: monkeypatch.setattr(os, "pwritev", pwritev))
+        with pytest.raises(OSError, match="injected into the writer"):
+            run()
+        assert next(calls) > 3
+        assert not _pipeline_threads()
+        assert get_pool().snapshot()["leased_slots"] == 0
+        if job == "rebuild":
+            for sid in range(14):
+                assert os.path.exists(base + to_ext(sid)) == \
+                    (sid not in LOST)
+
+    @pytest.mark.parametrize("job", JOBS)
+    def test_a_writer_writes_in_the_order_of_put(self, job, tmp_path,
+                                                 monkeypatch):
+        """Batches are put in order, so each writer's offsets in a file
+        only rise, and the rolling CRCs chained in that order are the
+        files' CRC32Cs."""
+        real = os.pwritev
+        offsets: dict = {}
+
+        def pwritev(fd, bufs, offset):
+            if _writer_thread():
+                offsets.setdefault((threading.get_ident(), fd),
+                                   []).append(offset)
+            return real(fd, bufs, offset)
+
+        base, shards, run = self._job(
+            job, tmp_path, monkeypatch,
+            before=lambda: monkeypatch.setattr(os, "pwritev", pwritev))
+        crcs = run()
+        writers = {tid for tid, _ in offsets}
+        if job == "host":   # two writers, and either may take every span
+            assert len(writers) in (1, 2)
+            assert len({fd for _, fd in offsets}) == 14
+        else:
+            assert len(writers) == 1
+            assert min(map(len, offsets.values())) > 2
+        for offs in offsets.values():
+            assert offs == sorted(set(offs))
+        for sid in shards:
+            with open(base + to_ext(sid), "rb") as f:
+                assert crcs[sid] == crc_host.crc32c(f.read()), sid
+
+    def test_put_gives_up_when_the_job_stops_with_the_queue_full(self):
+        errors: list = []
+        stop, gate = threading.Event(), threading.Event()
+        written: list = []
+
+        def write(item):
+            gate.wait(10)
+            written.append(item)
+
+        stage = be._WriteStage("ec.encode", write, lambda key, s: None,
+                               errors, stop, depth=1)
+        stage.start()
+        assert stage.put("taken by the writer")
+        assert stage.put("fills the queue")
+        got: list = []
+        blocked = threading.Thread(
+            target=lambda: got.append(stage.put("one too many")))
+        blocked.start()
+        blocked.join(0.2)
+        assert blocked.is_alive() and not got   # the queue is full
+        stop.set()
+        blocked.join(5)
+        assert got == [False]
+        assert stage.put("after the stop") is False
+        gate.set()
+        stage.close()
+        assert written == ["taken by the writer"] and not errors
+        assert not _pipeline_threads()
+
+    def test_every_item_is_written_once_by_a_pool_that_gathers(self):
+        """Four writers that each take what is queued behind their item
+        (`take_if`, as the host pipeline's client does), more threads
+        than items in the queue, a short switch interval: no item is
+        lost and none is written twice."""
+        import sys
+
+        errors: list = []
+        stop = threading.Event()
+        written: list = []
+
+        def write(item):
+            group = [item]
+            while len(group) < 8:
+                nxt = stage.take_if(lambda n: n == group[-1] + 1)
+                if nxt is None:
+                    break
+                group.append(nxt)
+            written.extend(group)
+
+        stage = be._WriteStage("ec.encode", write, lambda key, s: None,
+                               errors, stop, depth=6, writers=4)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            stage.start()
+            for i in range(3000):
+                assert stage.put(i)
+            stage.close()
+        finally:
+            sys.setswitchinterval(interval)
+        assert not errors and not _pipeline_threads()
+        assert sorted(written) == list(range(3000))
+
+    def test_a_file_set_opens_the_shards_it_is_given(self, tmp_path,
+                                                     monkeypatch):
+        """A rebuild's set holds the missing shards only, at their final
+        size; a set that fails to open one closes those it opened."""
+        base = str(tmp_path / "v")
+        files = be._ShardFileSet(base, to_ext, 100, shards=(3, 11))
+        assert sorted(files.fds) == [3, 11]
+        assert files.write(11, [b"abc", b"de"], 7) == 5
+        files.close()
+        assert sorted(os.listdir(tmp_path)) == ["v.ec03", "v.ec11"]
+        with open(base + to_ext(11), "rb") as f:
+            assert f.read() == bytes(7) + b"abcde" + bytes(88)
+        opened, closed = [], []
+        real_open, real_close = os.open, os.close
+
+        def failing_truncate(fd, size):
+            if len(opened) == 2:
+                raise OSError(errno.ENOSPC, "injected into ftruncate")
+
+        monkeypatch.setattr(os, "open", lambda *a: opened.append(
+            real_open(*a)) or opened[-1])
+        monkeypatch.setattr(os, "close", lambda fd: closed.append(fd)
+                            or real_close(fd))
+        monkeypatch.setattr(os, "ftruncate", failing_truncate)
+        with pytest.raises(OSError, match="injected into ftruncate"):
+            be._ShardFileSet(base, to_ext, 100, shards=(0, 5, 9))
+        assert len(opened) == 2 and closed == opened
 
 
 class TestDevicePoolPipeline:
